@@ -220,7 +220,7 @@ def send(tensor, dst=0, group=None, sync_op=True):
     """Point-to-point on a ring: implemented as ppermute inside SPMD regions."""
     ax = _axis(group)
     if _in_spmd(ax):
-        n = env.axis_size(ax)
+        n = lax.axis_size(ax)
         perm = [(i, dst) for i in range(n)]
         return _apply(lambda x: lax.ppermute(x, ax, perm), tensor, op_name="send")
     return tensor
@@ -229,7 +229,7 @@ def send(tensor, dst=0, group=None, sync_op=True):
 def recv(tensor, src=0, group=None, sync_op=True):
     ax = _axis(group)
     if _in_spmd(ax):
-        n = env.axis_size(ax)
+        n = lax.axis_size(ax)
         perm = [(src, i) for i in range(n)]
         out = _apply(lambda x: lax.ppermute(x, ax, perm), tensor, op_name="recv")
         if isinstance(tensor, Tensor):
@@ -244,7 +244,7 @@ def p2p_shift(tensor, group=None, shift=1):
     `shift` steps around the axis. Used by pipeline & ring attention."""
     ax = _axis(group)
     def f(x):
-        n = env.axis_size(ax)
+        n = lax.axis_size(ax)
         perm = [(i, (i + shift) % n) for i in range(n)]
         return lax.ppermute(x, ax, perm)
     return _apply(f, tensor, op_name="p2p_shift")

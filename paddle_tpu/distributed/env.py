@@ -33,11 +33,11 @@ def init_parallel_env():
     global _initialized
     if _initialized:
         return
-    if "TPU_WORKER_HOSTNAMES" in os.environ or "MEGASCALE_COORDINATOR_ADDRESS" in os.environ:
-        try:
-            jax.distributed.initialize()
-        except Exception:
-            pass
+    # several worker hosts (a one-host machine lists just itself and has
+    # nothing to initialise) or a multi-slice coordinator
+    if ("," in os.environ.get("TPU_WORKER_HOSTNAMES", "")
+            or "MEGASCALE_COORDINATOR_ADDRESS" in os.environ):
+        jax.distributed.initialize()
     _initialized = True
 
 
@@ -109,46 +109,6 @@ def create_single_axis_mesh(axis, n=None, devices=None):
 def replicated_sharding(mesh=None):
     mesh = mesh or _global_mesh
     return NamedSharding(mesh, PartitionSpec())
-
-
-def axis_size(name):
-    """Static size of a bound mesh axis inside an SPMD region. jax<0.5 has
-    no `lax.axis_size`; `psum` of a literal 1 folds to the size constant."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None,
-                     check_rep=False):
-    """`jax.shard_map(..., axis_names=...)` portability shim: jax<0.5 only
-    has jax.experimental.shard_map, whose partial-manual knob is the
-    complement set `auto=` instead of `axis_names=`."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        # callers rely on disabled replication checking (e.g. pipeline
-        # stages return per-device garbage under out_specs=P()); forward it
-        # under whichever name this jax spells it
-        try:
-            import inspect
-            sig = inspect.signature(jax.shard_map).parameters
-            for flag in ("check_rep", "check_vma"):
-                if flag in sig:
-                    kw[flag] = check_rep
-                    break
-        except (TypeError, ValueError):
-            pass
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep, **kw)
 
 
 class ParallelEnv:

@@ -43,7 +43,7 @@ def pipeline_spmd(block_fn, stage_params, x_mb, *, axis_name="pp"):
     other stages return garbage that the caller discards (out_specs selects
     from the last stage).
     """
-    S = env.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     M = x_mb.shape[0]
     T = M + S - 1
@@ -98,13 +98,9 @@ def _stage_fn_of(block_fn, remat_policy=None):
 
 def _varying(a, axis_name):
     try:
-        if hasattr(lax, "pcast"):
-            return lax.pcast(a, (axis_name,), to="varying")
-        if hasattr(lax, "pvary"):
-            return lax.pvary(a, (axis_name,))
+        return lax.pcast(a, (axis_name,), to="varying")
     except ValueError:
-        pass  # already varying over axis_name
-    return a
+        return a  # already varying over axis_name
 
 
 def _gated_fwd(stage_fn, axis_name, active, pv, inp):
@@ -158,7 +154,7 @@ def pipeline_spmd_1f1b(block_fn, stage_params, x_mb, *, axis_name="pp",
     (cast) stage params. ~1 extra forward vs GPipe+autodiff, in exchange for
     O(S) instead of O(M) activation memory.
     """
-    S = env.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     M = x_mb.shape[0]
     stage_fn = _stage_fn_of(block_fn, remat_policy)
 
@@ -259,7 +255,7 @@ def pipeline_spmd_interleaved_1f1b(block_fn, stage_params, x_mb, *,
     stage_params leaves: [1, V, L_chunk, ...] — this device's V chunks.
     x_mb: [M, mb...]; returns [M, mb...] like pipeline_spmd.
     """
-    S = env.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     V = num_virtual
     Sv = V * S
     M = x_mb.shape[0]
@@ -438,7 +434,7 @@ def pipeline_ring_gpipe(block_fn, stage_params, x_mb, *, axis_name="pp",
     stage's LAST layer with the boundary send fused into its final GEMM's
     epilogue (fused_collectives.fused_gemm_ppsend); the hook owns the hop,
     so no separate ppermute is issued for it."""
-    S = env.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     M = x_mb.shape[0]
     T = M + S - 1
@@ -496,7 +492,7 @@ def pipeline_ring_1f1b(block_fn, stage_params, x_mb, *, axis_name="pp",
     issued at tick end, cotangents ride the reversed ring the same way, and
     per-stage param grads accumulate in the PARAM dtype (fp32 master params
     give fp32 accumulation under a bf16 wire for free)."""
-    S = env.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     M = x_mb.shape[0]
     stage_fn = _stage_fn_of(block_fn, remat_policy)
     wire = jnp.dtype(wire_dtype) if wire_dtype is not None else x_mb.dtype
@@ -671,10 +667,10 @@ def run_pipeline(block_fn, stacked_params, x, num_microbatches, mesh=None,
             inner = functools.partial(
                 pipeline_ring_gpipe, block_fn, axis_name=axis_name,
                 wire_dtype=wire_dtype, boundary=boundary)
-        mapped = env.shard_map_compat(
+        mapped = jax.shard_map(
             lambda p, xm: inner(p, xm), mesh=mesh,
             in_specs=(param_specs, P(None, *xs)),
-            out_specs=P("pp", None, *xs), axis_names=None)
+            out_specs=P("pp", None, *xs), check_vma=False)
         out_smb = mapped(staged, x_mb)
         # stage-major [S, M, mb, ...]: slice the last stage's outputs (the
         # one cross-stage broadcast of the step, replacing the seed's
@@ -696,10 +692,10 @@ def run_pipeline(block_fn, stacked_params, x, num_microbatches, mesh=None,
                 "path derives its own recompute from the scan)")
         spmd = pipeline_spmd
     inner = functools.partial(spmd, block_fn, axis_name=axis_name)
-    mapped = env.shard_map_compat(
+    mapped = jax.shard_map(
         lambda p, xm: inner(p, xm),
         mesh=mesh, in_specs=(param_specs, P()), out_specs=P(),
-        axis_names=frozenset({axis_name}))
+        axis_names=frozenset({axis_name}), check_vma=False)
     out_mb = mapped(staged, x_mb)
     return out_mb.reshape((B,) + out_mb.shape[2:])
 

@@ -48,7 +48,7 @@ def _block_attn(q, k, v, m_prev, l_prev, acc_prev, block_mask):
 def ring_attention_spmd(q, k, v, *, axis_name="sp", causal=True):
     """Inside shard_map manual over `axis_name`. q,k,v: [B, S_local, H, D]
     (local sequence chunk). Returns [B, S_local, H, D]."""
-    n = env.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, Sl, H, D = q.shape
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -84,7 +84,7 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=True):
     from ..tensor_impl import Tensor, as_tensor_data
     qa, ka, va = (as_tensor_data(t) for t in (q, k, v))
     spec = P(None, axis_name, None, None)
-    mapped = env.shard_map_compat(
+    mapped = jax.shard_map(
         functools.partial(ring_attention_spmd, axis_name=axis_name, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         axis_names=frozenset({axis_name}))
@@ -95,7 +95,7 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=True):
 def ulysses_attention_spmd(q, k, v, *, axis_name="sp", causal=True):
     """All-to-all sequence parallelism: exchange seq-shard for head-shard,
     run full-sequence attention per head group, exchange back."""
-    n = env.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     B, Sl, H, D = q.shape
     assert H % n == 0, f"heads {H} not divisible by sp degree {n}"
 
@@ -127,7 +127,7 @@ def ulysses_attention(q, k, v, mesh=None, axis_name="sp", causal=True):
     from ..tensor_impl import Tensor, as_tensor_data
     qa, ka, va = (as_tensor_data(t) for t in (q, k, v))
     spec = P(None, axis_name, None, None)
-    mapped = env.shard_map_compat(
+    mapped = jax.shard_map(
         functools.partial(ulysses_attention_spmd, axis_name=axis_name, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         axis_names=frozenset({axis_name}))

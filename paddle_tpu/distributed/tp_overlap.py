@@ -287,14 +287,13 @@ def make_sp_block(config, mesh, cfg):
     scan: (layer_params, x[B,S,H] logical) -> x. Full-manual over every mesh
     axis (see module docstring for why partial-manual is not an option on
     jax 0.4.x); axes other than dp/mp are size-1 by `resolve_gpt` gating."""
-    from .env import shard_map_compat
     block = sp_block_fn(config, cfg.n, axis=cfg.axis, backend=cfg.backend,
                         meta=cfg.kernel_meta(mesh))
     x_spec = sp_activation_spec(cfg.batch_axis)
-    return shard_map_compat(
-        block, mesh,
+    return jax.shard_map(
+        block, mesh=mesh,
         in_specs=(dict(SP_BLOCK_PARAM_SPECS), x_spec),
-        out_specs=x_spec)
+        out_specs=x_spec, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -610,7 +609,6 @@ def column_linear(x, w, b, mesh, gather_output):
     x [B,S,H] seq-sharded between blocks, w [H, F] mp-sharded on F. The
     bias (mp-sharded on F) is added on the logical output — elementwise, no
     extra collective."""
-    from .env import shard_map_compat
     mp = int(mesh.shape.get("mp", 1))
     batch_axis = "dp" if mesh.shape.get("dp", 1) > 1 else None
     x_spec = P(batch_axis, "mp", None)
@@ -619,9 +617,9 @@ def column_linear(x, w, b, mesh, gather_output):
     def f(xs, ws):
         return column_parallel(xs, ws, None, "mp", mp, backend, meta)
 
-    mapped = shard_map_compat(
-        f, mesh, in_specs=(x_spec, P(None, "mp")),
-        out_specs=P(batch_axis, None, "mp"))
+    mapped = jax.shard_map(
+        f, mesh=mesh, in_specs=(x_spec, P(None, "mp")),
+        out_specs=P(batch_axis, None, "mp"), check_vma=False)
     out = mapped(x, w)
     if b is not None:
         out = out + b
@@ -636,7 +634,6 @@ def row_linear(x, w, b, mesh):
     x [B,S,F] mp-sharded on F, w [F, H] mp-sharded on F; output seq-sharded
     [B,S,H] (the next block's norms/residuals run on the shard). The full
     bias is added once on the logical reduced output."""
-    from .env import shard_map_compat
     mp = int(mesh.shape.get("mp", 1))
     batch_axis = "dp" if mesh.shape.get("dp", 1) > 1 else None
     backend, meta = _layer_backend(mesh)
@@ -644,9 +641,9 @@ def row_linear(x, w, b, mesh):
     def f(xs, ws):
         return row_parallel(xs, ws, None, "mp", mp, backend, meta)
 
-    mapped = shard_map_compat(
-        f, mesh, in_specs=(P(batch_axis, None, "mp"), P("mp", None)),
-        out_specs=P(batch_axis, "mp", None))
+    mapped = jax.shard_map(
+        f, mesh=mesh, in_specs=(P(batch_axis, None, "mp"), P("mp", None)),
+        out_specs=P(batch_axis, "mp", None), check_vma=False)
     out = mapped(x, w)
     return out if b is None else out + b
 

@@ -17,7 +17,7 @@ _FLAGS = {
     # execution when debugging numerics or tracing issues.
     "FLAGS_eager_jit_cache": True,
     # Persist XLA executables across processes (JAX_COMPILATION_CACHE_DIR,
-    # default <cwd>/.jax_cache — see framework/compilation_cache.py).
+    # default <checkout>/.jax_cache — see framework/compilation_cache.py).
     "FLAGS_persistent_compilation_cache": True,
     # -- explicit gradient communication (distributed/grad_comm.py) ---------
     # Master switch: "auto" activates the explicit schedule only when one of

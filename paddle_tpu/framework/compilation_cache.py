@@ -1,16 +1,21 @@
-"""Persistent XLA compilation cache wiring.
+"""Persistent XLA compilation cache — the one owner of
+``jax_compilation_cache_dir``.
 
 jax can serialize compiled executables to disk and reload them in later
 processes (the TPU analog of the reference's cached CUDA kernel binaries +
-cudnn autotune cache). We point it at `JAX_COMPILATION_CACHE_DIR` when set,
-else `<cwd>/.jax_cache/`, the first time any paddle_tpu path creates a jitted
-executable — so a fresh process re-running the same training script skips
-XLA recompilation entirely.
+cudnn autotune cache). Where it lives:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set: jax read it into its config at import
+  and nothing here touches it — the deployment places the cache.
+* otherwise ``<checkout>/.jax_cache``, computed from this package's own
+  location (as io/native.py finds its build dir). The directory is part of
+  the cache key, so it must not move with the caller's working directory.
 
 Lazy by design: importing paddle_tpu must not create directories or mutate
-jax config; the first dispatch-cache entry / TrainStep / to_static build
-triggers it. `FLAGS_persistent_compilation_cache=False` (or an explicit
-user-set jax_compilation_cache_dir) leaves the config untouched.
+jax config; the first build point (dispatch-cache entry, optimizer step,
+TrainStep, to_static, HybridTrainStep, serving.Engine) triggers it.
+``FLAGS_persistent_compilation_cache=False`` before that point leaves the
+config untouched.
 """
 from __future__ import annotations
 
@@ -19,53 +24,30 @@ import threading
 
 import jax
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
+
 _lock = threading.Lock()
 _initialized = False
-
-
-def _flags_enabled():
-    from .. import flags as _flags
-    return bool(_flags._FLAGS.get("FLAGS_persistent_compilation_cache", True))
 
 
 def ensure_persistent_cache():
     """Idempotent: enable jax's on-disk compilation cache once per process."""
     global _initialized
-    if _initialized and _flags_enabled():
-        return  # fast path only while the flag still agrees with the latch
+    if _initialized:
+        return
     with _lock:
-        from .. import flags as _flags
-        enabled = _flags._FLAGS.get("FLAGS_persistent_compilation_cache", True)
         if _initialized:
-            if not enabled:
-                # flag turned off after we enabled the cache: undo it at the
-                # next build point so the knob stays live both ways
-                try:
-                    jax.config.update("jax_compilation_cache_dir", None)
-                except Exception:
-                    pass
-                _initialized = False
             return
-        if not enabled:
+        from .. import flags as _flags
+        if not _flags._FLAGS.get("FLAGS_persistent_compilation_cache", True):
             return  # latch NOT set: enabling the flag later still works
         _initialized = True
-        try:
-            current = jax.config.jax_compilation_cache_dir
-        except AttributeError:
-            return  # jax without the compilation-cache config
-        if current:  # user (or autotune) already chose a directory
-            return
-        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
-            os.path.join(os.getcwd(), ".jax_cache")
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-        except Exception:
-            pass  # persistent cache is an optimization, never a hard dep
+        if not jax.config.jax_compilation_cache_dir:
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
 
 
 def cache_dir():
     """The active persistent-cache directory, or None when disabled."""
-    try:
-        return jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        return None
+    return jax.config.jax_compilation_cache_dir

@@ -48,11 +48,7 @@ def set_device(device: str):
     kind, _, idx = device.partition(":")
     idx = int(idx) if idx else 0
     kind = {"gpu": "tpu", "cuda": "tpu", "tpu": "tpu", "cpu": "cpu"}.get(kind, kind)
-    try:
-        dev = jax.devices(kind)[idx]
-    except RuntimeError:
-        dev = jax.devices()[0]
-        kind = dev.platform
+    dev = jax.devices(kind)[idx]  # raises when the machine has no such device
     jax.config.update("jax_default_device", dev)
     _current = f"{kind}:{idx}" if kind != "cpu" else "cpu"
     return _current

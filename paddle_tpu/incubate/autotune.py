@@ -57,15 +57,8 @@ def get_config():
 
 def _apply():
     if _CONFIG["kernel"]["enable"]:
-        import os
-        import jax
-        cache = os.path.join(os.getcwd(), ".jax_cache")
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              1.0)
-        except Exception:  # noqa: BLE001 — already configured is fine
-            pass
+        from ..framework.compilation_cache import ensure_persistent_cache
+        ensure_persistent_cache()
 
 
 def dataloader_num_workers(requested):

@@ -200,8 +200,7 @@ def expert_parallel_moe(x, gate_w, gate_b, w1, b1, w2, b2, *, mesh=None,
 
     tok = P(axis, None)
     exp = P(axis, *([None] * (w1.ndim - 1)))
-    from .....distributed import env as _dist_env
-    mapped = _dist_env.shard_map_compat(
+    mapped = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(tok, P(), P(), exp, P(axis, None), exp, P(axis, None)),
         out_specs=(tok, P()),

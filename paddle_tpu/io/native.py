@@ -58,12 +58,10 @@ def load_library(rebuild=False):
         if _build_error is not None and not rebuild:
             return None  # don't retry a known-broken toolchain every call
         try:
-            have_lib = os.path.exists(_LIB_PATH)
-            have_src = os.path.exists(_SRC)
-            stale = rebuild or not have_lib or (
-                have_src and os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
-            )  # a prebuilt .so without the source tree is fine as-is
-            if stale:
+            # native/build/ is not committed: the library is always built
+            # here, from the source beside it, when missing or older
+            if (rebuild or not os.path.exists(_LIB_PATH)
+                    or os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)):
                 _compile_lib()
             lib = ctypes.CDLL(_LIB_PATH)
         except (OSError, RuntimeError, FileNotFoundError) as e:

@@ -29,6 +29,8 @@ compute instead of bunching at the update barrier.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -416,7 +418,7 @@ class TrainStep:
         state stays alive for in-place repair."""
         from ..distributed import grad_comm as _gc
         from ..distributed import integrity as _integrity
-        from ..distributed.env import shard_map_compat as shard_map
+        shard_map = functools.partial(jax.shard_map, check_vma=False)
         cfg = self._gc_cfg
         mesh, axis, n = self.mesh, cfg.axis, cfg.n
         optimizer = self.optimizer
@@ -435,7 +437,7 @@ class TrainStep:
         # arange argument (a trace-time constant through psum_scatter also
         # aborts the partitioner).
         composed = bool(cfg.auto_axes)
-        manual = frozenset({axis}) if composed else None
+        manual = frozenset({axis}) if composed else frozenset()
         # only the explicit-allreduce baseline's grad gather is emulated in
         # composed mode; the sharded-update path hands its param gather to
         # GSPMD outside the manual region (native all-gather bytes)

@@ -18,6 +18,7 @@ from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .. import nn
 from ..nn.layer_base import Layer
@@ -86,14 +87,31 @@ GPT_CONFIGS = {
 }
 
 
-def _attention(q, k, v, use_flash, causal=True, block_q=256, block_k=256):
+def _attention(q, k, v, use_flash, causal=True, block_q=256, block_k=256,
+               mesh=None):
     """q,k,v arrays [B,S,H,D] -> [B,S,H,D]. Routed by the same logged
-    predicate as nn.functional (flash_supported) so gating can't drift."""
+    predicate as nn.functional (flash_supported) so gating can't drift.
+
+    ``mesh``: the hybrid mesh the caller is being partitioned over by GSPMD.
+    A Mosaic kernel cannot be partitioned automatically ("wrap the call in
+    a shard_map"), so the kernel runs per shard over the two dims the
+    hybrid layout shards and attention is independent in: batch rows over
+    'dp', heads over 'mp'. Inside a region that is already manual (the
+    pipeline's, the sequence-parallel block's) the call is per shard as is."""
     from ..ops.pallas_kernels.flash_attention import flash_supported
     if use_flash and flash_supported(q.shape, kv_seq=k.shape[1], why="gpt"):
         from ..ops.pallas_kernels.flash_attention import flash_attention_bshd
-        return flash_attention_bshd(q, k, v, causal,
-                                    block_q=block_q, block_k=block_k)
+
+        def flash(q, k, v):
+            return flash_attention_bshd(q, k, v, causal,
+                                        block_q=block_q, block_k=block_k)
+        if (mesh is not None and mesh.size > 1
+                and not jax.sharding.get_abstract_mesh().manual_axes):
+            spec = P("dp" if "dp" in mesh.axis_names else None, None,
+                     "mp" if "mp" in mesh.axis_names else None, None)
+            flash = jax.shard_map(flash, mesh=mesh, in_specs=(spec,) * 3,
+                                  out_specs=spec, check_vma=False)
+        return flash(q, k, v)
     return blockwise_attention(q, k, v, causal=causal)
 
 
@@ -270,7 +288,7 @@ def ln_fp32(x, g, b, eps):
         x.dtype) + b.astype(x.dtype)
 
 
-def gpt_block_prelude_fn(config: GPTConfig):
+def gpt_block_prelude_fn(config: GPTConfig, mesh=None):
     """The block minus its final down-projection: (p, x) -> (resid, gact)
     where resid is the post-attention residual stream and gact the gelu
     activation — the (r, x) operands of the boundary GEMM the fused pp
@@ -295,7 +313,8 @@ def gpt_block_prelude_fn(config: GPTConfig):
             q, k, v = q3[:, :, 0], k3[:, :, 0], v3[:, :, 0]
         ctx = _attention(q, k, v, config.use_flash,
                          block_q=getattr(config, "flash_block_q", 256),
-                         block_k=getattr(config, "flash_block_k", 256))
+                         block_k=getattr(config, "flash_block_k", 256),
+                         mesh=mesh)
         # named residual: remat_policy="save_attn" keeps ctx so the backward
         # pass skips the flash-forward rerun (flash bwd recomputes its own
         # tiles from q/k/v; rerunning fwd for ctx would be pure waste)
@@ -312,8 +331,10 @@ def gpt_block_prelude_fn(config: GPTConfig):
     return prelude
 
 
-def gpt_block_fn(config: GPTConfig):
-    prelude = gpt_block_prelude_fn(config)
+def gpt_block_fn(config: GPTConfig, mesh=None):
+    """``mesh``: the mesh GSPMD partitions the caller over, if any (see
+    ``_attention``)."""
+    prelude = gpt_block_prelude_fn(config, mesh)
 
     def block(p, x):
         x, up = prelude(p, x)

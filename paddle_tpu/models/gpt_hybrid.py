@@ -164,7 +164,7 @@ def gpt_hidden(params, ids, config: GPTConfig, mesh=None, num_microbatches=1):
         x_spec = _tp.sp_activation_spec(sp.batch_axis) if sp is not None \
             else P(batch_axis, None, None)
         x = jax.lax.with_sharding_constraint(x, NamedSharding(mesh, x_spec))
-    block = gpt_block_fn(config)
+    block = gpt_block_fn(config, mesh)
     from ..distributed.recompute import POLICIES
     pol_name = getattr(config, "remat_policy", "full") or "full"
     if pol_name not in POLICIES:
@@ -412,6 +412,8 @@ class HybridTrainStep:
                                         self._opt_dev_shardings())
 
     def _build(self):
+        from ..framework.compilation_cache import ensure_persistent_cache
+        ensure_persistent_cache()
         config, mesh, M = self.config, self.mesh, self.num_microbatches
         optimizer = self.optimizer
         unflat = self._unflat

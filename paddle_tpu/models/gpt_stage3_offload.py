@@ -27,9 +27,8 @@ TPU-native design (no CUDA-style manual prefetch hooks):
   accumulator. That implicit placement is exactly what the on-chip smoke
   (``tools_stage3_smoke.py``) validates: at 6.7B the ``[L, ...]`` gradient
   alone exceeds HBM, so a refactor that lets XLA hoist the accumulator
-  chip-side fails immediately with an OOM instead of silently regressing
-  (the 2.7B streamed-offload run in TPU_SMOKE.log is the same guard at the
-  scale already captured on hardware). Keep that in mind before touching
+  chip-side fails immediately with an OOM instead of silently regressing.
+  Keep that in mind before touching
   the ``device_put`` placement in ``hidden``'s scan body.
 - The optimizer update for block params runs over host-resident p/g/m/v in
   one of two modes:

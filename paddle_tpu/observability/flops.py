@@ -1,19 +1,17 @@
 """Model-FLOP estimators — the SINGLE source for every MFU number.
 
-bench.py (the BENCH_* trajectory), tools_mfu_sweep.py and the live step
-telemetry (observability/step_telemetry.py) all consume these, so the
-offline bench numbers and the live in-run MFU can never diverge by using
-different formulas.
-
-Pure python on purpose: bench.py's parent process must stay jax-free
-(signal safety), so nothing here may import jax at module scope.
+bench.py, tools_mfu_sweep.py and the live step telemetry
+(observability/step_telemetry.py) all consume these, so the offline bench
+numbers and the live in-run MFU can never diverge by using different
+formulas.
 """
 from __future__ import annotations
 
 
 def peak_flops_bf16(device_kind: str) -> float:
     """Per-chip bf16 peak by device kind (marketing numbers; the MFU
-    denominator)."""
+    denominator). A device that is not in the table is an error, never a
+    default: an MFU against the wrong peak is a wrong number."""
     dk = (device_kind or "").lower()
     table = {
         "v6": 918e12, "v5p": 459e12, "v5 lite": 197e12, "v5e": 197e12,
@@ -22,7 +20,9 @@ def peak_flops_bf16(device_kind: str) -> float:
     for k, v in table.items():
         if k in dk:
             return v
-    return 197e12  # conservative default
+    raise ValueError(
+        f"no bf16 peak FLOP/s known for device_kind {device_kind!r}; "
+        f"known TPU kinds: {sorted(table)}")
 
 
 def model_flops_per_token(cfg, seq_len):

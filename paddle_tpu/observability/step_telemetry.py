@@ -278,12 +278,11 @@ class StepSampler:
 
 
 def default_peak_flops():
-    """Per-process peak FLOP/s: per-chip bf16 peak x local device count."""
-    try:
-        import jax
-        from .flops import peak_flops_bf16
-        devs = jax.devices()
-        return peak_flops_bf16(getattr(devs[0], "device_kind", "")) \
-            * len(devs)
-    except Exception:  # noqa: BLE001
+    """Per-process peak FLOP/s: per-chip bf16 peak x local device count.
+    None (no MFU) off-TPU — a CPU rate over a TPU peak is not a number."""
+    import jax
+    from .flops import peak_flops_bf16
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
         return None
+    return peak_flops_bf16(devs[0].device_kind) * len(devs)

@@ -60,7 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 logger = logging.getLogger("paddle_tpu.fused_collectives")
 
-_VMEM = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM)
+_VMEM = pl.BlockSpec(memory_space=pltpu.VMEM)
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 # distinct Mosaic collective ids per kernel family (barrier semaphores of
@@ -166,15 +166,8 @@ def _compiler_params(name, interpret):
     is never DCE'd. Interpret mode takes none."""
     if interpret:
         return {}
-    for cls_name in ("TPUCompilerParams", "CompilerParams"):
-        cls = getattr(pltpu, cls_name, None)
-        if cls is not None:
-            try:
-                return {"compiler_params": cls(collective_id=_CID[name],
-                                               has_side_effects=True)}
-            except TypeError:
-                return {"compiler_params": cls(collective_id=_CID[name])}
-    return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        collective_id=_CID[name], has_side_effects=True)}
 
 
 def _barrier(interpret):

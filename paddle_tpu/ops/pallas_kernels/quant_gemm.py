@@ -107,19 +107,22 @@ def quant_gemm_kernel(x, wq, scale, block_n=128, block_k=128,
     bk = min(block_k, K)
     nk = K // bk
 
-    return pl.pallas_call(
-        functools.partial(_quant_gemm_kernel, nk=nk, out_dtype=x.dtype),
-        grid=(F // bn, nk),
-        in_specs=[
-            pl.BlockSpec((R, bk), lambda f, k: (0, k)),
-            pl.BlockSpec((bk, bn), lambda f, k: (k, f)),
-            pl.BlockSpec((1, bn), lambda f, k: (0, f)),
-        ],
-        out_specs=pl.BlockSpec((R, bn), lambda f, k: (0, f)),
-        out_shape=jax.ShapeDtypeStruct((R, F), x.dtype),
-        scratch_shapes=[pltpu.VMEM((R, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, wq, scale.reshape(1, F).astype(jnp.float32))
+    # Mosaic rejects x64-typed index math (the index maps' literal 0 becomes
+    # an i64 under the framework's global x64 flag): pin 32-bit types.
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            functools.partial(_quant_gemm_kernel, nk=nk, out_dtype=x.dtype),
+            grid=(F // bn, nk),
+            in_specs=[
+                pl.BlockSpec((R, bk), lambda f, k: (0, k)),
+                pl.BlockSpec((bk, bn), lambda f, k: (k, f)),
+                pl.BlockSpec((1, bn), lambda f, k: (0, f)),
+            ],
+            out_specs=pl.BlockSpec((R, bn), lambda f, k: (0, f)),
+            out_shape=jax.ShapeDtypeStruct((R, F), x.dtype),
+            scratch_shapes=[pltpu.VMEM((R, bn), jnp.float32)],
+            interpret=interpret,
+        )(x, wq, scale.reshape(1, F).astype(jnp.float32))
 
 
 def quant_gemm(x, wq, scale, use_kernel=False, interpret=False):

@@ -625,6 +625,8 @@ class Engine:
                                      self.adapters.row_bytes())
             metrics.set_adapter_residency(0, 0)
 
+        from ..framework.compilation_cache import ensure_persistent_cache
+        ensure_persistent_cache()
         cfg = _cfg_key(config)
         donate_ok = jax.default_backend() != "cpu"  # cpu: donation unimplemented
         B = self.num_slots

@@ -49,7 +49,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..distributed.env import shard_map_compat
 from ..models.generation import _final_ln
 from ..models.gpt import ln_fp32
 from ..ops.pallas_kernels.quant_gemm import lora_delta, compose_delta
@@ -386,10 +385,10 @@ def mp_paged_forward(params, config, ids, kc, vc, start, valid, table,
                              P(None, None, None, "mp"))
                       for name in slabs}]
         args += [aid_arr, slabs]
-    mapped = shard_map_compat(
-        device_fn, mesh,
+    mapped = jax.shard_map(
+        device_fn, mesh=mesh,
         in_specs=tuple(in_specs),
-        out_specs=(P(None, None), KV_SPEC, KV_SPEC))
+        out_specs=(P(None, None), KV_SPEC, KV_SPEC), check_vma=False)
     return mapped(*args)
 
 

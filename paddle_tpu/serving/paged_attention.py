@@ -91,12 +91,29 @@ def paged_kernel_supported(nh, d, page_size, why=""):
 # Pallas TPU kernel: one-token decode through the page table
 
 
-def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, page_size, scale):
+def _decode_kernel(*refs, page_size, scale, quant):
     """Grid (B, MP): slot b sweeps its logical pages j; the BlockSpec
     index_map already resolved logical->physical through the prefetched
-    table, so k_ref/v_ref hold THIS slot's j-th page. Online softmax state
-    (m, l, acc) lives in VMEM scratch across the page sweep."""
+    table, so k_ref/v_ref hold THIS slot's j-th page [ps, nh, d]. Online
+    softmax state (m, l, acc) lives in VMEM scratch across the page sweep.
+
+    The page is walked one key position at a time on the VPU: position s
+    is a native [nh, d] tile, its score column is a lane reduction and its
+    context contribution a broadcast multiply-add. No dot_general: the
+    per-head contraction "hd,shd->hs" has its batch dim in the middle of
+    the page and no free lhs dim, which Mosaic's dot lowering refuses
+    (TPU_DotDimensionNumbersAttr 'lhs_non_contracting_dims', jax 0.9.0).
+
+    ``quant``: the pool holds int8/fp8 values and the per-PAGE dequant
+    scales arrive as two more scalar-prefetch operands — scores scale
+    after the q.k reduction, v contributions inside the ctx accumulation,
+    so the fp K/V bytes never exist in HBM."""
+    if quant:
+        (table_ref, pos_ref, ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref,
+         m_ref, l_ref, acc_ref) = refs
+    else:
+        (table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+         m_ref, l_ref, acc_ref) = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -107,29 +124,34 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
+    k_scale = scale
+    if quant:
+        phys = table_ref[b * nj + j]
+        k_scale = scale * ksc_ref[phys]
     q = q_ref[0].astype(jnp.float32)                     # [nh, d]
-    k = k_ref[0].astype(jnp.float32)                     # [ps, nh, d]
-    v = v_ref[0].astype(jnp.float32)
-    s = jnp.einsum("hd,shd->hs", q, k,
-                   preferred_element_type=jnp.float32) * scale  # [nh, ps]
-    key_pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)                    # [1, ps]
-    valid = key_pos <= pos_ref[b]
-    s = jnp.where(valid, s, -jnp.inf)
+    last = pos_ref[b]
+    cols = []                                            # ps x [nh, 1]
+    for s in range(page_size):
+        c = jnp.sum(q * k_ref[0, s].astype(jnp.float32), axis=-1,
+                    keepdims=True) * k_scale
+        cols.append(jnp.where(j * page_size + s <= last, c, -jnp.inf))
 
     m_prev = m_ref[:, :1]                                # [nh, 1]
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    m_new = m_prev
+    for c in cols:
+        m_new = jnp.maximum(m_new, c)
     # fully-masked pages keep m at -inf; guard the exp(-inf - -inf) NaNs
     alpha = jnp.where(m_prev == -jnp.inf, 0.0, jnp.exp(m_prev - m_new))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)        # [nh, ps]
-    l_ref[:] = jnp.broadcast_to(alpha * l_prev +
-                                jnp.sum(p, axis=-1, keepdims=True),
-                                l_ref.shape)
+    l_new = alpha * l_ref[:, :1]
+    pv = jnp.zeros(acc_ref.shape, jnp.float32)           # [nh, d]
+    for s, c in enumerate(cols):
+        p = jnp.where(c == -jnp.inf, 0.0, jnp.exp(c - m_new))
+        l_new = l_new + p
+        pv = pv + p * v_ref[0, s].astype(jnp.float32)
+    if quant:
+        pv = pv * vsc_ref[phys]
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    # ctx update: [nh, ps] x [ps, nh, d] -> per-head [nh, d]
-    pv = jnp.einsum("hs,shd->hd", p, v,
-                    preferred_element_type=jnp.float32)
     acc_ref[:] = acc_ref[:] * alpha + pv
 
     @pl.when(j == nj - 1)
@@ -137,54 +159,48 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
-def _decode_kernel_q(table_ref, pos_ref, ksc_ref, vsc_ref, q_ref, k_ref,
-                     v_ref, o_ref, m_ref, l_ref, acc_ref, *, page_size,
-                     scale):
-    """Quantized-KV variant of ``_decode_kernel``: the pool holds int8/
-    fp8 values and the per-PAGE dequant scales arrive as scalar-prefetch
-    operands — the dequant multiply lives INSIDE the online-softmax page
-    sweep (scores scale after the q·k dot, v contributions scale inside
-    the ctx accumulation), so the fp K/V bytes never exist in HBM."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
+def _paged_decode_call(q, kc_l, vc_l, table, pos, scales, page_size,
+                       interpret):
+    """pallas_call shared by the fp and quantized-pool entry points:
+    ``scales`` is () or (ksc_l, vsc_l) [P] fp32, prefetched to SMEM after
+    the flat table and pos."""
+    B, nh, d = q.shape
+    MP = table.shape[1]
 
-    @pl.when(j == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def q_map(b, j, *prefetch):
+        return (b, 0, 0)
 
-    MP = nj
-    phys = table_ref[b * MP + j]
-    ks = ksc_ref[phys]
-    vs = vsc_ref[phys]
-    q = q_ref[0].astype(jnp.float32)                     # [nh, d]
-    k = k_ref[0].astype(jnp.float32)                     # [ps, nh, d]
-    v = v_ref[0].astype(jnp.float32)
-    s = jnp.einsum("hd,shd->hs", q, k,
-                   preferred_element_type=jnp.float32) * scale * ks
-    key_pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)                    # [1, ps]
-    valid = key_pos <= pos_ref[b]
-    s = jnp.where(valid, s, -jnp.inf)
+    def page_map(b, j, tab, *prefetch):
+        return (tab[b * MP + j], 0, 0, 0)
 
-    m_prev = m_ref[:, :1]                                # [nh, 1]
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.where(m_prev == -jnp.inf, 0.0, jnp.exp(m_prev - m_new))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)        # [nh, ps]
-    l_ref[:] = jnp.broadcast_to(alpha * l_prev +
-                                jnp.sum(p, axis=-1, keepdims=True),
-                                l_ref.shape)
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    pv = jnp.einsum("hs,shd->hd", p, v,
-                    preferred_element_type=jnp.float32) * vs
-    acc_ref[:] = acc_ref[:] * alpha + pv
-
-    @pl.when(j == nj - 1)
-    def _():
-        o_ref[0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2 + len(scales),   # flat table, pos[, scales]
+        grid=(B, MP),
+        in_specs=[
+            pl.BlockSpec((1, nh, d), q_map),
+            pl.BlockSpec((1, page_size, nh, d), page_map),
+            pl.BlockSpec((1, page_size, nh, d), page_map),
+        ],
+        out_specs=pl.BlockSpec((1, nh, d), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((nh, 128), jnp.float32),      # m (lane-broadcast)
+            pltpu.VMEM((nh, 128), jnp.float32),      # l
+            pltpu.VMEM((nh, d), jnp.float32),        # acc
+        ],
+    )
+    kernel = functools.partial(_decode_kernel, page_size=page_size,
+                               scale=1.0 / (d ** 0.5), quant=bool(scales))
+    # Mosaic rejects x64-typed index math; the framework enables x64 globally
+    # for dtype parity, so pin 32-bit types inside the kernel trace.
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, nh, d), jnp.float32),
+            interpret=interpret,
+        )(table.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
+          *(sc.astype(jnp.float32) for sc in scales),
+          q.astype(jnp.float32), kc_l, vc_l)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
@@ -193,39 +209,8 @@ def paged_decode_attention_q(q, kc_l, vc_l, table, pos, ksc_l, vsc_l, *,
     """Quantized-pool one-token paged attention: like
     ``paged_decode_attention`` plus per-page dequant scales ksc_l/vsc_l
     [P] (fp32) prefetched to SMEM and applied inside the page sweep."""
-    B, nh, d = q.shape
-    MP = table.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # flat table, pos, k scales, v scales
-        grid=(B, MP),
-        in_specs=[
-            pl.BlockSpec((1, nh, d),
-                         lambda b, j, tab, pos, ks, vs: (b, 0, 0)),
-            pl.BlockSpec((1, page_size, nh, d),
-                         lambda b, j, tab, pos, ks, vs:
-                         (tab[b * MP + j], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, nh, d),
-                         lambda b, j, tab, pos, ks, vs:
-                         (tab[b * MP + j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, nh, d),
-                               lambda b, j, tab, pos, ks, vs: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nh, 128), jnp.float32),      # m (lane-broadcast)
-            pltpu.VMEM((nh, 128), jnp.float32),      # l
-            pltpu.VMEM((nh, d), jnp.float32),        # acc
-        ],
-    )
-    kernel = functools.partial(_decode_kernel_q, page_size=page_size,
-                               scale=1.0 / (d ** 0.5))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nh, d), jnp.float32),
-        interpret=interpret,
-    )(table.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
-      ksc_l.astype(jnp.float32), vsc_l.astype(jnp.float32),
-      q.astype(jnp.float32), kc_l, vc_l)
+    return _paged_decode_call(q, kc_l, vc_l, table, pos, (ksc_l, vsc_l),
+                              page_size, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
@@ -234,34 +219,8 @@ def paged_decode_attention(q, kc_l, vc_l, table, pos, *, page_size,
     """One-token paged attention: q [B, nh, d] (fp32), kc_l/vc_l
     [P, page_size, nh, d], table [B, MP], pos [B] -> ctx [B, nh, d] fp32.
     Unmapped table entries are 0 (trash page) and masked by pos."""
-    B, nh, d = q.shape
-    MP = table.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # flat table [B*MP], pos [B]
-        grid=(B, MP),
-        in_specs=[
-            pl.BlockSpec((1, nh, d), lambda b, j, tab, pos: (b, 0, 0)),
-            pl.BlockSpec((1, page_size, nh, d),
-                         lambda b, j, tab, pos: (tab[b * MP + j], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, nh, d),
-                         lambda b, j, tab, pos: (tab[b * MP + j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, nh, d), lambda b, j, tab, pos: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nh, 128), jnp.float32),      # m (lane-broadcast)
-            pltpu.VMEM((nh, 128), jnp.float32),      # l
-            pltpu.VMEM((nh, d), jnp.float32),        # acc
-        ],
-    )
-    kernel = functools.partial(_decode_kernel, page_size=page_size,
-                               scale=1.0 / (d ** 0.5))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nh, d), jnp.float32),
-        interpret=interpret,
-    )(table.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
-      q.astype(jnp.float32), kc_l, vc_l)
+    return _paged_decode_call(q, kc_l, vc_l, table, pos, (), page_size,
+                              interpret)
 
 
 # ---------------------------------------------------------------------------

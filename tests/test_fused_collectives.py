@@ -19,6 +19,8 @@ CPU mesh in Pallas interpret mode:
 """
 import re
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -31,11 +33,14 @@ from paddle_tpu import nn, profiler
 from paddle_tpu.distributed import env as dist_env
 from paddle_tpu.distributed import comm_backend, grad_comm
 from paddle_tpu.distributed import tp_overlap as tp
-from paddle_tpu.distributed.env import shard_map_compat
 from paddle_tpu.models.gpt import GPTConfig
 from paddle_tpu.models.gpt_hybrid import (HybridTrainStep, init_gpt_params,
                                           gpt_hidden)
 from paddle_tpu.ops.pallas_kernels import fused_collectives as fc
+
+# the kernels and ring schedules under test return per-device values under
+# replicated out_specs: replication checking off, as at their call sites
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 _DEF = {
@@ -109,10 +114,10 @@ def test_fused_ag_gemm_bitwise_vs_unfused_reference(devices8):
     w = jnp.asarray(rng.randn(H, F).astype(np.float32))
     specs = dict(in_specs=(P(None, "mp", None), P(None, None)),
                  out_specs=P(None, None, None))
-    fused = shard_map_compat(lambda x, ww: fc.fused_ag_gemm(meta, x, ww),
-                             mesh, **specs)
-    ref = shard_map_compat(lambda x, ww: fc.ag_gemm_reference("mp", n, x, ww),
-                           mesh, **specs)
+    fused = shard_map(lambda x, ww: fc.fused_ag_gemm(meta, x, ww),
+                             mesh=mesh, **specs)
+    ref = shard_map(lambda x, ww: fc.ag_gemm_reference("mp", n, x, ww),
+                           mesh=mesh, **specs)
     got = jax.jit(fused)(xf, w)
     want = jax.jit(ref)(xf, w)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -133,10 +138,10 @@ def test_fused_gemm_rs_bitwise_vs_unfused_reference(devices8):
     w = jnp.asarray(rng.randn(F, H).astype(np.float32))
     specs = dict(in_specs=(P(None, None, "mp"), P("mp", None)),
                  out_specs=P(None, "mp", None))
-    fused = shard_map_compat(lambda y, ww: fc.fused_gemm_rs(meta, y, ww),
-                             mesh, **specs)
-    ref = shard_map_compat(lambda y, ww: fc.gemm_rs_reference("mp", n, y, ww),
-                           mesh, **specs)
+    fused = shard_map(lambda y, ww: fc.fused_gemm_rs(meta, y, ww),
+                             mesh=mesh, **specs)
+    ref = shard_map(lambda y, ww: fc.gemm_rs_reference("mp", n, y, ww),
+                           mesh=mesh, **specs)
     got = jax.jit(fused)(yf, w)
     want = jax.jit(ref)(yf, w)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -171,8 +176,8 @@ def test_fused_vjp_bitwise_vs_unfused_schedule(devices8):
     specs = dict(
         in_specs=(P(None, "mp", None), P(None, None), P(None, None, None)),
         out_specs=(P(None, "mp", None), P(None, None)))
-    got = jax.jit(shard_map_compat(fused_bwd, mesh, **specs))(xf, w, g)
-    want = jax.jit(shard_map_compat(ref_bwd, mesh, **specs))(xf, w, g)
+    got = jax.jit(shard_map(fused_bwd, mesh=mesh, **specs))(xf, w, g)
+    want = jax.jit(shard_map(ref_bwd, mesh=mesh, **specs))(xf, w, g)
     for a, b in zip(got, want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # end-to-end: grads of a column->gelu->row chain agree with the dense
@@ -183,8 +188,8 @@ def test_fused_vjp_bitwise_vs_unfused_schedule(devices8):
         local = jnp.sum(fc.fused_gemm_rs(meta, jax.nn.gelu(up), w2) ** 2)
         return lax.psum(local, "mp")    # seq-sharded output: global sum
 
-    smap = shard_map_compat(
-        loss_fused, mesh,
+    smap = shard_map(
+        loss_fused, mesh=mesh,
         in_specs=(P(None, "mp", None), P(None, "mp"), P("mp", None)),
         out_specs=P())
     w1 = jnp.asarray(rng.randn(H, F).astype(np.float32) * 0.2)
@@ -210,12 +215,12 @@ def test_fused_rs_bucket_bitwise_incl_bf16_wire(devices8):
     xall = jnp.asarray(rng.randn(n, n, 64).astype(np.float32))
 
     for wire in (None, jnp.bfloat16):
-        fused = shard_map_compat(
+        fused = shard_map(
             lambda x: fc.fused_rs_bucket(meta, x, wire),
-            mesh, in_specs=P("dp", None), out_specs=P("dp"))
-        ref = shard_map_compat(
+            mesh=mesh, in_specs=P("dp", None), out_specs=P("dp"))
+        ref = shard_map(
             lambda x: fc.rs_bucket_reference("dp", n, x, wire),
-            mesh, in_specs=P("dp", None), out_specs=P("dp"))
+            mesh=mesh, in_specs=P("dp", None), out_specs=P("dp"))
         got = jax.jit(fused)(xall.reshape(n * n, 64))
         want = jax.jit(ref)(xall.reshape(n * n, 64))
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -230,12 +235,12 @@ def test_fused_ag_bucket_matches_all_gather(devices8):
     meta = fc.meta_for(mesh, "dp", interpret=True)
     rng = np.random.RandomState(4)
     rows = jnp.asarray(rng.randn(n, 32).astype(np.float32))
-    fused = shard_map_compat(
+    fused = shard_map(
         lambda r: fc.fused_ag_bucket(meta, r[0]),
-        mesh, in_specs=P("dp", None), out_specs=P(None, None))
-    ref = shard_map_compat(
+        mesh=mesh, in_specs=P("dp", None), out_specs=P(None, None))
+    ref = shard_map(
         lambda r: lax.all_gather(r[0], "dp", tiled=False),
-        mesh, in_specs=P("dp", None), out_specs=P(None, None))
+        mesh=mesh, in_specs=P("dp", None), out_specs=P(None, None))
     got = jax.jit(fused)(rows)
     want = jax.jit(ref)(rows)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -85,7 +85,6 @@ def test_artifact_loads_without_model_code(tmp_path):
     import subprocess, sys, os
     code = f"""
 import sys; sys.path.insert(0, {repr(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))})
-import jax; jax.config.update("jax_platforms", "cpu")  # jax pre-imported: env too late
 import numpy as np
 from paddle_tpu import inference
 pred = inference.Predictor({prefix!r})

@@ -24,6 +24,8 @@ bytes, never changes math. Plus:
   * a ServingSupervisor replica is an mp GROUP (mp_replica_meshes +
     one-arg engine factory), surviving replica kill with zero drops.
 """
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -36,6 +38,10 @@ from paddle_tpu.models.generation import generate_from_params
 from paddle_tpu.models.gpt import GPTConfig
 from paddle_tpu.models.gpt_hybrid import init_gpt_params
 from paddle_tpu.ops.pallas_kernels import fused_collectives as fc
+
+# the kernels and ring schedules under test return per-device values under
+# replicated out_specs: replication checking off, as at their call sites
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 # vocab divisible by 4: the sharded-lm-head path. CFG_ODD (97) covers the
 # replicated-head fallback.
@@ -269,15 +275,14 @@ def test_fused_gemm_ag_bitwise(devices8):
     x = jax.random.normal(jax.random.key(0), (3, 2, 64))
     w = jax.random.normal(jax.random.key(1), (64, 128))
     from jax.sharding import PartitionSpec as P
-    from paddle_tpu.distributed.env import shard_map_compat
 
     full = jax.jit(lambda x, w: x @ w)(x, w)
-    fused = shard_map_compat(
-        lambda xs, ws: fc.fused_gemm_ag(meta, xs, ws), mesh,
+    fused = shard_map(
+        lambda xs, ws: fc.fused_gemm_ag(meta, xs, ws), mesh=mesh,
         in_specs=(P(), P(None, "mp")), out_specs=P())(x, w)
     assert (np.asarray(fused) == np.asarray(full)).all()
-    ref = shard_map_compat(
-        lambda xs, ws: fc.gemm_ag_reference("mp", 4, xs, ws), mesh,
+    ref = shard_map(
+        lambda xs, ws: fc.gemm_ag_reference("mp", 4, xs, ws), mesh=mesh,
         in_specs=(P(), P(None, "mp")), out_specs=P())(x, w)
     assert (np.asarray(ref) == np.asarray(full)).all()
 
@@ -286,6 +291,12 @@ def test_fused_gemm_ag_bitwise(devices8):
 # handoff, swap, errors
 
 
+@pytest.mark.skip(reason=(
+    "XLA:CPU's SPMD partitioner CHECK-fails (spmd_partitioner_util.h:117, "
+    "PadToShape under HandleScatter) compiling the sequence-parallel "
+    "HybridTrainStep at seq 32 on jaxlib 0.9.0 — an abort that takes the "
+    "whole pytest process down, so it cannot run as a failing test "
+    "(ROADMAP D6)"))
 def test_hybrid_train_step_sharded_handoff(devices8):
     """An mp-trained HybridTrainStep tree (head-major, device-sharded)
     serves directly: no host gather, no double permute, bitwise parity

@@ -228,7 +228,8 @@ def test_step_telemetry_sampled_records():
     from paddle_tpu.observability.flops import train_step_flops
     flops, _ = train_step_flops(CFG, 2, 16)
     assert c["flops_per_step"] == flops
-    assert c["last_mfu"] is not None and 0 < c["last_mfu"] < 1
+    # no MFU off-TPU: a CPU rate is never divided by a TPU peak
+    assert c["last_mfu"] is None
     recs = step_telemetry.records()
     assert len(recs) == 4
     assert recs[-1]["tokens"] == 2 * 16

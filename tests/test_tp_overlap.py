@@ -5,6 +5,8 @@ flags-off bitwise trajectory invariance, mp comm counters (RS+AG replacing
 the per-block all-reduces), 1/mp activation claim, mp_layers wiring, the
 grad_comm dp x mp composition, and the satellite fixes (split validation,
 ParallelCrossEntropy, DataLoader prefetch_factor)."""
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -19,6 +21,10 @@ from paddle_tpu.distributed import tp_overlap as tp
 from paddle_tpu.models.gpt import GPTConfig, gpt_block_fn
 from paddle_tpu.models.gpt_hybrid import HybridTrainStep, init_gpt_params, \
     gpt_hidden
+
+# the kernels and ring schedules under test return per-device values under
+# replicated out_specs: replication checking off, as at their call sites
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 _DEF = {
@@ -80,14 +86,13 @@ def test_ring_kernels_match_dense_fwd_and_grad(devices8):
     w1 = jnp.asarray(rng.randn(H, F).astype(np.float32) * 0.2)
     w2 = jnp.asarray(rng.randn(F, H).astype(np.float32) * 0.2)
 
-    from paddle_tpu.distributed.env import shard_map_compat
 
     def f(xf, w1, w2):
         up = tp.ring_ag_gemm(xf, w1, "mp", mp)
         up = jax.nn.gelu(up)
         return tp.gemm_ring_rs(up, w2, "mp", mp)
 
-    smap = shard_map_compat(f, mesh,
+    smap = shard_map(f, mesh=mesh,
                             in_specs=(P(None, "mp", None), P(None, "mp"),
                                       P("mp", None)),
                             out_specs=P(None, "mp", None))
@@ -113,13 +118,12 @@ def test_seq_ag_rs_roundtrip(devices8):
     mp = 4
     mesh = Mesh(np.array(jax.devices()[:mp]), ("mp",))
     x = jnp.arange(2 * 8 * 4, dtype=jnp.float32).reshape(2, 8, 4)
-    from paddle_tpu.distributed.env import shard_map_compat
 
     def f(xs):
         full = tp.seq_all_gather(xs, "mp", mp)
         return tp.seq_reduce_scatter(full, "mp", mp) / mp
 
-    smap = shard_map_compat(f, mesh, in_specs=P(None, "mp", None),
+    smap = shard_map(f, mesh=mesh, in_specs=P(None, "mp", None),
                             out_specs=P(None, "mp", None))
     with mesh:
         out = jax.jit(smap)(x)
@@ -465,7 +469,6 @@ def test_parallel_cross_entropy_ignore_index(devices8):
 
 def test_mp_allreduce_inside_shard_map(devices8):
     from paddle_tpu.distributed.fleet.mp_layers import mp_allreduce
-    from jax.experimental.shard_map import shard_map
     mesh = Mesh(np.array(jax.devices()[:4]), ("mp",))
     dist_env.set_mesh(mesh)
 
@@ -473,8 +476,7 @@ def test_mp_allreduce_inside_shard_map(devices8):
         out = mp_allreduce(x)
         return out._data if hasattr(out, "_data") else out
 
-    g = jax.jit(shard_map(f, mesh=mesh, in_specs=P("mp"), out_specs=P("mp"),
-                          check_rep=False))
+    g = jax.jit(shard_map(f, mesh=mesh, in_specs=P("mp"), out_specs=P("mp")))
     x = np.arange(4, dtype=np.float32)
     np.testing.assert_allclose(np.asarray(g(x)), np.full(4, x.sum()))
 

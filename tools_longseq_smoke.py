@@ -4,12 +4,13 @@ at S=8k/16k/32k, single chip (the sp>1 ring path is validated on the
 virtual mesh in dryrun_multichip; this measures the per-chip kernel the
 ring schedule runs between ppermute steps).
 
-Prints one line per config; append winners to TPU_SMOKE.log.
+Prints one line per config.
 """
+import os
 import sys
 import time
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def main():

@@ -65,7 +65,9 @@ def train_rung(steps=8, verbose=True):
         assert c["sampled"] == steps, c
         assert c["last_dispatch_s"] is not None
         assert c["last_sync_s"] is not None
-        assert c["last_mfu"] is not None and c["last_mfu"] > 0
+        # MFU exists only against a known TPU peak; off-TPU it is None
+        on_tpu = jax.default_backend() == "tpu"
+        assert (c["last_mfu"] is not None and c["last_mfu"] > 0) == on_tpu
         assert c["flops_per_step"] > 0
         if verbose:
             print(f"TRAIN rung: {obs.step_summary()}", flush=True)

@@ -4,13 +4,12 @@ GPT training on a single 16 GB chip backed by host RAM.
 
   python tools_stage3_smoke.py 6.7B [stream|host]
   python tools_stage3_smoke.py 13B  [stream|host]
-
-Append results to TPU_SMOKE.log.
 """
+import os
 import sys
 import time
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def main():
@@ -21,7 +20,8 @@ def main():
     import paddle_tpu as paddle
     from paddle_tpu.models.gpt import GPT_CONFIGS
     from paddle_tpu.models.gpt_stage3_offload import Stage3OffloadTrainStep
-    from bench import model_flops_per_token, peak_flops_bf16
+    from paddle_tpu.observability.flops import (model_flops_per_token,
+                                                peak_flops_bf16)
 
     assert jax.default_backend() == "tpu", jax.devices()
     if model == "tiny":
@@ -60,7 +60,7 @@ def main():
     dt = (time.perf_counter() - t1) / steps
     tok_s = batch * seq / dt
     fpt, _ = model_flops_per_token(cfg, seq)
-    peak = peak_flops_bf16(getattr(jax.devices()[0], "device_kind", ""))
+    peak = peak_flops_bf16(jax.devices()[0].device_kind)
     print(f"STAGE3 {name} bs={batch} seq={seq} update={update}: "
           f"{tok_s:.1f} tok/s, {dt:.2f} s/step, "
           f"MFU {tok_s*fpt/peak*100:.1f}%, "
