@@ -533,8 +533,12 @@ class HybridTrainStep:
             self.opt_state = self._move_opt(self.opt_state,
                                             self._opt_dev_shardings())
         t_tel = self._tel.begin(self._step_count)
-        loss, flat_params, self.opt_state = self._jitted(
-            flat_params, self.opt_state, ids, lr)
+        # the dispatch as a step on the profiler's clock (a no-op check
+        # outside a profiler session)
+        with jax.profiler.StepTraceAnnotation("pt.train.step",
+                                              step_num=self._step_count):
+            loss, flat_params, self.opt_state = self._jitted(
+                flat_params, self.opt_state, ids, lr)
         if t_tel is not None:
             from ..observability.flops import train_step_flops
             B, S = ids.shape

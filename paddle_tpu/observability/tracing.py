@@ -21,6 +21,12 @@ Perfetto-loadable Chrome-trace JSON (``export_perfetto``) or stream to a
 structured JSONL sink (``add_sink`` / ``JsonlTraceSink``). Everything is
 host-side: tracing on/off never changes a compiled executable, a traced
 operand, or a trace counter.
+
+A tracing engine also hands in every boundary's phase spans
+(``collect_boundary``: ``pt.serve.step`` around ``pt.serve.admit | feed |
+wait | emit``, the floats of the engine's phase clock). They export onto one
+engine track (pid = engine tag, thread ``boundaries``), so the trace shows
+what the host did between two ``decode_step`` spans of a request.
 """
 from __future__ import annotations
 
@@ -85,7 +91,10 @@ class RequestTrace:
 _lock = threading.Lock()
 _done = deque(maxlen=4096)
 _seen = set()        # request_ids currently in the ring: first-wins dedup
+_steps = deque(maxlen=4096)   # engine boundaries: {"engine", "spans"}
 _sinks = []
+# the engine track's thread id: beside the request ids, which count up from 0
+BOUNDARY_TID = 2 ** 31 - 1
 
 
 def _maxlen():
@@ -149,16 +158,35 @@ def collect(req, engine_tag="engine"):
     return rec
 
 
+def collect_boundary(engine_tag, spans):
+    """Archive one engine boundary's phase spans (called by ``Engine.step``
+    of a tracing engine). The ring is bounded by ``FLAGS_trace_buffer``
+    like the request ring."""
+    with _lock:
+        global _steps
+        ml = _maxlen()
+        if _steps.maxlen != ml:
+            _steps = deque(_steps, maxlen=ml)
+        _steps.append({"engine": str(engine_tag), "spans": spans})
+
+
 def traces():
     """Snapshot of the collected finished-request traces (newest last)."""
     with _lock:
         return [dict(r, spans=[dict(s) for s in r["spans"]]) for r in _done]
 
 
+def boundaries():
+    """Snapshot of the collected engine boundaries (newest last)."""
+    with _lock:
+        return [dict(r, spans=[dict(s) for s in r["spans"]]) for r in _steps]
+
+
 def clear():
     with _lock:
         _done.clear()
         _seen.clear()
+        _steps.clear()
 
 
 def add_sink(fn):
@@ -202,15 +230,16 @@ class JsonlTraceSink:
 
 def chrome_events(records=None):
     """Chrome-trace event list from finished-trace records (default: the
-    collected ring). pid = engine tag, tid = request id, ts/dur in µs on
-    the perf_counter timeline; instants export as ph='i'."""
+    collected ring, and with it the collected engine boundaries). pid =
+    engine tag, tid = request id or the engine's ``boundaries`` thread,
+    ts/dur in µs on the perf_counter timeline; instants export as ph='i'."""
     events = []
     seen_pids = {}
     seen_tids = set()
-    for rec in (traces() if records is None else records):
+    for rec in (traces() + boundaries() if records is None else records):
         new_pid = rec["engine"] not in seen_pids
         pid = seen_pids.setdefault(rec["engine"], len(seen_pids) + 1)
-        tid = rec["request_id"]
+        tid = rec.get("request_id", BOUNDARY_TID)
         for ev in rec["spans"]:
             ts = ev["t0"] * 1e6
             dur = (ev["t1"] - ev["t0"]) * 1e6
@@ -231,13 +260,16 @@ def chrome_events(records=None):
             seen_tids.add((pid, tid))
             events.append({"name": "thread_name", "ph": "M", "pid": pid,
                            "tid": tid,
-                           "args": {"name": f"request {tid}"}})
+                           "args": {"name": "boundaries"
+                                    if tid == BOUNDARY_TID
+                                    else f"request {tid}"}})
     return events
 
 
 def export_perfetto(path, records=None):
-    """Write the collected request traces as Chrome-trace JSON (loads in
-    Perfetto / chrome://tracing / TensorBoard). Returns the path."""
+    """Write the collected request traces and engine boundaries as
+    Chrome-trace JSON (loads in Perfetto / chrome://tracing / TensorBoard).
+    Returns the path."""
     payload = {"traceEvents": chrome_events(records),
                "displayTimeUnit": "ms"}
     with open(path, "w") as f:

@@ -275,8 +275,10 @@ def _pallas_forward(q, k, v, causal, block_q=256, block_k=256,
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     # Mosaic rejects x64-typed index math; the framework enables x64 globally
-    # for dtype parity, so pin 32-bit types inside the kernel trace.
-    with jax.enable_x64(False):
+    # for dtype parity, so pin 32-bit types inside the kernel trace. The
+    # scope names the device op (%flash_fwd.N in a chip trace) whatever
+    # wraps the call: custom_vjp, remat, a scan.
+    with jax.enable_x64(False), jax.named_scope("flash_fwd"):
         result = pl.pallas_call(
             functools.partial(_fwd_kernel, **kw),
             out_shape=out_shape,

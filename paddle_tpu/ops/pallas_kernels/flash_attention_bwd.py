@@ -281,35 +281,39 @@ def flash_attention_backward(q, k, v, o, lse, do, scale, causal,
 
     with jax.enable_x64(False):
         # dQ: grid (BH, q-block, k-block); k is the reduction (arbitrary) dim
+        # (the scopes name the device ops: %flash_bwd_dq.N, %flash_bwd_dkv.N)
         ops, builders = shared_operands()
         qm, km = (lambda b, i, j: i), (lambda b, i, j: j)
-        dq = pl.pallas_call(
-            functools.partial(_dq_kernel, nk=nk, **common),
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-            grid=(BH, nq, nk),
-            in_specs=[mk(qm, km) for mk in builders],
-            out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            compiler_params=params,
-            interpret=interpret,
-        )(*ops)
+        with jax.named_scope("flash_bwd_dq"):
+            dq = pl.pallas_call(
+                functools.partial(_dq_kernel, nk=nk, **common),
+                out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+                grid=(BH, nq, nk),
+                in_specs=[mk(qm, km) for mk in builders],
+                out_specs=pl.BlockSpec((1, block_q, D),
+                                       lambda b, i, j: (b, i, 0)),
+                scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+                compiler_params=params,
+                interpret=interpret,
+            )(*ops)
 
         # dK/dV: grid (BH, k-block, q-block); q is the reduction dim
         ops, builders = shared_operands()
         qm, km = (lambda b, j, i: i), (lambda b, j, i: j)
-        dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, nq=nq, **common),
-            out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype)),
-            grid=(BH, nk, nq),
-            in_specs=[mk(qm, km) for mk in builders],
-            out_specs=(
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            ),
-            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                            pltpu.VMEM((block_k, D), jnp.float32)],
-            compiler_params=params,
-            interpret=interpret,
-        )(*ops)
+        with jax.named_scope("flash_bwd_dkv"):
+            dk, dv = pl.pallas_call(
+                functools.partial(_dkv_kernel, nq=nq, **common),
+                out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
+                           jax.ShapeDtypeStruct(v.shape, v.dtype)),
+                grid=(BH, nk, nq),
+                in_specs=[mk(qm, km) for mk in builders],
+                out_specs=(
+                    pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+                    pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+                ),
+                scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                                pltpu.VMEM((block_k, D), jnp.float32)],
+                compiler_params=params,
+                interpret=interpret,
+            )(*ops)
     return dq, dk, dv

@@ -433,10 +433,25 @@ def fault_summary():
 def serving_counters():
     """Snapshot of the serving-engine counters: request lifecycle
     (submitted/admitted/completed/expired/rejected), executable calls and
-    traces, tokens_out, ttft_p50/p99, token_latency_p50, tokens_per_s,
-    occupancy, queue depth — plus the paged-KV ledger (page_occupancy,
-    prefix_hit_rate, prefix_tokens_reused, chunk_steps, cow_copies,
-    prefill_waste_mean). (Thin view over the registry's "serving"
+    traces, tokens_out, ttft_p50/p99, tokens_per_s, occupancy, queue depth
+    — plus the paged-KV ledger (page_occupancy, prefix_hit_rate,
+    prefix_tokens_reused, chunk_steps, cow_copies, prefill_waste_mean).
+
+    The phase clock of ``Engine.step`` (always on): ``step_s`` over
+    ``boundaries`` is the mean boundary, and ``admit_s`` / ``feed_s`` /
+    ``wait_s`` / ``emit_s`` its disjoint phases (with a small remainder
+    they sum to ``step_s``). ``decode_time_s`` / ``prefill_time_s`` are
+    feed + wait of the decode-side and of the chunk / prefill dispatches,
+    each ended by its outputs reaching the host; ``admit_queue_wait_s`` /
+    ``admit_queue_waits`` is submit to admission of admitted requests and
+    ``prefill_span_s`` / ``first_tokens`` admission to the first token.
+    The same phases are ``jax.profiler.TraceAnnotation`` spans
+    (``pt.serve.step`` around ``pt.serve.admit | feed | wait | emit``, a
+    dispatch's feed and wait with ``kind=chunk|decode|draft|verify|
+    pooled``; the trainers' dispatch is ``pt.train.step``): see them in a
+    ``jax.profiler.start_trace`` session, on the device trace's clock, or
+    on the ``boundaries`` thread of ``Engine.export_trace()`` with
+    ``FLAGS_serving_trace`` on. (Thin view over the registry's "serving"
     family.)"""
     from ..observability import collect
     return collect("serving")

@@ -753,6 +753,11 @@ class Engine:
         self._chunk_off = np.zeros(B, np.int32)
         self._admit_seq = np.zeros(B, np.int64)
         self._admit_count = 0
+        # admission (or seating) instant of each slot's request: where its
+        # prefill_span_s starts. Not snapshotted: a restored slot's span
+        # restarts at the restore
+        self._admit_t = [0.0] * B
+        self._clock = metrics.PhaseClock(keep_spans=self.trace_enabled)
         self._results = {}                # request_id -> GenerationResult
 
         # disaggregated serving (serving/kv_transfer.py): role is
@@ -1092,6 +1097,18 @@ class Engine:
         slot. Returns True while any work remains."""
         if self._stopped:
             return False
+        # the phase clock (metrics.PhaseClock): the step opens in its admit
+        # phase, every dispatch site below switches it to feed / wait /
+        # emit, and the boundary's sums reach the ledger under one lock
+        self._clock.start()
+        try:
+            return self._step()
+        finally:
+            spans = self._clock.finish()
+            if spans is not None:
+                obs_tracing.collect_boundary(self.tag, spans)
+
+    def _step(self):
         # chaos hook: simulated ABRUPT engine death (no flush) — recovery
         # must come from the last periodic snapshot or request replay
         _fi.maybe_kill_serving(self.tag, self._step_count)
@@ -1186,21 +1203,20 @@ class Engine:
     def _iterate_pooled(self, active):
         """One pooled-layout decode iteration: one token for every active
         slot through the [L, B, Smax, nh, d] cache."""
-        t0 = time.perf_counter()
+        clk = self._clock
+        t0 = clk.feed("pooled", "decode_time_s")
         self._kc, self._vc, nxt, keys = self._decode(
             self.params, self._kc, self._vc,
             jnp.asarray(self._tok), jnp.asarray(self._pos),
             jnp.asarray(active), jnp.asarray(self._do_sample),
             jnp.asarray(self._temp), jnp.asarray(self._top_p),
             jnp.asarray(self._keys))
+        clk.wait()
         nxt = np.asarray(nxt)
+        t1 = clk.emit()
         # copy: device_get views are read-only and _admit writes rows
         self._keys = np.array(keys)
-        t1 = time.perf_counter()
-        dt = t1 - t0
         metrics.bump("decode_steps")
-        metrics.add_time("decode_time_s", dt)
-        metrics.observe_token_latency(dt, 1)
         for b, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -1293,7 +1309,8 @@ class Engine:
         dispatch shapes ARE the steady-state executable set (the chunk
         ladder), trace-counter gated."""
         B = self.num_slots
-        t_boundary = time.perf_counter()    # chunks + CoW + decode: the
+        clk = self._clock
+        t_boundary = clk.pause()            # chunks + CoW + decode: the
         prefilling = sorted(                # whole inter-token gap
             (b for b in range(B) if self._slots[b] is not None
              and self._chunk_off[b] < self._slots[b].prompt_len),
@@ -1320,6 +1337,7 @@ class Engine:
         if self._spec is not None:
             self._iterate_spec(decoding, t_boundary)
             return
+        t0 = clk.feed("decode", "decode_time_s")
         # mid-prefill slots ride along inert: valid=0 routes their writes
         # to the trash page, emit=False parks their PRNG keys
         valid = np.zeros(B, np.int32)
@@ -1328,7 +1346,6 @@ class Engine:
         emit[decoding] = True
         for b in decoding:
             self._cow(b, int(self._pos[b]), int(self._pos[b]) + 1)
-        t0 = time.perf_counter()
         self._decode_dispatches += 1     # per-role gate: prefill workers
         out = self._paged_step(          # must never reach this dispatch
             self.params, self._kc, self._vc,
@@ -1338,6 +1355,7 @@ class Engine:
             jnp.asarray(self._temp), jnp.asarray(self._top_p),
             jnp.asarray(self._keys), *self._kv_scale_args(),
             *self._adapter_args())
+        clk.wait()
         if self._anomaly:
             self._kc, self._vc, nxt, keys, ok = out
             ok = np.asarray(ok)
@@ -1345,16 +1363,11 @@ class Engine:
             self._kc, self._vc, nxt, keys = out
             ok = None
         nxt = np.asarray(nxt)
+        now = clk.emit()
         self._keys = np.array(keys)
-        now = time.perf_counter()
         self._record_mp_comm(B, 1, t0, now,
                              [self._slots[b] for b in decoding])
         metrics.bump("paged_steps")
-        metrics.add_time("decode_time_s", now - t0)
-        # the latency a decode stream OBSERVES spans the whole boundary —
-        # interleaved prefill chunks and CoW copies included — which is
-        # exactly the gap chunked prefill is supposed to bound
-        metrics.observe_token_latency(now - t_boundary, 1)
         for b in decoding:
             req = self._slots[b]
             if ok is not None and not ok[b]:
@@ -1422,14 +1435,17 @@ class Engine:
             emit[b] = True
         ids = np.zeros((B, k + 1), np.int32)
         ids[:, 0] = self._tok                 # lane 0: last emitted token
-        t0 = time.perf_counter()
+        clk = self._clock
         if int(nprop.max()) > 0:
+            clk.feed("draft", "decode_time_s")
             props = self._spec_draft(
                 self._draft_params, self._kc, self._vc,
                 jnp.asarray(self._tok), jnp.asarray(self._pos),
                 jnp.asarray(self.pool.table), *self._kv_scale_args())
+            clk.wait()
             ids[:, 1:] = np.asarray(props)
             metrics.bump("draft_dispatches")
+        clk.feed("verify", "decode_time_s")
         for b in decoding:
             self._cow(b, int(self._pos[b]),
                       int(self._pos[b]) + int(valid[b]))
@@ -1441,6 +1457,7 @@ class Engine:
             jnp.asarray(self._do_sample), jnp.asarray(self._temp),
             jnp.asarray(self._top_p), jnp.asarray(self._keys),
             *self._kv_scale_args())
+        clk.wait()
         if self._anomaly:
             self._kc, self._vc, toks, n_emit, keys, ok = out
             ok = np.asarray(ok)
@@ -1449,12 +1466,10 @@ class Engine:
             ok = None
         toks = np.asarray(toks)
         n_emit = np.asarray(n_emit)
+        now = clk.emit()
         self._keys = np.array(keys)
-        now = time.perf_counter()
         metrics.bump("paged_steps")
         metrics.bump("verify_dispatches")
-        metrics.add_time("decode_time_s", now - t0)
-        total_emitted = 0
         for b in decoding:
             req = self._slots[b]
             if ok is not None and not ok[b]:
@@ -1485,15 +1500,12 @@ class Engine:
                     break                    # safety net; plan already
                 self._pos[b] += 1            # accounts for the stop cut
                 self._emit_token(req, b, int(toks[b, j]), first=False)
-            total_emitted += plan
-        # the whole boundary gap bought total_emitted tokens — the
-        # speculative payoff the latency histogram should see
-        metrics.observe_token_latency(now - t_boundary,
-                                      max(1, total_emitted))
 
     def _prefill_chunk(self, b):
         """Advance slot b's prefill by one chunk ([1, rung] dispatch of
         the fused step); the final chunk emits the request's first token."""
+        clk = self._clock
+        t0 = clk.feed("chunk", "prefill_time_s")
         req = self._slots[b]
         plen = req.prompt_len
         off = int(self._chunk_off[b])
@@ -1516,7 +1528,6 @@ class Engine:
         ids = np.zeros((1, C), np.int32)
         ids[0, :v] = req.prompt[off:off + v]
         self._cow(b, off, off + v)
-        t0 = time.perf_counter()
         out = self._paged_step(
             self.params, self._kc, self._vc, jnp.asarray(ids),
             jnp.asarray([off], np.int32), jnp.asarray([v], np.int32),
@@ -1526,6 +1537,7 @@ class Engine:
             jnp.asarray(self._top_p[b:b + 1]),
             jnp.asarray(self._keys[b:b + 1]), *self._kv_scale_args(),
             *self._adapter_args(slice(b, b + 1)))
+        clk.wait()
         if self._anomaly:
             # the verdict is only consulted on the emitting (final) chunk
             # — fetch it there, not per chunk (no extra host sync on the
@@ -1534,16 +1546,18 @@ class Engine:
         else:
             self._kc, self._vc, nxt, keys = out
             ok_dev = None
-        t1 = time.perf_counter()
+        # the chunk step ends when its outputs are on the host: the fetch
+        # of the slot's key row is where the host waits for the device
+        keys = np.asarray(keys)
+        t1 = clk.emit()
+        self._keys[b] = keys[0]
         self._record_mp_comm(1, C, t0, t1, [req])
         metrics.bump("paged_steps")
         metrics.bump("chunk_steps")
         metrics.bump("prefill_chunks")
-        metrics.add_time("prefill_time_s", t1 - t0)
         if req.trace is not None:
             req.trace.span("prefill_chunk", t0, t1, offset=off, tokens=v,
                            chunk=C)
-        self._keys[b] = np.asarray(keys)[0]
         if last:
             self._chunk_off[b] = plen
             self._pos[b] = plen               # next decode writes here
@@ -1769,7 +1783,10 @@ class Engine:
             if tail is None:
                 return False               # page pressure: retry later
         pages = self.pool.adopt_staged(rid)
-        self._trace_queue_span(req, b)
+        # seated, not admitted (the prefill worker counted the admission):
+        # the slot's prefill span runs from here to its first token
+        t_seat = self._admit_t[b] = time.perf_counter()
+        self._trace_queue_span(req, b, t_seat)
         self.pool.map_slot(b, pages + tail, None)
         req.slot = b
         self._slots[b] = req
@@ -1809,6 +1826,9 @@ class Engine:
         if first and fresh_first:
             metrics.observe_ttft(req.first_token_t - req.submit_t,
                                  priority=req.priority)
+            self._clock.add("prefill_span_s",
+                            req.first_token_t - self._admit_t[b])
+            self._clock.add("first_tokens", 1)
             if req.trace is not None:
                 # the exact timestamp the TTFT sample uses — the exported
                 # trace reconciles with the ledger to the float
@@ -1996,7 +2016,7 @@ class Engine:
         step, interleaved with every other slot's decode."""
         chunk_start, shared, private, spare = req._page_plan
         del req._page_plan
-        self._trace_queue_span(req, b)
+        self._observe_admission(req, b)
         self.pool.map_slot(b, list(shared) + list(private), spare)
         req.state = RUNNING
         req.slot = b
@@ -2037,24 +2057,25 @@ class Engine:
         """Prefill req's prompt into slot b (prompt padded to its bucket);
         the prefill emits the request's FIRST token (TTFT stops here)."""
         plen = req.prompt_len
-        self._trace_queue_span(req, b)
+        self._observe_admission(req, b)
         req.params_version = self.params_version
         bucket = self.scheduler.bucket_for(plen)
         metrics.observe_prefill_waste(bucket - plen)
+        clk = self._clock
+        t0 = clk.feed("pooled", "prefill_time_s")
         ids = np.zeros(bucket, np.int32)
         ids[:plen] = req.prompt
         key0 = jax.random.key_data(jax.random.key(req.seed))
-        t0 = time.perf_counter()
         self._kc, self._vc, tok, key = self._prefill(
             self.params, self._kc, self._vc, jnp.asarray(ids),
             jnp.int32(plen), jnp.int32(b), jnp.asarray(key0),
             jnp.asarray(bool(req.do_sample)),
             jnp.float32(req.temperature),
             jnp.float32(1.0 if req.top_p is None else req.top_p))
+        clk.wait()
         tok = int(np.asarray(tok))
-        t1 = time.perf_counter()
+        t1 = clk.emit()
         metrics.bump("prefill_calls")
-        metrics.add_time("prefill_time_s", t1 - t0)
         metrics.bump("admitted")
         if req.trace is not None:
             req.trace.span("prefill", t0, t1, bucket=bucket, tokens=plen)
@@ -2067,21 +2088,23 @@ class Engine:
         if fresh_first:
             metrics.observe_ttft(req.first_token_t - req.submit_t,
                                  priority=req.priority)
+            clk.add("prefill_span_s", req.first_token_t - self._admit_t[b])
+            clk.add("first_tokens", 1)
             if req.trace is not None:
                 req.trace.instant("first_token", req.first_token_t)
         if req.stop_token_ids and tok in req.stop_token_ids:
             self._resolve(req, STOP)
-            return
-        if req.max_new_tokens == 1:
+        elif req.max_new_tokens == 1:
             self._resolve(req, LENGTH)
-            return
-        self._slots[b] = req
-        self._keys[b] = np.asarray(key)
-        self._tok[b] = tok
-        self._pos[b] = plen            # first decode writes token's KV here
-        self._do_sample[b] = bool(req.do_sample)
-        self._temp[b] = float(req.temperature)
-        self._top_p[b] = 1.0 if req.top_p is None else float(req.top_p)
+        else:
+            self._slots[b] = req
+            self._keys[b] = np.asarray(key)
+            self._tok[b] = tok
+            self._pos[b] = plen        # first decode writes token's KV here
+            self._do_sample[b] = bool(req.do_sample)
+            self._temp[b] = float(req.temperature)
+            self._top_p[b] = 1.0 if req.top_p is None else float(req.top_p)
+        clk.admit()                    # back to the boundary's admission
 
     def _quarantine(self, req, b):
         """Anomaly-guard resolution (``FLAGS_serving_anomaly_policy=
@@ -2148,7 +2171,20 @@ class Engine:
         if self.kv_layout == "paged":
             self.pool.release_slot(b)
 
-    def _trace_queue_span(self, req, b):
+    def _observe_admission(self, req, b):
+        """Admission into slot b, one instant for all it feeds: the
+        ``admit_queue_wait_s`` sample (now - ``submit_t``, flag or no flag;
+        a requeued request is admitted, and counted, again, from its
+        original arrival), the start of the slot's ``prefill_span_s``, and
+        the end of the traced request's queue-wait span."""
+        now = time.perf_counter()
+        self._admit_t[b] = now
+        self._clock.add("admit_queue_wait_s",
+                        max(0.0, now - req.submit_t) if req.submit_t else 0.0)
+        self._clock.add("admit_queue_waits", 1)
+        self._trace_queue_span(req, b, now)
+
+    def _trace_queue_span(self, req, b, now):
         """Admission closes the request's queue-wait span: from arrival
         (``submit_t`` — the exact float the TTFT/latency ledger uses) or,
         after a requeue/restore hop, from the last recorded span, to now."""
@@ -2156,7 +2192,7 @@ class Engine:
             return
         tail = req.trace.tail()
         t0 = req.submit_t if tail is None else max(tail, req.submit_t)
-        req.trace.span("queue", t0, time.perf_counter(), slot=b)
+        req.trace.span("queue", t0, now, slot=b)
 
     def _resolve(self, req, reason, count="completed"):
         if req.state != FINISHED:
@@ -2576,6 +2612,7 @@ class Engine:
             self._adapter_gauges()
         self._admit_seq = np.asarray(state["admit_seq"], np.int64).copy()
         self._admit_count = int(state["admit_count"])
+        self._admit_t = [time.perf_counter()] * self.num_slots
         self._step_count = int(state["step_count"])
         if self.kv_layout == "paged":
             self.pool.load_state_dict(state["pool"])
@@ -2737,8 +2774,10 @@ class Engine:
         return out
 
     def export_trace(self, path):
-        """Write every collected finished-request trace (process-wide ring,
-        this engine's included) as Perfetto-loadable Chrome-trace JSON."""
+        """Write every collected finished-request trace and engine boundary
+        (process-wide rings, this engine's included) as Perfetto-loadable
+        Chrome-trace JSON: one thread per request and, per engine, the
+        ``boundaries`` thread with each step's ``pt.serve.*`` phases."""
         return obs_tracing.export_perfetto(path)
 
     def run(self, requests=None):

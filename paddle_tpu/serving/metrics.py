@@ -10,9 +10,11 @@ admission, eviction and sampling-param changes reuse the cached executables.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 _lock = threading.Lock()
 
@@ -112,9 +114,22 @@ def _zero():
         "adapter_loads": 0, "adapter_evicts": 0, "adapter_swaps": 0,
         "adapter_admit_blocked": 0,
         "adapters_resident": 0, "adapter_delta_bytes": 0,
-        # tokens / time
+        # tokens / time. decode_time_s / prefill_time_s are feed + wait of
+        # the decode-side (decode, draft, verify, pooled decode) and of the
+        # prefill-side (chunk, pooled prefill) dispatches: each ends when
+        # the dispatch's outputs are on the host
         "tokens_out": 0,
         "decode_time_s": 0.0, "prefill_time_s": 0.0,
+        # phase clock of Engine.step (PhaseClock below): seconds of every
+        # boundary (count: "boundaries") and of its disjoint phases, which
+        # with a remainder (ledger bumps, snapshots) sum to step_s
+        "step_s": 0.0, "admit_s": 0.0, "feed_s": 0.0, "wait_s": 0.0,
+        "emit_s": 0.0,
+        # request-level: submit -> admission of every admitted request, and
+        # admission -> first token of every fresh first token (a requeued
+        # or replayed request's first token counts once, as in observe_ttft)
+        "admit_queue_wait_s": 0.0, "admit_queue_waits": 0,
+        "prefill_span_s": 0.0, "first_tokens": 0,
         # occupancy: sum of active slots over decode steps / (steps * slots)
         "active_slot_steps": 0, "slot_steps": 0,
         # queue depth observed at step boundaries
@@ -140,7 +155,6 @@ _adapter_tokens = {}
 # first — a long-running server must surface a late latency regression
 _MAX_SAMPLES = 65536
 _ttft = deque(maxlen=_MAX_SAMPLES)      # seconds
-_tok_lat = deque(maxlen=_MAX_SAMPLES)   # per-token decode latency (seconds)
 # per-priority-class TTFT rings (lazy: a class appears once it has a
 # sample) — the SLO story is per-class: the chaos gate holds the
 # INTERACTIVE p99 while best_effort visibly degrades
@@ -217,6 +231,105 @@ def add_time(name, dt):
         _C[name] += dt
 
 
+class PhaseClock:
+    """The one timing idiom of ``Engine.step``: a mark-based clock that
+    takes one ``perf_counter()`` per phase edge, keeps the boundary's sums
+    in a local dict and adds them to the ledger with ONE lock acquisition
+    in ``finish()``. Every phase is also a ``jax.profiler.TraceAnnotation``
+    (``pt.serve.step`` around ``pt.serve.admit|feed|wait|emit``; a
+    dispatch's feed and wait carry ``kind=``), so inside a profiler session
+    the phases lie on the device trace's clock; outside one an annotation
+    is a no-op check. With ``keep_spans`` the same instants are kept as
+    spans for the engine track of the exported trace.
+
+    The phases of a boundary are disjoint: opening one closes the one
+    before it at the same instant. ``feed(kind, time_to)`` opens a
+    dispatch; its feed and its ``wait()`` also add to ``time_to``
+    (``decode_time_s`` or ``prefill_time_s``)."""
+
+    def __init__(self, keep_spans=False):
+        self.sums = {}
+        self.spans = [] if keep_spans else None
+        self._step_ann = self._ann = None
+        self._name = self._kind = self._time_to = None
+        self._t_step = self._t = 0.0
+
+    def start(self):
+        """Top of ``Engine.step``: opens the step and its first phase."""
+        self._step_ann = TraceAnnotation("pt.serve.step")
+        self._step_ann.__enter__()
+        self._t_step = self._t = time.perf_counter()
+        self._open("admit", None, None)
+
+    def _open(self, name, kind, time_to):
+        self._name, self._kind, self._time_to = name, kind, time_to
+        self._ann = TraceAnnotation("pt.serve." + name) if kind is None \
+            else TraceAnnotation("pt.serve." + name, kind=kind)
+        self._ann.__enter__()
+
+    def pause(self):
+        """Close the open phase, if any; what follows until the next phase
+        opens is the boundary's remainder. Returns the instant."""
+        now = time.perf_counter()
+        name = self._name
+        if name is not None:
+            self._ann.__exit__(None, None, None)
+            dt = now - self._t
+            sums = self.sums
+            sums[name + "_s"] = sums.get(name + "_s", 0.0) + dt
+            if self._time_to is not None:
+                sums[self._time_to] = sums.get(self._time_to, 0.0) + dt
+            if self.spans is not None:
+                ev = {"name": "pt.serve." + name, "t0": self._t, "t1": now}
+                if self._kind is not None:
+                    ev["kind"] = self._kind
+                self.spans.append(ev)
+            self._name = None
+        self._t = now
+        return now
+
+    def _switch(self, name, kind=None, time_to=None):
+        now = self.pause()
+        self._open(name, kind, time_to)
+        return now
+
+    def admit(self):
+        return self._switch("admit")
+
+    def feed(self, kind, time_to):
+        return self._switch("feed", kind, time_to)
+
+    def wait(self):
+        """From the jitted call's return to its outputs on the host: the
+        same dispatch as the feed before it."""
+        return self._switch("wait", self._kind, self._time_to)
+
+    def emit(self):
+        return self._switch("emit")
+
+    def add(self, name, value):
+        """A request-level sum taken inside the boundary: rides the same
+        flush."""
+        self.sums[name] = self.sums.get(name, 0) + value
+
+    def finish(self):
+        """End of ``Engine.step`` (also when it raised): closes what is
+        open, flushes the sums under one lock and returns the boundary's
+        spans (the step first), or None."""
+        now = self.pause()
+        self._step_ann.__exit__(None, None, None)
+        sums, self.sums = self.sums, {}
+        sums["step_s"] = now - self._t_step
+        with _lock:
+            for k, v in sums.items():
+                _C[k] += v
+        if self.spans is None:
+            return None
+        spans, self.spans = self.spans, []
+        return [{"name": "pt.serve.step", "t0": self._t_step, "t1": now}] \
+            + spans
+
+
 def observe_boundary(queue_depth, active, slots):
     with _lock:
         _C["boundaries"] += 1
@@ -260,11 +373,6 @@ def observe_queue_wait(seconds, outcome):
         _C[f"{outcome}_queue_waits"] += 1
 
 
-def observe_token_latency(seconds, n=1):
-    with _lock:
-        _tok_lat.append(seconds / max(n, 1))
-
-
 def recent_ttft_p50(n=256):
     """p50 over the last ``n`` TTFT samples (None when empty) — the cheap
     live estimate the preemption margin derives from, without computing
@@ -288,12 +396,10 @@ def recent_ttft_p99(n=512):
 
 def serving_counters():
     """Snapshot of the serving ledger plus derived rates: ttft p50/p99,
-    per-token latency, tokens/s over decode time, slot occupancy, mean
-    queue depth."""
+    tokens/s over executable time, slot occupancy, mean queue depth."""
     with _lock:
         out = dict(_C)
         ttft = list(_ttft)
-        lat = list(_tok_lat)
         cls_samples = {c: list(v) for c, v in _ttft_cls.items()}
         ad_tokens = dict(_adapter_tokens)
     out["ttft_p50"] = float(np.percentile(ttft, 50)) if ttft else None
@@ -307,7 +413,6 @@ def serving_counters():
     out["expired_queue_wait_mean"] = (
         out["expired_queue_wait_s"] / out["expired_queue_waits"]
         if out["expired_queue_waits"] else 0.0)
-    out["token_latency_p50"] = float(np.percentile(lat, 50)) if lat else None
     # tokens_out counts prefill-emitted first tokens too, so the rate
     # divides by total executable time (prefill + decode), not decode alone
     exec_t = out["decode_time_s"] + out["prefill_time_s"]
@@ -349,7 +454,6 @@ def reset_serving_counters():
     with _lock:
         _C = _zero()
         _ttft.clear()
-        _tok_lat.clear()
         _ttft_cls.clear()
         _adapter_tokens.clear()
         # _mp_info / _adapter_info survive on purpose: they are engine
@@ -387,7 +491,6 @@ def export_state():
     SLO history across a restart instead of reporting from zero."""
     with _lock:
         return {"counters": dict(_C), "ttft": list(_ttft),
-                "token_latency": list(_tok_lat),
                 "ttft_cls": {c: list(v) for c, v in _ttft_cls.items()},
                 "adapter_tokens": dict(_adapter_tokens)}
 
@@ -403,8 +506,6 @@ def import_state(state):
                 _C[k] = v
         _ttft.clear()
         _ttft.extend(state.get("ttft", ()))
-        _tok_lat.clear()
-        _tok_lat.extend(state.get("token_latency", ()))
         _ttft_cls.clear()
         for c, v in state.get("ttft_cls", {}).items():
             _ttft_cls[c] = deque(v, maxlen=_MAX_SAMPLES)
