@@ -464,7 +464,9 @@ def leg_kernels(cfg, *, batch, seq, num_slots, page_size, max_seq_len,
                          q, k, v, True)), args, want, mosaic)
 
     # paged decode, full-precision and int8 pool, every slot at a different
-    # depth of its page list (first page, page boundary, last position)
+    # depth of its page list (first page, page boundary, last position);
+    # the engine's call: the last layer of a stacked pool, addressed in
+    # place by the kernel's index_map and by the gather's start indices
     MP = max_seq_len // page_size
     P = num_slots * MP + 1
     q = jnp.asarray(rng.standard_normal((num_slots, nh, d)), jnp.float32)
@@ -472,7 +474,8 @@ def leg_kernels(cfg, *, batch, seq, num_slots, page_size, max_seq_len,
         num_slots, MP), jnp.int32)
     pos = jnp.asarray(np.linspace(0, max_seq_len - 1, num_slots).round(),
                       jnp.int32).at[1].set(page_size - 1).at[2].set(page_size)
-    shape = (P, page_size, nh, d)
+    layer = jnp.asarray(1, jnp.int32)
+    shape = (2, P, page_size, nh, d)
     kc, vc = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
               for _ in range(2))
     kq, vq = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
@@ -481,19 +484,21 @@ def leg_kernels(cfg, *, batch, seq, num_slots, page_size, max_seq_len,
                 for _ in range(2))
 
     def gather_read(q, kc, vc, table, pos, *scales):
-        return paged_attention_read(q[:, None], kc, vc, table, pos[:, None],
-                                    page_size, False, jnp.float32,
-                                    *scales)[:, 0]
+        return paged_attention_read(q[:, None], kc, vc, layer, table,
+                                    pos[:, None], page_size, False,
+                                    jnp.float32, *scales)[:, 0]
 
     args = (q, kc, vc, table, pos)
     _kernel_case(leg, "paged_decode",
                  lambda *a: paged_decode_attention(
-                     *a, page_size=page_size, interpret=interpret),
+                     *a, page_size=page_size, layer=layer,
+                     interpret=interpret),
                  args, jax.jit(gather_read)(*args), mosaic)
     args = (q, kq, vq, table, pos, ksc, vsc)
     _kernel_case(leg, "paged_decode_q",
                  lambda *a: paged_decode_attention_q(
-                     *a, page_size=page_size, interpret=interpret),
+                     *a, page_size=page_size, layer=layer,
+                     interpret=interpret),
                  args, jax.jit(gather_read)(*args), mosaic)
 
     # weight-only int8 GEMM at the qkv and ffn-up widths, one decode batch

@@ -5,7 +5,8 @@ eager+jit runtime.
 Two KV layouts (``kv_layout`` / FLAGS_serving_kv_layout):
 
 * **paged** (default) — block-paged pool ``[L, P, page_size, nh, d]``
-  plus a slot->page table (vLLM-style PagedAttention): admission is
+  (d padded to whole lanes on the device, ``pool_head_dim``) plus a
+  slot->page table (vLLM-style PagedAttention): admission is
   bounded by physical PAGES, not worst-case-length slots, so effective
   batch tracks ACTUAL sequence lengths; prompts with a cached prefix map
   the same physical pages copy-on-write (serving/paged_kv.py); and long
@@ -71,8 +72,8 @@ from . import quant as _squant
 from .adapters import AdapterRegistry, AdapterSpec, UnknownAdapterError
 from .kv_transfer import KVTransfer, PagePayload
 from .paged_attention import (
-    paged_draft_forward, paged_forward, paged_kernel_supported,
-    paged_kv_rewind, paged_verify_forward,
+    pad_lanes, paged_draft_forward, paged_forward, paged_kernel_supported,
+    paged_kv_rewind, paged_verify_forward, pool_head_dim,
 )
 from .paged_kv import PagedKVPool, pages_for
 from .request import (
@@ -284,8 +285,9 @@ def _make_page_write(donate):
 
     def fn(kc, vc, kpage, vpage, dst):
         metrics.bump("write_traces")  # body runs only when traced
-        kc = kc.at[:, dst].set(kpage)
-        vc = vc.at[:, dst].set(vpage)
+        # a payload carries the model's head_dim, the pool whole lanes
+        kc = kc.at[:, dst].set(pad_lanes(kpage, kc))
+        vc = vc.at[:, dst].set(pad_lanes(vpage, vc))
         return kc, vc
 
     return jax.jit(fn, donate_argnums=donate)
@@ -713,8 +715,10 @@ class Engine:
                 self._spec_draft = _make_spec_draft(
                     cfg, self.page_size, self._spec.k, quant=quant_key)
                 self._build_draft_params()
+            # head_dim padded to whole lanes on the device; snapshots and
+            # page payloads keep the model's d (_logical / pad_lanes)
             shape = (config.num_layers, self.pool.num_pages, self.page_size,
-                     nh, d)
+                     nh, pool_head_dim(d))
             if self._kv_quant:
                 compute = _squant.STORE_DTYPES[kv_dtype]
         self._kc = jnp.zeros(shape, compute)
@@ -1260,6 +1264,13 @@ class Engine:
                 req.trace.span("mp_comm", t0, t1, bytes=wire,
                                backend=self._mp_cfg.backend, mp=self.mp)
 
+    def _logical(self, pool):
+        """A host copy of a pool array (or of pages of it) at the model's
+        head_dim, contiguous: what snapshots, page payloads and the chaos
+        hooks see. The device holds ``pool_head_dim`` lanes."""
+        d = self.config.hidden_size // self.config.num_heads
+        return np.ascontiguousarray(np.asarray(pool)[..., :d])
+
     def _kv_scale_args(self):
         """Per-page dequant scale operands of a quantized pool: host-
         authoritative like the page table, uploaded with every dispatch
@@ -1605,8 +1616,8 @@ class Engine:
             if self._kv_quant:
                 ks = self.pool.k_scale[:, phys].copy()
                 vs = self.pool.v_scale[:, phys].copy()
-            payload = PagePayload(li, np.asarray(jax.device_get(kpage)),
-                                  np.asarray(jax.device_get(vpage)),
+            payload = PagePayload(li, self._logical(jax.device_get(kpage)),
+                                  self._logical(jax.device_get(vpage)),
                                   ks, vs)
             if self._kv_crc:
                 payload.stamp()
@@ -1672,13 +1683,14 @@ class Engine:
         flips = _fi.maybe_kv_bitflip(self.tag, self._step_count)
         if not flips or self._kc is None:
             return
-        host = np.asarray(jax.device_get(self._kc)).copy()
+        host = self._logical(jax.device_get(self._kc)).copy()
         for page, layer, bit in flips:
             view = host[int(layer) % host.shape[0], int(page) % host.shape[1]]
             flat = view.view(np.uint8).reshape(-1)
             byte, off = divmod(int(bit), 8)
             flat[byte] ^= np.uint8(1 << off)
-        self._kc = jax.device_put(host, self._kc.sharding)
+        self._kc = jax.device_put(pad_lanes(host, self._kc),
+                                  self._kc.sharding)
 
     def _install_page(self, payload, dst):
         """Write one page payload into physical page ``dst`` (ONE traced
@@ -2478,8 +2490,8 @@ class Engine:
         unpopped results, and the serving metrics ledger. Safe for
         ``CheckpointManager``/``framework.io`` round trips; pair with
         ``load_state_dict`` for bitwise mid-decode resume."""
-        kc_np = np.asarray(jax.device_get(self._kc))
-        vc_np = np.asarray(jax.device_get(self._vc))
+        kc_np = self._logical(jax.device_get(self._kc))
+        vc_np = self._logical(jax.device_get(self._vc))
         if kc_np.dtype not in (np.int8, np.float32, np.float64, np.float16):
             # fp8/bf16 pools: numpy IO paths don't all speak ml_dtypes —
             # snapshot the raw bytes; meta's kv dtype restores the view
@@ -2586,8 +2598,8 @@ class Engine:
             # raw-byte snapshot of an fp8 pool: restore the dtype view
             kc_np = kc_np.view(compute)
             vc_np = vc_np.view(compute)
-        self._kc = jnp.asarray(kc_np, compute)
-        self._vc = jnp.asarray(vc_np, compute)
+        self._kc = pad_lanes(jnp.asarray(kc_np, compute), self._kc)
+        self._vc = pad_lanes(jnp.asarray(vc_np, compute), self._vc)
         if self._kv_sharding is not None:
             # snapshots hold the GLOBAL pool (mp-independent geometry, and
             # the gather-only schedule makes its contents bitwise equal at
@@ -2836,7 +2848,7 @@ class Engine:
         fewer than fp32, fp8 likewise)."""
         cfg = self.config
         nh_l = cfg.num_heads // self.mp
-        d = cfg.hidden_size // cfg.num_heads
+        d = self._kc.shape[-1]        # the lanes the device holds
         item = int(self._kc.dtype.itemsize)
         per_tok = 2 * cfg.num_layers * nh_l * d * item
         if self._kv_quant:

@@ -52,7 +52,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..models.generation import _final_ln
 from ..models.gpt import ln_fp32
 from ..ops.pallas_kernels.quant_gemm import lora_delta, compose_delta
-from .paged_attention import paged_attention_read, paged_kv_scatter
+from .paged_attention import (layer_ids, paged_attention_read,
+                              paged_kv_scatter)
 
 KV_SPEC = P(None, None, None, "mp", None)   # [L, P, page, nh@mp, d]
 
@@ -225,12 +226,14 @@ def _local_proj(h, p, name):
     return (h @ p[name].astype(h.dtype)) * s.astype(h.dtype)
 
 
-def _mp_block(p, h, kc_l, vc_l, table, pos, valid, nh, n, eps, page_size,
+def _mp_block(p, h, kc, vc, l, table, pos, valid, nh, n, eps, page_size,
               use_kernel, axis, backend, meta, ksc_l=None, vsc_l=None,
               aid=None, ad_l=None):
-    """One transformer block on PER-CHIP shards: h [B, T, H] replicated,
+    """Transformer block ``l`` on PER-CHIP shards: h [B, T, H] replicated,
     weights column-sharded (qkv head-major: the local contiguous shard is
-    nh/n whole heads), KV pool holding the local heads only. Every op is
+    nh/n whole heads), the whole KV pool kc/vc [L, P, page, nh/n, d]
+    holding the local heads only, written and read at layer l in place
+    (paged_kv_scatter / paged_attention_read). Every op is
     either replicated elementwise math, a full-contraction GEMM block, a
     per-head attention (head subsets are bitwise-independent), or an
     exact gather — so the block output is bitwise identical to
@@ -264,9 +267,9 @@ def _mp_block(p, h, kc_l, vc_l, table, pos, valid, nh, n, eps, page_size,
     qkv4 = qkv.reshape(B, T, nh_l, 3, d)        # head-major local columns
     q, k, v = qkv4[..., 0, :], qkv4[..., 1, :], qkv4[..., 2, :]
 
-    kc_l, vc_l = paged_kv_scatter(kc_l, vc_l, k, v, table, pos, valid,
-                                  page_size, ksc_l, vsc_l)
-    ctx = paged_attention_read(q, kc_l, vc_l, table, pos, page_size,
+    kc, vc = paged_kv_scatter(kc, vc, l, k, v, table, pos, valid,
+                              page_size, ksc_l, vsc_l)
+    ctx = paged_attention_read(q, kc, vc, l, table, pos, page_size,
                                use_kernel, h.dtype, ksc_l,
                                vsc_l)                           # [B,T,nh_l,d]
     # gather the context heads (chip order == logical head order), then
@@ -295,7 +298,7 @@ def _mp_block(p, h, kc_l, vc_l, table, pos, valid, nh, n, eps, page_size,
                    else p["down_w"].astype(h.dtype),
                    axis, n, backend, meta, scale=down_s,
                    epilogue=_delta_epi(act, "down_w"))
-    return h + down + p["down_b"].astype(h.dtype), kc_l, vc_l
+    return h + down + p["down_b"].astype(h.dtype), kc, vc
 
 
 def mp_paged_forward(params, config, ids, kc, vc, start, valid, table,
@@ -320,40 +323,26 @@ def mp_paged_forward(params, config, ids, kc, vc, start, valid, table,
 
     def device_fn(params, kc, vc, ids, start, valid, table, *extra):
         extra = list(extra)
-        if kv_scales is not None:
-            scales = (extra.pop(0), extra.pop(0))
-        else:
-            scales = ()
-        if adapters is not None:
-            aid_d, slabs_d = extra
-        else:
-            aid_d = slabs_d = None
+        ksc, vsc = ((extra.pop(0), extra.pop(0)) if kv_scales is not None
+                    else (None, None))
+        aid_d, slabs_d = extra if adapters is not None else (None, None)
         B, T = ids.shape
         pos = start[:, None] + jnp.arange(T)[None, :]           # [B, T]
         x = ag_last(params["wte"].astype(compute)[ids], axis, n, backend,
                     meta) + \
             jnp.take(params["wpe"].astype(compute), pos, axis=0)
 
-        def layer_fn(h, xs):
-            if adapters is not None:
-                xs, ad_l = xs[:-1], xs[-1]
-            else:
-                ad_l = None
-            if scales:
-                p_l, kc_l, vc_l, ksc_l, vsc_l = xs
-            else:
-                p_l, kc_l, vc_l = xs
-                ksc_l = vsc_l = None
-            h, kc_l, vc_l = _mp_block(p_l, h, kc_l, vc_l, table, pos,
-                                      valid, nh, n, eps, page_size,
-                                      use_kernel, axis, backend, meta,
-                                      ksc_l, vsc_l, aid_d, ad_l)
-            return h, (kc_l, vc_l)
+        def layer_fn(carry, xs):
+            h, kc, vc = carry
+            p_l, l, ksc_l, vsc_l, ad_l = xs
+            return _mp_block(p_l, h, kc, vc, l, table, pos, valid, nh, n,
+                             eps, page_size, use_kernel, axis, backend, meta,
+                             ksc_l, vsc_l, aid_d, ad_l), None
 
-        xs = (params["blocks"], kc, vc) + tuple(scales)
-        if adapters is not None:
-            xs = xs + (slabs_d,)
-        x, (kc2, vc2) = jax.lax.scan(layer_fn, x, xs)
+        # the pools are the scan's carry, as in paged_forward
+        (x, kc2, vc2), _ = jax.lax.scan(
+            layer_fn, (x, kc, vc),
+            (params["blocks"], layer_ids(params), ksc, vsc, slabs_d))
         idx = jnp.maximum(valid - 1, 0)
         xlast = jax.vmap(
             lambda xb, i: jax.lax.dynamic_slice_in_dim(xb, i, 1, axis=0))(

@@ -18,6 +18,13 @@ Two implementations of the decode-attention read:
   ``FLAGS_serving_paged_kernel`` and a TPU-backend + shape predicate
   (``paged_kernel_supported``), mirroring the flash-attention routing.
 
+The pool's last axis is ``pool_head_dim(d)``: head_dim padded up to the
+TPU's 128 lanes, the pad never read. The pool is never sliced, copied or
+re-stacked by a step: it is the layer
+scan's CARRY, each layer writes and reads it at ``[l, ...]`` (one scatter of
+the window's rows; the kernel's index_map or the page gather's start
+indices), and under the engine's donation XLA updates the buffer in place.
+
 The fused step here is ALSO the chunked-prefill executable: every slot
 processes a ``T``-token window at its own offset (``T=1`` pure decode;
 ``T=chunk`` while any prompt is prefilling), with per-slot ``start`` /
@@ -67,6 +74,29 @@ def _proj(h, p, name, wq_kernel=False):
     return quant_gemm(h, p[name], s, use_kernel=wq_kernel)
 
 
+def pool_head_dim(d):
+    """The pool's last axis for head_dim ``d``: padded up to a multiple of
+    the TPU's 128 lanes (GPT-3 2.7B's 80 -> 128; a multiple stays). A page
+    row [nh, d] then is whole (8, 128) tiles, which is how the chip holds
+    it while it computes in any case; what the pad buys is that row-major
+    is also the device's DEFAULT layout for the array. Unpadded, a TPU
+    lays bf16 [L, P, page, nh, 80] out with the PAGE axis minor-most and
+    the step converts the whole pool on the way in and on the way out of
+    every dispatch. (Pinning row-major with ``jax.experimental.layout``
+    does not survive the persistent compile cache: PERF.md, PR 26.) The
+    pad lanes hold zeros (``pad_lanes``) and are read by nothing: every
+    read below takes ``[..., :d]``."""
+    return -(-d // 128) * 128
+
+
+def pad_lanes(x, like):
+    """Rows ``x`` [..., d] widened with zeros to the last axis of the pool
+    ``like``: a whole-row write is ONE in-place scatter on the chip, where
+    a write of ``[..., :d]`` becomes a loop of row-sized updates."""
+    pad = like.shape[-1] - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def paged_kernel_supported(nh, d, page_size, why=""):
     """Routing predicate for the Pallas paged-decode kernel (same pattern
     as ops.pallas_kernels.flash_supported): TPU backend + Mosaic-friendly
@@ -108,11 +138,12 @@ def _decode_kernel(*refs, page_size, scale, quant):
     scales arrive as two more scalar-prefetch operands — scores scale
     after the q.k reduction, v contributions inside the ctx accumulation,
     so the fp K/V bytes never exist in HBM."""
+    # refs[0] is the layer index: only the BlockSpec index_map reads it
     if quant:
-        (table_ref, pos_ref, ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref,
+        (_, table_ref, pos_ref, ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref,
          m_ref, l_ref, acc_ref) = refs
     else:
-        (table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+        (_, table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
          m_ref, l_ref, acc_ref) = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -159,28 +190,33 @@ def _decode_kernel(*refs, page_size, scale, quant):
         o_ref[0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
-def _paged_decode_call(q, kc_l, vc_l, table, pos, scales, page_size,
+def _paged_decode_call(q, kc, vc, layer, table, pos, scales, page_size,
                        interpret):
-    """pallas_call shared by the fp and quantized-pool entry points:
-    ``scales`` is () or (ksc_l, vsc_l) [P] fp32, prefetched to SMEM after
-    the flat table and pos."""
+    """pallas_call shared by the fp and quantized-pool entry points. kc/vc
+    are the WHOLE pool [L, P, page_size, nh, d] and ``layer`` a traced
+    scalar: it rides as the first scalar-prefetch operand and the page
+    index_map addresses block (layer, phys page), so no layer of the pool
+    is sliced out (and copied) for the kernel. ``layer=None`` takes one
+    layer's [P, page_size, nh, d] (a free leading axis, layer 0).
+    ``scales`` is () or that layer's (ksc_l, vsc_l) [P] fp32, prefetched
+    to SMEM after the flat table and pos."""
+    if layer is None:
+        kc, vc, layer = kc[None], vc[None], 0
     B, nh, d = q.shape
     MP = table.shape[1]
 
     def q_map(b, j, *prefetch):
         return (b, 0, 0)
 
-    def page_map(b, j, tab, *prefetch):
-        return (tab[b * MP + j], 0, 0, 0)
+    def page_map(b, j, lay, tab, *prefetch):
+        return (lay[0], tab[b * MP + j], 0, 0, 0)
 
+    page_spec = pl.BlockSpec((None, 1, page_size, nh, d), page_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 + len(scales),   # flat table, pos[, scales]
+        # layer, flat table, pos[, scales]
+        num_scalar_prefetch=3 + len(scales),
         grid=(B, MP),
-        in_specs=[
-            pl.BlockSpec((1, nh, d), q_map),
-            pl.BlockSpec((1, page_size, nh, d), page_map),
-            pl.BlockSpec((1, page_size, nh, d), page_map),
-        ],
+        in_specs=[pl.BlockSpec((1, nh, d), q_map), page_spec, page_spec],
         out_specs=pl.BlockSpec((1, nh, d), q_map),
         scratch_shapes=[
             pltpu.VMEM((nh, 128), jnp.float32),      # m (lane-broadcast)
@@ -198,28 +234,32 @@ def _paged_decode_call(q, kc_l, vc_l, table, pos, scales, page_size,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, nh, d), jnp.float32),
             interpret=interpret,
-        )(table.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
+        )(jnp.asarray(layer, jnp.int32).reshape(1),
+          table.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
           *(sc.astype(jnp.float32) for sc in scales),
-          q.astype(jnp.float32), kc_l, vc_l)
+          q.astype(jnp.float32), kc, vc)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def paged_decode_attention_q(q, kc_l, vc_l, table, pos, ksc_l, vsc_l, *,
-                             page_size, interpret=False):
+def paged_decode_attention_q(q, kc, vc, table, pos, ksc_l, vsc_l, *,
+                             page_size, layer=None, interpret=False):
     """Quantized-pool one-token paged attention: like
-    ``paged_decode_attention`` plus per-page dequant scales ksc_l/vsc_l
-    [P] (fp32) prefetched to SMEM and applied inside the page sweep."""
-    return _paged_decode_call(q, kc_l, vc_l, table, pos, (ksc_l, vsc_l),
+    ``paged_decode_attention`` plus the layer's per-page dequant scales
+    ksc_l/vsc_l [P] (fp32) prefetched to SMEM and applied inside the page
+    sweep."""
+    return _paged_decode_call(q, kc, vc, layer, table, pos, (ksc_l, vsc_l),
                               page_size, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def paged_decode_attention(q, kc_l, vc_l, table, pos, *, page_size,
+def paged_decode_attention(q, kc, vc, table, pos, *, page_size, layer=None,
                            interpret=False):
-    """One-token paged attention: q [B, nh, d] (fp32), kc_l/vc_l
-    [P, page_size, nh, d], table [B, MP], pos [B] -> ctx [B, nh, d] fp32.
-    Unmapped table entries are 0 (trash page) and masked by pos."""
-    return _paged_decode_call(q, kc_l, vc_l, table, pos, (), page_size,
+    """One-token paged attention: q [B, nh, d] (fp32), table [B, MP],
+    pos [B] -> ctx [B, nh, d] fp32. kc/vc are the whole pool
+    [L, P, page_size, nh, d] read at the traced scalar ``layer``, or with
+    ``layer=None`` one layer's [P, page_size, nh, d]. Unmapped table
+    entries are 0 (trash page) and masked by pos."""
+    return _paged_decode_call(q, kc, vc, layer, table, pos, (), page_size,
                               interpret)
 
 
@@ -239,58 +279,71 @@ def _quantize_kv(x, sc, dtype):
     return jnp.clip(scaled, float(info.min), float(info.max)).astype(dtype)
 
 
-def paged_kv_scatter(kc_l, vc_l, k, v, table, pos, valid, page_size,
-                     ksc_l=None, vsc_l=None):
-    """Scatter one window's K/V [B, T, nh', d] into the paged pool through
-    the slot->page table: logical page -> physical; lanes past valid[b]
-    (and whole inactive slots) write to trash page 0. ``nh'`` is whichever
-    head count the caller holds — all heads single-chip, the local shard
-    under mp (the table is head-independent). With a quantized pool the
-    per-page scales ksc_l/vsc_l [P] quantize the write in place (trash
-    page 0 keeps scale 1.0; its garbage is never read unmasked)."""
-    MP = table.shape[1]
-    T = pos.shape[1]
-    writable = jnp.arange(T)[None, :] < valid[:, None]          # [B, T]
-    li = jnp.minimum(pos // page_size, MP - 1)
+def _write_slots(table, pos, writable, page_size):
+    """(phys, off) [B, T] of the pool rows the window positions pos map
+    to through the table; lanes that are not ``writable`` route to trash
+    page 0."""
+    li = jnp.minimum(pos // page_size, table.shape[1] - 1)
     phys = jnp.where(writable, jnp.take_along_axis(table, li, axis=1), 0)
-    off = pos % page_size
+    return phys, pos % page_size
+
+
+def paged_kv_scatter(kc, vc, l, k, v, table, pos, valid, page_size,
+                     ksc_l=None, vsc_l=None):
+    """Scatter one window's K/V [B, T, nh', d] into layer ``l`` (traced
+    scalar) of the WHOLE paged pool kc/vc [L, P, page_size, nh',
+    pool_head_dim(d)] through the slot->page table: logical page -> physical; lanes past
+    valid[b] (and whole inactive slots) write to trash page 0. One
+    scatter at (l, phys, off) into the carried pool, so under donation
+    XLA updates the buffer in place and the B x T rows are the only bytes
+    written. ``nh'`` is whichever head count the caller holds — all heads
+    single-chip, the local shard under mp (the table is
+    head-independent). With a quantized pool the layer's per-page scales
+    ksc_l/vsc_l [P] quantize the write in place (trash page 0 keeps scale
+    1.0; its garbage is never read unmasked)."""
+    phys, off = _write_slots(table, pos, jnp.arange(pos.shape[1])[None, :]
+                             < valid[:, None], page_size)
     if ksc_l is not None:
-        k = _quantize_kv(k, ksc_l[phys], kc_l.dtype)
-        v = _quantize_kv(v, vsc_l[phys], vc_l.dtype)
-    kc_l = kc_l.at[phys, off].set(k.astype(kc_l.dtype))
-    vc_l = vc_l.at[phys, off].set(v.astype(vc_l.dtype))
-    return kc_l, vc_l
+        k = _quantize_kv(k, ksc_l[phys], kc.dtype)
+        v = _quantize_kv(v, vsc_l[phys], vc.dtype)
+    kc = kc.at[l, phys, off].set(pad_lanes(k.astype(kc.dtype), kc))
+    vc = vc.at[l, phys, off].set(pad_lanes(v.astype(vc.dtype), vc))
+    return kc, vc
 
 
-def paged_attention_read(q, kc_l, vc_l, table, pos, page_size, use_kernel,
+def paged_attention_read(q, kc, vc, l, table, pos, page_size, use_kernel,
                          out_dtype, ksc_l=None, vsc_l=None):
-    """Paged attention read: q [B, T, nh', d] against the pool's nh' heads
-    through the table; returns ctx [B, T, nh', d] in ``out_dtype``. Every
-    head's math is independent and mirrors generation._layer_decode_slots
+    """Paged attention read: q [B, T, nh', d] against layer ``l`` (traced
+    scalar) of the WHOLE pool kc/vc [L, P, page_size, nh',
+    pool_head_dim(d)] through the table; returns ctx [B, T, nh', d] in ``out_dtype``. The layer is
+    addressed inside the consuming operation (the kernel's index_map, the
+    page gather's start indices), never sliced out first. Every head's
+    math is independent and mirrors generation._layer_decode_slots
     exactly, so any head SUBSET (the mp engine's per-chip shard) is
     bitwise identical to the same heads of the full computation.
 
-    Quantized pool (ksc_l/vsc_l [P] per-page scales present): scores are
-    computed against the QUANTIZED keys and multiplied by the key page's
-    scale AFTER the dot — every position of a page shares one scale, so
-    the multiply factors out of the contraction and both read branches
-    below compute bit-identical scores; V dequantizes after its gather.
-    The per-dtype exactness contract (mp == single-chip, order/restore
-    invariance) rides on this branch-consistency."""
+    Quantized pool (the layer's ksc_l/vsc_l [P] per-page scales present):
+    scores are computed against the QUANTIZED keys and multiplied by the
+    key page's scale AFTER the dot — every position of a page shares one
+    scale, so the multiply factors out of the contraction and both read
+    branches below compute bit-identical scores; V dequantizes after its
+    gather. The per-dtype exactness contract (mp == single-chip,
+    order/restore invariance) rides on this branch-consistency."""
     B, T, nh, d = q.shape
     MP = table.shape[1]
 
     if use_kernel and T == 1:
         if ksc_l is not None:
             return paged_decode_attention_q(
-                q[:, 0].astype(jnp.float32), kc_l, vc_l, table, pos[:, 0],
-                ksc_l, vsc_l,
-                page_size=page_size)[:, None].astype(out_dtype)
+                q[:, 0].astype(jnp.float32), kc, vc, table, pos[:, 0],
+                ksc_l, vsc_l, page_size=page_size,
+                layer=l)[:, None].astype(out_dtype)
         return paged_decode_attention(
-            q[:, 0].astype(jnp.float32), kc_l, vc_l, table, pos[:, 0],
-            page_size=page_size)[:, None].astype(out_dtype)     # [B,1,nh,d]
+            q[:, 0].astype(jnp.float32), kc, vc, table, pos[:, 0],
+            page_size=page_size,
+            layer=l)[:, None].astype(out_dtype)                 # [B,1,nh,d]
     S = MP * page_size
-    P = kc_l.shape[0]
+    P = kc.shape[1]
     if T == 1 and 2 * P * page_size <= B * S:
         # decode on an UNDERSUBSCRIBED pool (physical pages well below
         # the sum of virtual windows — the memory-equal serving
@@ -302,13 +355,13 @@ def paged_attention_read(q, kc_l, vc_l, table, pos, page_size, use_kernel,
         # gather branch wins when P*ps ~ B*S, hence the static 2x
         # shape guard).
         s_all = jnp.einsum("bthd,pshd->bhtps", q.astype(jnp.float32),
-                           kc_l.astype(jnp.float32)) / (d ** 0.5)
+                           kc[l, ..., :d].astype(jnp.float32)) / (d ** 0.5)
         scores = jax.vmap(lambda sa, tb: sa[:, :, tb])(
             s_all, table).reshape(B, nh, T, S)
     else:
         # chunk prefill (pool-wide scoring is FLOP-heavy for T
         # queries) and amply-sized pools: gather the key window
-        kv_k = kc_l[table].reshape(B, S, nh, d)
+        kv_k = kc[l, table][..., :d].reshape(B, S, nh, d)
         scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
                             kv_k.astype(jnp.float32)) / (d ** 0.5)
     if ksc_l is not None:
@@ -316,7 +369,7 @@ def paged_attention_read(q, kc_l, vc_l, table, pos, page_size, use_kernel,
         # multiply lands AFTER the dot in both branches identically
         k_sc = jnp.repeat(ksc_l[table], page_size, axis=1)      # [B, S]
         scores = scores * k_sc[:, None, None, :]
-    kv_v = vc_l[table].reshape(B, S, nh, d).astype(jnp.float32)
+    kv_v = vc[l, table][..., :d].reshape(B, S, nh, d).astype(jnp.float32)
     if vsc_l is not None:
         v_sc = jnp.repeat(vsc_l[table], page_size, axis=1)      # [B, S]
         kv_v = kv_v * v_sc[:, :, None, None]
@@ -328,6 +381,12 @@ def paged_attention_read(q, kc_l, vc_l, table, pos, page_size, use_kernel,
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhts,bshd->bthd", probs,
                       kv_v).astype(out_dtype)
+
+
+def layer_ids(params):
+    """The layer scan's index operand, [L] int32 over the tree's blocks (a
+    layer-truncated draft tree walks the pool's leading layers)."""
+    return jnp.arange(params["blocks"]["qkv_w"].shape[0], dtype=jnp.int32)
 
 
 def _adapted_proj(h, p, name, wq_kernel, aid, ad_l):
@@ -346,13 +405,14 @@ def _adapted_proj(h, p, name, wq_kernel, aid, ad_l):
     return compose_delta(base, lora_delta(h, A_l, B_l, aid), aid)
 
 
-def _layer_paged(p, h, kc_l, vc_l, table, pos, valid, nh, eps, page_size,
+def _layer_paged(p, h, kc, vc, l, table, pos, valid, nh, eps, page_size,
                  use_kernel, ksc_l=None, vsc_l=None, wq_kernel=False,
                  aid=None, ad_l=None):
-    """One transformer block over h [B, T, H] where each batch row is a
+    """Transformer block ``l`` over h [B, T, H] where each batch row is a
     serving slot processing the token window at absolute positions
-    pos[b, :] (valid[b] of them real). K/V are scattered through the page
-    table (padding lanes -> trash page 0); attention reads the gathered
+    pos[b, :] (valid[b] of them real). K/V are scattered into layer l of
+    the whole pool kc/vc through the page table (padding lanes -> trash
+    page 0), which comes back updated; attention reads the gathered
     virtual window with the absolute causal mask. Math mirrors
     generation._layer_decode_slots / _layer_cached exactly, so a slot's
     stream is bitwise identical to single-request decode. Quantized
@@ -369,9 +429,9 @@ def _layer_paged(p, h, kc_l, vc_l, table, pos, valid, nh, eps, page_size,
     q, k, v = jnp.split(qkv.reshape(B, T, 3, nh, d), 3, axis=2)
     q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]
 
-    kc_l, vc_l = paged_kv_scatter(kc_l, vc_l, k, v, table, pos, valid,
-                                  page_size, ksc_l, vsc_l)
-    ctx = paged_attention_read(q, kc_l, vc_l, table, pos, page_size,
+    kc, vc = paged_kv_scatter(kc, vc, l, k, v, table, pos, valid,
+                              page_size, ksc_l, vsc_l)
+    ctx = paged_attention_read(q, kc, vc, l, table, pos, page_size,
                                use_kernel, h.dtype, ksc_l, vsc_l)
 
     attn = _adapted_proj(ctx.reshape(B, T, H), p, "out_w", wq_kernel,
@@ -382,7 +442,7 @@ def _layer_paged(p, h, kc_l, vc_l, table, pos, valid, nh, eps, page_size,
         p["up_b"].astype(h.dtype)
     up = jax.nn.gelu(up, approximate=True)
     return h + _adapted_proj(up, p, "down_w", wq_kernel, aid, ad_l) + \
-        p["down_b"].astype(h.dtype), kc_l, vc_l
+        p["down_b"].astype(h.dtype), kc, vc
 
 
 def paged_forward(params, config, ids, kc, vc, start, valid, table,
@@ -391,9 +451,12 @@ def paged_forward(params, config, ids, kc, vc, start, valid, table,
     """Fused chunk/decode forward: ids [B, T] is each slot's token window at
     absolute positions start[b]..start[b]+T-1 (valid[b] real). Returns
     logits at each slot's position valid[b]-1 ([B, V]) plus the updated
-    paged pools [L, P, page_size, nh, d]. ``kv_scales`` = (k_scale,
-    v_scale) [L, P] traced per-page dequant scales when the pool is
-    quantized; ``wq_kernel`` routes quantized weight GEMMs through the
+    paged pools [L, P, page_size, nh, d]. The pools are the layer scan's
+    CARRY and every layer addresses them as [l, ...]: no pool-shaped array
+    is sliced out as ``xs`` or re-stacked as ``ys``, so with kc/vc donated
+    the step writes B x T rows a layer and nothing else. ``kv_scales`` =
+    (k_scale, v_scale) [L, P] traced per-page dequant scales when the pool
+    is quantized; ``wq_kernel`` routes quantized weight GEMMs through the
     Pallas quant kernel (TPU). ``adapters`` = (aid [B], slabs {target:
     (A [L, cap, K, r], B [L, cap, r, F])}) traced per-slot adapter rows —
     the slabs ride the layer scan alongside the block weights and the
@@ -407,27 +470,17 @@ def paged_forward(params, config, ids, kc, vc, start, valid, table,
     ksc, vsc = kv_scales if kv_scales is not None else (None, None)
     aid, slabs = adapters if adapters is not None else (None, None)
 
-    def layer_fn(h, xs):
-        if adapters is not None:
-            xs, ad_l = xs[:-1], xs[-1]
-        else:
-            ad_l = None
-        if kv_scales is not None:
-            p_l, kc_l, vc_l, ksc_l, vsc_l = xs
-        else:
-            p_l, kc_l, vc_l = xs
-            ksc_l = vsc_l = None
-        h, kc_l, vc_l = _layer_paged(p_l, h, kc_l, vc_l, table, pos, valid,
-                                     nh, config.layer_norm_epsilon,
-                                     page_size, use_kernel, ksc_l, vsc_l,
-                                     wq_kernel, aid, ad_l)
-        return h, (kc_l, vc_l)
+    def layer_fn(carry, xs):
+        h, kc, vc = carry
+        p_l, l, ksc_l, vsc_l, ad_l = xs
+        return _layer_paged(p_l, h, kc, vc, l, table, pos, valid, nh,
+                            config.layer_norm_epsilon, page_size, use_kernel,
+                            ksc_l, vsc_l, wq_kernel, aid, ad_l), None
 
-    xs = ((params["blocks"], kc, vc) if kv_scales is None
-          else (params["blocks"], kc, vc, ksc, vsc))
-    if adapters is not None:
-        xs = xs + (slabs,)
-    x, (kc, vc) = jax.lax.scan(layer_fn, x, xs)
+    # a None (no quantized pool, no adapters) is an empty pytree to scan
+    (x, kc, vc), _ = jax.lax.scan(
+        layer_fn, (x, kc, vc),
+        (params["blocks"], layer_ids(params), ksc, vsc, slabs))
     idx = jnp.maximum(valid - 1, 0)
     xlast = jax.vmap(
         lambda xb, i: jax.lax.dynamic_slice_in_dim(xb, i, 1, axis=0))(
@@ -444,7 +497,7 @@ def paged_forward(params, config, ids, kc, vc, start, valid, table,
 # speculative decoding: verify forward (+ KV rewind) and the draft forward
 
 
-def _layer_verify(p, h, kc_l, vc_l, table, pos, valid, nh, eps, page_size,
+def _layer_verify(p, h, kc, vc, l, table, pos, valid, nh, eps, page_size,
                   use_kernel, ksc_l=None, vsc_l=None, wq_kernel=False):
     """``_layer_paged`` with the attention read decomposed PER LANE: each
     of the T window lanes reads the pool at the [B, 1] shape — the exact
@@ -463,10 +516,10 @@ def _layer_verify(p, h, kc_l, vc_l, table, pos, valid, nh, eps, page_size,
     q, k, v = jnp.split(qkv.reshape(B, T, 3, nh, d), 3, axis=2)
     q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]
 
-    kc_l, vc_l = paged_kv_scatter(kc_l, vc_l, k, v, table, pos, valid,
-                                  page_size, ksc_l, vsc_l)
+    kc, vc = paged_kv_scatter(kc, vc, l, k, v, table, pos, valid,
+                              page_size, ksc_l, vsc_l)
     ctx = jnp.concatenate(
-        [paged_attention_read(q[:, t:t + 1], kc_l, vc_l, table,
+        [paged_attention_read(q[:, t:t + 1], kc, vc, l, table,
                               pos[:, t:t + 1], page_size, use_kernel,
                               h.dtype, ksc_l, vsc_l)
          for t in range(T)], axis=1)
@@ -478,7 +531,7 @@ def _layer_verify(p, h, kc_l, vc_l, table, pos, valid, nh, eps, page_size,
     up = _proj(h2, p, "up_w", wq_kernel) + p["up_b"].astype(h.dtype)
     up = jax.nn.gelu(up, approximate=True)
     return h + _proj(up, p, "down_w", wq_kernel) + \
-        p["down_b"].astype(h.dtype), kc_l, vc_l
+        p["down_b"].astype(h.dtype), kc, vc
 
 
 def _head_logits(params, config, x, wq_kernel=False):
@@ -507,7 +560,6 @@ def paged_verify_forward(params, config, ids, kc, vc, start, valid, table,
     unchanged, and appended masked lanes contribute exact zeros."""
     compute = jnp.dtype(config.compute_dtype or "float32")
     B, T = ids.shape
-    MP = table.shape[1]
     pos = start[:, None] + jnp.arange(T)[None, :]               # [B, T]
     x = params["wte"].astype(compute)[ids] + \
         jnp.take(params["wpe"].astype(compute), pos, axis=0)
@@ -516,28 +568,22 @@ def paged_verify_forward(params, config, ids, kc, vc, start, valid, table,
     # the same phys/off routing as paged_kv_scatter: padding lanes and
     # inactive slots resolve to trash page 0, whose pre-write bytes are
     # saved (and later rewritten) harmlessly
-    writable = jnp.arange(T)[None, :] < valid[:, None]          # [B, T]
-    li = jnp.minimum(pos // page_size, MP - 1)
-    phys = jnp.where(writable, jnp.take_along_axis(table, li, axis=1), 0)
-    off = pos % page_size
+    phys, off = _write_slots(table, pos, jnp.arange(T)[None, :]
+                             < valid[:, None], page_size)
 
-    def layer_fn(h, xs):
-        if kv_scales is not None:
-            p_l, kc_l, vc_l, ksc_l, vsc_l = xs
-        else:
-            p_l, kc_l, vc_l = xs
-            ksc_l = vsc_l = None
-        saved_k = kc_l[phys, off]            # [B, T, nh, d] storage dtype
-        saved_v = vc_l[phys, off]
-        h, kc_l, vc_l = _layer_verify(p_l, h, kc_l, vc_l, table, pos,
-                                      valid, nh, config.layer_norm_epsilon,
-                                      page_size, use_kernel, ksc_l, vsc_l,
-                                      wq_kernel)
-        return h, (kc_l, vc_l, saved_k, saved_v)
+    def layer_fn(carry, xs):
+        h, kc, vc = carry
+        p_l, l, ksc_l, vsc_l = xs
+        saved_k = kc[l, phys, off]           # [B, T, nh, d] storage dtype
+        saved_v = vc[l, phys, off]
+        carry = _layer_verify(p_l, h, kc, vc, l, table, pos, valid, nh,
+                              config.layer_norm_epsilon, page_size,
+                              use_kernel, ksc_l, vsc_l, wq_kernel)
+        return carry, (saved_k, saved_v)
 
-    xs = ((params["blocks"], kc, vc) if kv_scales is None
-          else (params["blocks"], kc, vc, ksc, vsc))
-    x, (kc, vc, saved_k, saved_v) = jax.lax.scan(layer_fn, x, xs)
+    (x, kc, vc), (saved_k, saved_v) = jax.lax.scan(
+        layer_fn, (x, kc, vc),
+        (params["blocks"], layer_ids(params), ksc, vsc))
     logits = _head_logits(params, config, x, wq_kernel)         # [B, T, V]
     return logits, kc, vc, saved_k, saved_v
 
@@ -554,29 +600,21 @@ def paged_kv_rewind(kc, vc, saved_k, saved_v, table, start, valid, n_emit,
     write-only garbage. Non-restored lanes route to page 0 exactly like
     ``paged_kv_scatter``'s padding lanes."""
     T = saved_k.shape[2]
-    MP = table.shape[1]
     pos = start[:, None] + jnp.arange(T)[None, :]               # [B, T]
     lane = jnp.arange(T)[None, :]
-    restore = (lane >= n_emit[:, None]) & (lane < valid[:, None])
-    li = jnp.minimum(pos // page_size, MP - 1)
-    phys = jnp.where(restore, jnp.take_along_axis(table, li, axis=1), 0)
-    off = pos % page_size
-
-    def layer_fn(carry, xs):
-        kc_l, vc_l, sk_l, sv_l = xs
-        kc_l = kc_l.at[phys, off].set(sk_l)
-        vc_l = vc_l.at[phys, off].set(sv_l)
-        return carry, (kc_l, vc_l)
-
-    _, (kc, vc) = jax.lax.scan(layer_fn, 0, (kc, vc, saved_k, saved_v))
-    return kc, vc
+    phys, off = _write_slots(
+        table, pos, (lane >= n_emit[:, None]) & (lane < valid[:, None]),
+        page_size)
+    # one scatter over every layer at once: saved_* are [L, B, T, nh, d]
+    return kc.at[:, phys, off].set(saved_k), vc.at[:, phys, off].set(saved_v)
 
 
-def _draft_layer(p_l, h, kc_l, vc_l, sk_l, sv_l, table, base_pos, i, nh,
+def _draft_layer(p_l, h, kc, vc, l, sk_l, sv_l, table, base_pos, i, nh,
                  eps, page_size, ksc_l, vsc_l):
-    """One draft transformer block at T=1: the current draft token reads
-    the REAL paged pool (strictly below base_pos — positions at/past it
-    hold stale rewound bytes) jointly with the in-flight draft K/V
+    """Draft transformer block ``l`` at T=1: the current draft token reads
+    layer l of the REAL paged pool kc/vc [L, P, page_size, nh, d]
+    (strictly below base_pos — positions at/past it hold stale rewound
+    bytes) jointly with the in-flight draft K/V
     sidecar (lanes 0..i), one concatenated softmax. The pool is never
     written: draft K/V live only in the sidecar, so rejected drafts need
     zero rewind."""
@@ -592,7 +630,7 @@ def _draft_layer(p_l, h, kc_l, vc_l, sk_l, sv_l, table, base_pos, i, nh,
     sv_l = sv_l.at[:, i].set(vx[:, 0])
 
     S = table.shape[1] * page_size
-    kv_k = kc_l[table].reshape(B, S, nh, d)
+    kv_k = kc[l, table][..., :d].reshape(B, S, nh, d)
     sc_pool = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
                          kv_k.astype(jnp.float32)) / (d ** 0.5)
     if ksc_l is not None:
@@ -607,7 +645,7 @@ def _draft_layer(p_l, h, kc_l, vc_l, sk_l, sv_l, table, base_pos, i, nh,
         [jnp.where(pool_mask, sc_pool, -jnp.inf),
          jnp.where(side_mask, sc_side, -jnp.inf)], axis=-1)
     probs = jax.nn.softmax(scores, axis=-1)
-    kv_v = vc_l[table].reshape(B, S, nh, d).astype(jnp.float32)
+    kv_v = vc[l, table][..., :d].reshape(B, S, nh, d).astype(jnp.float32)
     if vsc_l is not None:
         v_sc = jnp.repeat(vsc_l[table], page_size, axis=1)      # [B, S]
         kv_v = kv_v * v_sc[:, :, None, None]
@@ -639,9 +677,9 @@ def paged_draft_forward(params, config, tok, kc, vc, pos, table, page_size,
     B = tok.shape[0]
     nh = config.num_heads
     d = config.hidden_size // nh
-    Ld = params["blocks"]["qkv_w"].shape[0]
+    layers = layer_ids(params)                                 # [Ld]
+    Ld = layers.shape[0]
     ksc, vsc = kv_scales if kv_scales is not None else (None, None)
-    kcd, vcd = kc[:Ld], vc[:Ld]
     kscd = ksc[:Ld] if ksc is not None else None
     vscd = vsc[:Ld] if vsc is not None else None
     sk0 = jnp.zeros((Ld, B, k, nh, d), compute)
@@ -655,20 +693,16 @@ def paged_draft_forward(params, config, tok, kc, vc, pos, table, page_size,
             jnp.take(params["wpe"].astype(compute), p, axis=0)[:, None]
 
         def layer_fn(h, xs):
-            if kv_scales is not None:
-                p_l, kc_l, vc_l, sk_l, sv_l, ksc_l, vsc_l = xs
-            else:
-                p_l, kc_l, vc_l, sk_l, sv_l = xs
-                ksc_l = vsc_l = None
-            h, sk_l, sv_l = _draft_layer(p_l, h, kc_l, vc_l, sk_l, sv_l,
+            p_l, l, sk_l, sv_l, ksc_l, vsc_l = xs
+            h, sk_l, sv_l = _draft_layer(p_l, h, kc, vc, l, sk_l, sv_l,
                                          table, pos, i, nh,
                                          config.layer_norm_epsilon,
                                          page_size, ksc_l, vsc_l)
             return h, (sk_l, sv_l)
 
-        xs = ((params["blocks"], kcd, vcd, sk, sv) if kv_scales is None
-              else (params["blocks"], kcd, vcd, sk, sv, kscd, vscd))
-        x, (sk, sv) = jax.lax.scan(layer_fn, x, xs)
+        # the pool is read-only here: closed over whole, indexed [l, table]
+        x, (sk, sv) = jax.lax.scan(
+            layer_fn, x, (params["blocks"], layers, sk, sv, kscd, vscd))
         logits = _head_logits(params, config, x[:, 0])          # [B, V]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (nxt, sk, sv), nxt

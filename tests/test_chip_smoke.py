@@ -100,11 +100,17 @@ def test_paged_and_quant_kernels_lower_for_tpu_at_the_1p3b_shapes():
         sds((B,), jnp.int32)
     for dt, scales in ((jnp.bfloat16, ()),
                        (jnp.int8, (sds((P,), jnp.float32),) * 2)):
-        pool = sds((P, ps, nh, d), dt)
         fn = paged_decode_attention_q if scales else paged_decode_attention
+        # one layer's pool, and the engine's call: a traced layer of the
+        # whole stacked pool
+        pool = sds((P, ps, nh, d), dt)
         assert _lowers_for_tpu(
             lambda *a: fn(*a, page_size=ps), q, pool, pool, tab, pos,
             *scales) == 1
+        pool = sds((24, P, ps, nh, d), dt)
+        assert _lowers_for_tpu(
+            lambda l, *a: fn(*a, page_size=ps, layer=l),
+            sds((), jnp.int32), q, pool, pool, tab, pos, *scales) == 1
     for F in (6144, 8192):
         assert _lowers_for_tpu(
             quant_gemm_kernel, sds((B, 2048), jnp.bfloat16),

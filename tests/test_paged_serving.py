@@ -458,6 +458,156 @@ def test_paged_decode_kernel_matches_gather_reference():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_decode_kernel_reads_a_layer_of_the_whole_pool(layer, quant):
+    """The engine's call hands the kernel the stacked pool [L, P, ...] and
+    a traced layer (the index_map addresses block (layer, page)): bitwise
+    the per-layer call on kc[layer], which is the old [P, ...] entry, and
+    the gather read's numbers."""
+    from paddle_tpu.serving.paged_attention import (
+        paged_attention_read, paged_decode_attention,
+        paged_decode_attention_q)
+    rng = np.random.default_rng(3)
+    L, B, nh, d, ps, MP, P = 3, 3, 8, 128, 8, 4, 11
+    q = jnp.asarray(rng.standard_normal((B, nh, d)), jnp.float32)
+    if quant:
+        kc, vc = (jnp.asarray(rng.integers(-127, 128, (L, P, ps, nh, d)),
+                              jnp.int8) for _ in range(2))
+        scales = tuple(jnp.asarray(rng.uniform(0.01, 0.1, P), jnp.float32)
+                       for _ in range(2))
+        fn = paged_decode_attention_q
+    else:
+        kc, vc = (jnp.asarray(rng.standard_normal((L, P, ps, nh, d)),
+                              jnp.float32) for _ in range(2))
+        scales = ()
+        fn = paged_decode_attention
+    table = jnp.asarray(rng.integers(1, P, (B, MP)), jnp.int32)
+    pos = jnp.asarray([5, 17, 30], jnp.int32)
+
+    whole = fn(q, kc, vc, table, pos, *scales, page_size=ps,
+               layer=jnp.asarray(layer, jnp.int32), interpret=True)
+    one = fn(q, kc[layer], vc[layer], table, pos, *scales, page_size=ps,
+             interpret=True)
+    assert (np.asarray(whole) == np.asarray(one)).all()
+    want = paged_attention_read(q[:, None], kc, vc, layer, table,
+                                pos[:, None], ps, False, jnp.float32,
+                                *scales)[:, 0]
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_pool_pads_head_dim_to_lanes_and_nothing_sees_the_pad():
+    """The device pool's last axis is head_dim padded up to 128 lanes (its
+    row-major layout is then the device's default on a TPU); the pad
+    holds zeros whatever was served, and a snapshot keeps the model's
+    head_dim."""
+    from paddle_tpu.serving.paged_attention import pool_head_dim
+    assert [pool_head_dim(d) for d in (16, 80, 128, 160)] == \
+        [128, 128, 128, 256]
+    d = CFG.hidden_size // CFG.num_heads
+    eng = _engine()
+    assert eng._kc.shape[-1] == pool_head_dim(d) > d
+    eng.run(_mixed_requests(4, np.random.default_rng(5)))
+    kc, vc = np.asarray(eng._kc), np.asarray(eng._vc)
+    assert np.abs(kc[..., :d]).max() > 0
+    assert not kc[..., d:].any() and not vc[..., d:].any()
+    state = eng.state_dict()
+    assert state["kc"].shape == kc.shape[:-1] + (d,)
+    assert (state["kc"] == kc[..., :d]).all()
+
+
+# ---------------------------------------------------------------------------
+# structural gate: the pool is the layer scan's carry, never its xs or ys
+
+
+def _subjaxprs(obj):
+    if hasattr(obj, "eqns"):
+        yield obj
+    elif hasattr(obj, "jaxpr"):
+        yield from _subjaxprs(obj.jaxpr)
+    elif isinstance(obj, (tuple, list)):
+        for o in obj:
+            yield from _subjaxprs(o)
+
+
+def _scans(jaxpr):
+    """Every scan equation of a jaxpr, nested ones (pjit, shard_map, scan,
+    cond, custom calls) included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in _subjaxprs(v):
+                yield from _scans(sub)
+
+
+def _assert_pool_is_carried(closed, pool_shape):
+    """``pool_shape`` [L, P, page, nh, d]: no scan may take as xs or return
+    as ys the pool or a layer of it (any head count: an mp shard holds
+    nh/mp), the first two outputs are the pools, and they ride one scan
+    as carry."""
+    L, P, page, _, d = pool_shape
+
+    def pool_like(v):
+        sh = v.aval.shape
+        return len(sh) in (4, 5) and sh[-4:-2] == (P, page) and sh[-1] == d
+
+    carried = []
+    for eqn in _scans(closed.jaxpr):
+        nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+        stacked = eqn.invars[nc + nk:] + eqn.outvars[nk:]
+        bad = [v.aval for v in stacked if pool_like(v)]
+        assert not bad, f"a scan slices or re-stacks the pool: {bad}"
+        carried.append([v.aval.shape for v in eqn.outvars[:nk]
+                        if pool_like(v)])
+    assert [c for c in carried if len(c) == 2 and
+            all(len(sh) == 5 and sh[0] == L for sh in c)], carried
+    assert [v.aval.shape for v in closed.jaxpr.outvars[:2]] == \
+        [tuple(pool_shape)] * 2
+
+
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("variant", ["plain", "kernel", "verify", "mp"])
+def test_pool_is_the_layer_scans_carry(variant, quant):
+    """On the jaxpr of the step the engine builds, at its steady-state
+    shapes [B, 1] and [1, chunk] ([B, k+1] for verify): what made every
+    dispatch copy the pool in and out of the scan, layer by layer, was the
+    pool riding as xs and coming back as stacked ys. Platform independent:
+    the kernel variant traces the step the engine builds on a TPU."""
+    from paddle_tpu.serving import engine as E
+    kw = {"verify": {"speculate_k": 3}, "mp": {"mp": 2}}.get(variant, {})
+    # num_slots=9 is unique in the suite: these traces warm no executable
+    # that a trace-count gate of another test counts
+    eng = _engine(num_slots=9, quant=quant, **kw)
+    B, C, MP = 9, eng.prefill_chunk, eng.pool.table.shape[1]
+    step = eng._paged_step
+    if variant == "kernel":
+        step = E._make_paged_step(
+            E._cfg_key(CFG), eng.top_k, eng.page_size, True, (),
+            quant=None if quant is None else eng._quant.key())
+
+    def operands(b, t):
+        z = lambda *sh, dt=np.int32: jnp.zeros(sh, dt)
+        return (eng.params, eng._kc, eng._vc, z(b, t), z(b), z(b),
+                z(b, dt=bool), z(b, MP))
+
+    def sampling(b):
+        return (jnp.zeros(b, bool), jnp.ones(b, np.float32),
+                jnp.ones(b, np.float32), jnp.zeros((b, 2), np.uint32))
+
+    if variant == "verify":
+        traced = [jax.make_jaxpr(eng._spec_verify)(
+            *operands(B, 4), jnp.zeros(B, np.int32), *sampling(B),
+            *eng._kv_scale_args())]
+    else:
+        traced = [jax.make_jaxpr(step)(*operands(b, t), *sampling(b),
+                                       *eng._kv_scale_args())
+                  for b, t in ((B, 1), (1, C))]
+    for closed in traced:
+        _assert_pool_is_carried(closed, eng._kc.shape)
+
+
 def test_paged_kernel_routing_predicate():
     from paddle_tpu.serving.paged_attention import paged_kernel_supported
     # off-TPU backends always fall back to the jnp gather path

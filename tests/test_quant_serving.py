@@ -556,8 +556,8 @@ def test_paged_decode_kernel_quant_interpret_parity():
     pos = jnp.asarray([[17], [9]], jnp.int32)
     ksc = jnp.asarray(rng.uniform(0.01, 0.1, P), jnp.float32)
     vsc = jnp.asarray(rng.uniform(0.01, 0.1, P), jnp.float32)
-    ref = paged_attention_read(q, kq, vq, table, pos, ps, False,
-                               jnp.float32, ksc, vsc)
+    ref = paged_attention_read(q, kq[None], vq[None], 0, table, pos, ps,
+                               False, jnp.float32, ksc, vsc)
     got = paged_decode_attention_q(q[:, 0], kq, vq, table, pos[:, 0],
                                    ksc, vsc, page_size=ps,
                                    interpret=True)[:, None]

@@ -1,0 +1,96 @@
+"""The paged step compiled for a described (not attached) v5e chip, at the
+serving cells' attention geometry and two layers: what XLA:TPU makes of the
+KV pool. The CPU jaxpr gate (test_paged_serving) shows the pool is the layer
+scan's carry; this shows the chip's compiler then updates it in place — no
+copy, zero fill or relayout of a whole pool, the outputs in the donated
+buffers — and that the decode kernel keeps the name the benchmark
+finds it by.
+
+The topology is described inside a fixture (never at import: one process
+holds the TPU library, and every xdist worker imports this file)."""
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.models.gpt import GPTConfig
+from paddle_tpu.models.gpt_hybrid import init_gpt_params
+from paddle_tpu.serving import engine as E
+from paddle_tpu.serving.paged_attention import pool_head_dim
+
+PAGE, SLOTS, MAX_SEQ = 16, 16, 2048
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or another process has it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # the persistent compile cache cannot read back what was built for a
+    # described chip (it warns and compiles again): keep these out of it
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile_step(chip, cfg, num_pages, batch, window, use_kernel):
+    """The engine's own builder, lowered on shapes placed on the described
+    chip; the pool as the engine allocates it."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, "bfloat16"),
+        jax.eval_shape(lambda: init_gpt_params(cfg, jax.random.key(0))))
+    pool = sds((cfg.num_layers, num_pages, PAGE, cfg.num_heads,
+                pool_head_dim(cfg.hidden_size // cfg.num_heads)), "bfloat16")
+    step = E._make_paged_step(E._cfg_key(cfg), None, PAGE, use_kernel,
+                              (1, 2))
+    b = batch
+    return pool, step.lower(
+        params, pool, pool, sds((b, window), "int32"), sds((b,), "int32"),
+        sds((b,), "int32"), sds((b,), "bool"),
+        sds((b, MAX_SEQ // PAGE), "int32"), sds((b,), "bool"),
+        sds((b,), "float32"), sds((b,), "float32"),
+        sds((b, 2), "uint32")).compile()
+
+
+@pytest.mark.parametrize("name,hidden,heads,num_pages,batch,window,kernel", [
+    ("1.3B-decode", 2048, 16, 2049, SLOTS, 1, True),
+    ("1.3B-chunk", 2048, 16, 2049, 1, 16, True),
+    ("2.7B-decode", 2560, 32, 769, SLOTS, 1, False),
+    ("2.7B-chunk", 2560, 32, 769, 1, 16, False),
+])
+def test_paged_step_updates_the_pool_in_place_on_the_chip(
+        chip, name, hidden, heads, num_pages, batch, window, kernel):
+    cfg = GPTConfig(vocab_size=1024, hidden_size=hidden, num_layers=2,
+                    num_heads=heads, max_seq_len=MAX_SEQ, dropout=0.0,
+                    use_flash=False, compute_dtype="bfloat16", remat=False)
+    pool, compiled = _compile_step(chip, cfg, num_pages, batch, window,
+                                   kernel)
+    text = compiled.as_text()
+    dims = ",".join(str(n) for n in pool.shape)
+    whole = re.findall(
+        rf"(%[\w.\-]+) = bf16\[{dims}\]\S* ([\w\-]+)\(", text)
+    # (a dynamic-update-slice INTO the carried pool is the in-place write
+    # of a row; one that had to copy first would show the copy)
+    moved = [(op, code) for op, code in whole
+             if code in ("copy", "copy-start", "broadcast", "transpose")]
+    assert not moved, f"{name}: the whole pool is moved by {moved}"
+    # both pools come back in the buffers they came in
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * 2 * pool.size
+    calls = re.findall(r"(%[\w.\-]+) = \S+ custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    if kernel and window == 1:
+        assert [c for c in calls if c.startswith("%paged_decode_attention")]
+    else:
+        assert not calls
