@@ -1,81 +1,90 @@
 """The comparison that decides ``correct``: per-leaf norms of trees, the
 worst-leaf gap between the program's norms and the reference's, and the
-served tokens' logit gap. Each number compared is printed beside its limit."""
+served tokens' logit gap. Each number compared is printed beside its limit.
+
+A tree is a dict of leaves and of groups (dicts of leaves stacked over the
+layers). What is particular to a model comes from its family's ``weights``
+module (``W`` below): ``leaf_parts(name)``, the parts of a leaf's last axis
+whose norms are compared apart, and ``seed_leaves(cfg, key, dtype)``, every
+leaf made again from the seed."""
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import weights as W
+from .weights import seed_key
 
 F32 = jnp.float32
 
 
-def _thirds_sq(a):
-    """Sums of squares of the q, k, v thirds of the last axis."""
+def _parts_sq(a, n):
+    """Sums of squares of the n equal parts of the last axis."""
     lead = a.shape[:-1]
-    a3 = a.reshape(lead + (3, a.shape[-1] // 3))
-    return jnp.sum(jnp.square(a3), axis=tuple(i for i in range(a3.ndim)
-                                              if i != a3.ndim - 2))
+    an = a.reshape(lead + (n, a.shape[-1] // n))
+    return jnp.sum(jnp.square(an), axis=tuple(i for i in range(an.ndim)
+                                              if i != an.ndim - 2))
 
 
-def _sq(name, a):
+def _sq(name, a, leaf_parts):
     a = a.astype(F32)
-    if name.startswith("qkv_"):
-        t = _thirds_sq(a)
-        return {f"{name}.{p}": t[i] for i, p in enumerate("qkv")}
+    parts = leaf_parts(name)
+    if parts:
+        t = _parts_sq(a, len(parts))
+        return {f"{name}.{p}": t[i] for i, p in enumerate(parts)}
     return {name: jnp.sum(jnp.square(a))}
 
 
-@jax.jit
-def leaf_sq_norms(tree):
-    """{leaf name: sum of squares}; block leaves take all layers together."""
+@functools.partial(jax.jit, static_argnames=("leaf_parts",))
+def leaf_sq_norms(tree, leaf_parts):
+    """{leaf name: sum of squares}; a group's leaves take all layers
+    together."""
     out = {}
     for name, a in tree.items():
-        if name == "blocks":
+        if isinstance(a, dict):
             for bn, ba in a.items():
-                out.update({f"blocks.{k}": v for k, v in _sq(bn, ba).items()})
+                out.update({f"{name}.{k}": v
+                            for k, v in _sq(bn, ba, leaf_parts).items()})
         else:
-            out.update(_sq(name, a))
+            out.update(_sq(name, a, leaf_parts))
     return out
 
 
 @functools.lru_cache(maxsize=None)
-def _change_fn(cfg_items, dtype):
+def _change_fn(W, cfg_items, dtype):
     cfg = dict(cfg_items)
     dtype = jnp.dtype(dtype)
 
     @jax.jit
     def go(tree, key):
         out = {}
-        for name, a in tree.items():
-            if name == "blocks":
+        for name, layers, make in W.seed_leaves(cfg, key, dtype):
+            if layers is None:
+                d = tree[name].astype(F32) - make().astype(F32)
+                out.update(_sq(name, d, W.leaf_parts))
                 continue
-            d = a.astype(F32) - W.top_leaf(cfg, key, name, dtype).astype(F32)
-            out.update(_sq(name, d))
 
-        def layer(l):
-            init = W.layer_leaves(cfg, key, l, dtype)
-            res = {}
-            for bn in init:
-                d = tree["blocks"][bn][l].astype(F32) - init[bn].astype(F32)
-                res.update(_sq(bn, d))
-            return res
+            def layer(l, name=name, make=make):
+                res = {}
+                for bn, init in make(l).items():
+                    d = tree[name][bn][l].astype(F32) - init.astype(F32)
+                    res.update(_sq(bn, d, W.leaf_parts))
+                return res
 
-        per_layer = jax.lax.map(layer, jnp.arange(cfg["num_layers"]))
-        out.update({f"blocks.{k}": jnp.sum(v) for k, v in per_layer.items()})
+            per_layer = jax.lax.map(layer, jnp.arange(layers))
+            out.update({f"{name}.{k}": jnp.sum(v)
+                        for k, v in per_layer.items()})
         return out
 
     return go
 
 
-def change_sq_norms(tree, cfg, seed, dtype):
+def change_sq_norms(tree, W, cfg, seed, dtype):
     """{leaf name: sum of squares of (leaf now - leaf as the seed made it)},
     the seed's leaves made again one layer at a time."""
     items = tuple(sorted((k, v) for k, v in cfg.items()
                          if isinstance(v, (int, float))))
-    return _change_fn(items, str(dtype))(tree, W.seed_key(seed))
+    return _change_fn(W, items, str(dtype))(tree, seed_key(seed))
 
 
 def norms(sq):

@@ -93,11 +93,24 @@ def device_info(devices, peak_bytes):
             "count": jax.device_count(), "memory_peak_bytes": int(peak_bytes)}
 
 
+class Work:
+    """The required-work functions a reader sees as ``ctx.work``: the cell's
+    family's counts (``families/<family>/work.py``) and, under the same name
+    as ever, the harness's ``roofline_seconds``."""
+    roofline_seconds = staticmethod(work.roofline_seconds)
+
+    def __init__(self, family_work):
+        self._family_work = family_work
+
+    def __getattr__(self, name):
+        return getattr(self._family_work, name)
+
+
 class Context:
     """What a per-layer metric's reader gets: the cell's files, the peaks of
-    this device, the required-work functions, the reduced trace (or None),
-    the benchmark's host spans, the program's counters over the window and
-    the run's own facts."""
+    this device, the required-work functions of the cell's model family, the
+    reduced trace (or None), the benchmark's host spans, the program's
+    counters over the window and the run's own facts."""
 
     def __init__(self, cell, devices, window_s, spans, counters, facts,
                  trace=None, traced=None, stop_cost_s=0.0):
@@ -109,7 +122,7 @@ class Context:
         self.on_chip = devices[0].platform == "tpu"
         self.peaks = peaks.peaks_for(devices[0].device_kind) \
             if self.on_chip else None
-        self.work = work
+        self.work = Work(cell.family.work)
         self.window_s = window_s
         # the window less what writing the trace out took inside it: what a
         # rate read in the traced run is taken over

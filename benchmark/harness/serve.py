@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import compare, events, reference, runner, spans as spans_mod, sut, \
-    traffic, weights
+    traffic
 
 STEP_SPAN = "Engine.step"
 SUBMIT_SPAN = "Engine.submit"
@@ -257,12 +257,12 @@ def reference_gap(cell, seed, ids, rows, mm="exact"):
     reference's best. With ``mm`` naming the control, the tokens are instead
     those that the lower precision puts first at the same positions."""
     cfg = cell.config
-    ref = reference.served_logits(cfg, seed, jnp.asarray(ids),
-                                  cfg["dtypes"]["params"])
+    served_logits = cell.family.reference.served_logits
+    ref = served_logits(cfg, seed, jnp.asarray(ids), cfg["dtypes"]["params"],
+                        reference.mm_exact)
     if mm != "exact":
-        low = reference.served_logits(cfg, seed, jnp.asarray(ids),
-                                      cfg["dtypes"]["params"],
-                                      reference.MATMULS[mm])
+        low = served_logits(cfg, seed, jnp.asarray(ids),
+                            cfg["dtypes"]["params"], reference.MATMULS[mm])
         first = np.asarray(jnp.argmax(low, axis=-1))
         rows = [(p, first[i, p - 1:p - 1 + len(t)].tolist())
                 for i, (p, t) in enumerate(rows)]
@@ -274,8 +274,8 @@ def run(cell, seed, seconds, want_trace, t_start, devices):
     cfg, mix, chk = cell.config, cell.traffic, cell.file["check"]
     sp = spans_mod.Spans()
     ev = events.JaxEvents()
-    w = weights.make_weights(cfg, seed, cfg["dtypes"]["params"])
-    engine = sut.make_engine(cfg, cell.file["engine"], w)
+    w = cell.family.weights.make_weights(cfg, seed, cfg["dtypes"]["params"])
+    engine = cell.family.sut.make_engine(cfg, cell.file["engine"], w)
     del w
     warm_up(engine, cfg, engine.page_size, sp)
     source = traffic.RequestSource(mix, seed, cfg["vocab_size"])

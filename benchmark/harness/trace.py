@@ -20,6 +20,7 @@ import os
 import re
 
 MOSAIC = 'custom_call_target="tpu_custom_call"'
+PROGRAM_SPANS = "pt."    # the prefix of the program's own host spans
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 WRAPPER = re.compile(r"^%(while|conditional|call)[.\s=]")
 NAME_KEEP = 400          # characters of an operation's text that are kept
@@ -60,6 +61,10 @@ class Trace:
 
     @classmethod
     def from_xplane(cls, path, span_names, window_span):
+        """Of the host planes it keeps the benchmark's own spans
+        (``span_names``) and the program's (names that start with ``pt.``;
+        what the annotation carries beside its name follows it as
+        ``#key=value,...#``)."""
         import jax
         pd = jax.profiler.ProfileData.from_file(path)
         devices, host = {}, []
@@ -80,6 +85,10 @@ class Trace:
                     for ev in line.events:
                         if ev.name in names:
                             host.append([ev.name, ev.start_ns, ev.duration_ns])
+                        elif ev.name.startswith(PROGRAM_SPANS):
+                            args = ",".join(f"{k}={v}" for k, v in ev.stats)
+                            name = ev.name + (f"#{args}#" if args else "")
+                            host.append([name, ev.start_ns, ev.duration_ns])
         win = [h for h in host if h[0] == window_span]
         if not win:
             raise RuntimeError("the trace holds no window span")
@@ -168,11 +177,14 @@ class Trace:
         """[[host span, seconds], ...]: the device's idle time in the window
         by the benchmark's host span that covered the middle of each gap,
         longest first. The benchmark's spans do not nest, so the span is the
-        last one that started before the middle; gaps under two microseconds
-        are the seams between operations and are summed under one name."""
+        last one that started before the middle (the program's spans nest
+        inside them and name no gap yet); gaps under two microseconds are
+        the seams between operations and are summed under one name."""
         busy = self._union(self.devices[dev]["ops"])
         gaps = self._minus([list(self.window)], busy)
-        spans = sorted(self.host, key=lambda h: h[1])
+        spans = sorted((h for h in self.host
+                        if not h[0].startswith(PROGRAM_SPANS)),
+                       key=lambda h: h[1])
         starts = [h[1] for h in spans]
         tot = {}
         for a, b in gaps:

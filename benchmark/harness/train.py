@@ -1,12 +1,14 @@
 """A training run: one compiled step with its state is built from the seed,
 driven through its first steps by the window's own call and feed, handed to
 the measured window, and then compared with the plain reference."""
+import functools
+import sys
 import time
 
 import jax
 
 from . import compare, events, reference, runner, spans as spans_mod, sut, \
-    traffic, weights
+    traffic
 
 STEP_SPAN = "HybridTrainStep.__call__"
 FEED_SPAN = "input batch"
@@ -25,11 +27,26 @@ def _feed_and_step(step, mix, seed, i, vocab, sp):
     return loss
 
 
+def require_trainable(cell):
+    """A family may serve only: a training cell of one is refused in a
+    sentence, before any set-up."""
+    fam = cell.family
+    if not hasattr(fam.reference, "loss_and_grads"):
+        sys.exit(f"benchmark: {cell.name} is a training cell, and the model "
+                 f"family {fam.name!r} ({fam.path}) has no loss_and_grads in "
+                 f"its reference.py: it serves only; nothing was run")
+
+
+def _leaf_sq_norms(cell):
+    return functools.partial(compare.leaf_sq_norms,
+                             leaf_parts=cell.family.weights.leaf_parts)
+
+
 def program_readings(step, cell, seed, sp, n_check):
     """Drive the step through its first ``n_check`` steps; read each loss,
     the first gradient's per-leaf norms out of the optimizer's state after
     step 1, and the parameters' change after every step."""
-    cfg, mix = cell.config, cell.traffic
+    cfg, mix, fam = cell.config, cell.traffic, cell.family
     b1 = cell.file["trainer"]["optimizer"]["beta1"]
     losses, change = [], []
     grad = None
@@ -37,10 +54,11 @@ def program_readings(step, cell, seed, sp, n_check):
         losses.append(float(_feed_and_step(step, mix, seed, i,
                                            cfg["vocab_size"], sp)))
         if i == 0:
-            m = compare.norms(compare.leaf_sq_norms(sut.trainer_moment1(step)))
+            m = compare.norms(_leaf_sq_norms(cell)(
+                fam.sut.trainer_moment1(step)))
             grad = {k: v / (1 - b1) for k, v in m.items()}
         change.append(compare.norms(compare.change_sq_norms(
-            step.params, cfg, seed, cfg["dtypes"]["params"])))
+            step.params, fam.weights, cfg, seed, cfg["dtypes"]["params"])))
     return {"losses": losses, "grad": grad, "change": change}
 
 
@@ -51,7 +69,8 @@ def reference_readings(cell, seed, n_steps, mm="exact", batch_rows=None):
     cfg, mix, chk = cell.config, cell.traffic, cell.file["check"]
     hp = cell.file["trainer"]["optimizer"]
     ref = reference.TrainReference(
-        cfg, seed, hp, rows=chk["reference_rows"], mm=reference.MATMULS[mm],
+        cell.family, cfg, seed, hp, rows=chk["reference_rows"],
+        mm=reference.MATMULS[mm],
         param_dtype=cfg["dtypes"]["params"],
         moment_dtype=cfg["dtypes"]["moments"])
     losses, change = [], []
@@ -61,12 +80,13 @@ def reference_readings(cell, seed, n_steps, mm="exact", batch_rows=None):
         if batch_rows is not None:
             ids = ids[batch_rows]
         losses.append(ref.step(
-            ids, leaf_sq_norms=compare.leaf_sq_norms if i == 0 else None,
+            ids, leaf_sq_norms=_leaf_sq_norms(cell) if i == 0 else None,
             last=i == n_steps - 1))
         if i == 0:
             grad = compare.norms(ref.grad_sq)
         change.append(compare.norms(compare.change_sq_norms(
-            ref.params, cfg, seed, cfg["dtypes"]["params"])))
+            ref.params, cell.family.weights, cfg, seed,
+            cfg["dtypes"]["params"])))
     ref.params = ref.m = ref.v = None
     return {"losses": losses, "grad": grad, "change": change,
             "seconds": ref.seconds}
@@ -92,14 +112,15 @@ def compare_readings(prog, ref, limits, checks=None):
 
 
 def run(cell, seed, seconds, want_trace, t_start, devices):
+    require_trainable(cell)
     cfg, mix, chk = cell.config, cell.traffic, cell.file["check"]
-    trainer = cell.file["trainer"]
+    trainer, fam = cell.file["trainer"], cell.family
     sp = spans_mod.Spans()
     ev = events.JaxEvents()
     mesh = sut.make_mesh(trainer.get("mesh"))
-    w = weights.make_weights(cfg, seed, cfg["dtypes"]["params"],
-                             sut.param_shardings(cfg, mesh))
-    step = sut.make_trainer(cfg, trainer, w, mesh)
+    w = fam.weights.make_weights(cfg, seed, cfg["dtypes"]["params"],
+                                 fam.sut.param_shardings(cfg, mesh))
+    step = fam.sut.make_trainer(cfg, trainer, w, mesh)
     del w
     prog = program_readings(step, cell, seed, sp, chk["steps"])
     tokens_per_step = mix["batch"] * mix["seq"]

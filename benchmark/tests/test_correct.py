@@ -34,7 +34,8 @@ def test_control_fails_serving(rehearsal_cell):
     cfg = cell.config
     rng = np.random.default_rng(SEED)
     ids = rng.integers(0, cfg["vocab_size"], (4, 128)).astype(np.int32)
-    logits = np.asarray(reference.served_logits(cfg, SEED, ids, "float32"))
+    logits = np.asarray(cell.family.reference.served_logits(
+        cfg, SEED, ids, "float32", reference.mm_exact))
     # greedy continuations under the reference itself: gap 0
     rows = [(40, logits[i, 39:39 + 60].argmax(-1).tolist()) for i in range(4)]
     gap, n = serve.reference_gap(cell, SEED, ids, rows)
@@ -95,8 +96,8 @@ class _HalfBatch(_StateUnchanged):
 def test_broken_train_step_is_not_correct(rehearsal_cell, capsys, monkeypatch,
                                           fault):
     cell = rehearsal_cell("tiny.train-tiny")
-    real = sut.make_trainer
-    monkeypatch.setattr(sut, "make_trainer",
+    real = cell.family.sut.make_trainer
+    monkeypatch.setattr(cell.family.sut, "make_trainer",
                         lambda *a, **k: fault(real(*a, **k)))
     bench_run.run_cell(cell, SEED, 0.5, False, require_chip=False)
     res = last_json_line(capsys.readouterr().out)

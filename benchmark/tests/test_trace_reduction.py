@@ -71,6 +71,37 @@ def test_hand_made_numbers():
     assert t.used_devices() == [0, 1]
 
 
+def test_program_spans_are_kept_and_name_no_gap(tmp_path):
+    """``from_xplane`` keeps a host event under one of the benchmark's own
+    span names or under the program's prefix ``pt.``, and drops the rest; a
+    gap is still named by the benchmark's span, though the program's nest
+    inside it."""
+    import jax
+    from jax.profiler import TraceAnnotation
+    jax.profiler.start_trace(str(tmp_path))
+    with TraceAnnotation("the window"):
+        with TraceAnnotation("Engine.step"):
+            with TraceAnnotation("pt.serve.step"):
+                with TraceAnnotation("pt.serve.feed", kind="decode"):
+                    jax.block_until_ready(jax.numpy.ones(8) + 1)
+        with TraceAnnotation("somebody else's span"):
+            pass
+    jax.profiler.stop_trace()
+    t = T.Trace.from_xplane(T.newest_xplane(str(tmp_path)), ["Engine.step"],
+                            "the window")
+    names = [h[0] for h in t.host]
+    assert sorted(names) == ["Engine.step", "pt.serve.feed#kind=decode#",
+                             "pt.serve.step"]
+
+    ms = 1e6
+    h = hand_made()
+    before = h.idle_gaps(0)
+    h.host += [["pt.serve.step", 13 * ms, 8 * ms],
+               ["pt.serve.wait#kind=decode#", 16 * ms, 3 * ms]]
+    assert h.idle_gaps(0) == before
+    assert dict(before)["Engine.step"] == pytest.approx(0.006)
+
+
 def test_window_clips_operations():
     t = hand_made()
     t.window = [5e6, 9e6]

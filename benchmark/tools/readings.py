@@ -29,14 +29,16 @@ def _verdict(tag, seed, checks):
 
 
 def train_seed(cell, seed, control):
-    from benchmark.harness import spans, sut, train, weights
+    from benchmark.harness import spans, sut, train
+    train.require_trainable(cell)
     cfg, trainer, chk = cell.config, cell.file["trainer"], cell.file["check"]
+    fam = cell.family
     ref_steps, limits = chk["reference_steps"], chk["limits"]
     mesh = sut.make_mesh(trainer.get("mesh"))
-    sh = sut.param_shardings(cfg, mesh)
+    sh = fam.sut.param_shardings(cfg, mesh)
     t0 = time.perf_counter()
-    w = weights.make_weights(cfg, seed, cfg["dtypes"]["params"], sh)
-    step = sut.make_trainer(cfg, trainer, w, mesh)
+    w = fam.weights.make_weights(cfg, seed, cfg["dtypes"]["params"], sh)
+    step = fam.sut.make_trainer(cfg, trainer, w, mesh)
     del w
     prog = train.program_readings(step, cell, seed, spans.Spans(), chk["steps"])
     t_prog = time.perf_counter() - t0
@@ -64,12 +66,12 @@ def train_seed(cell, seed, control):
 
 
 def serve_seed(cell, seed, control, seconds):
-    from benchmark.harness import compare, runner, serve, spans, sut, traffic, \
-        weights
+    from benchmark.harness import compare, runner, serve, spans, sut, traffic
     cfg, mix, chk = cell.config, cell.traffic, cell.file["check"]
+    fam = cell.family
     sp = spans.Spans()
-    w = weights.make_weights(cfg, seed, cfg["dtypes"]["params"])
-    engine = sut.make_engine(cfg, cell.file["engine"], w)
+    w = fam.weights.make_weights(cfg, seed, cfg["dtypes"]["params"])
+    engine = fam.sut.make_engine(cfg, cell.file["engine"], w)
     del w
     serve.warm_up(engine, cfg, engine.page_size, sp)
     log = serve.ServeLog()
