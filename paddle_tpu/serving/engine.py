@@ -65,17 +65,17 @@ from ..observability import tracing as obs_tracing
 from ..utils import fault_injection as _fi
 from ..models.generation import (
     _cfg_key, _cfg_view, _collect_params, _forward_cached,
-    _forward_decode_slots, _logical_qkv, _mask_logits, _verify_accept,
+    _forward_decode_slots, _mask_logits, _verify_accept,
 )
 from . import metrics
 from . import quant as _squant
 from .adapters import AdapterRegistry, AdapterSpec, UnknownAdapterError
 from .kv_transfer import KVTransfer, PagePayload
 from .paged_attention import (
-    pad_lanes, paged_draft_forward, paged_forward, paged_kernel_supported,
-    paged_kv_rewind, paged_verify_forward, pool_head_dim,
+    pad_lanes, paged_draft_forward, paged_kv_rewind, paged_verify_forward,
 )
 from .paged_kv import PagedKVPool, pages_for
+from .served_model import GPT, served_model
 from .request import (
     CANCELLED, ERROR, EXPIRED, FINISHED, LENGTH, QUEUED, RUNNING, SHED,
     STOP, GenerationResult, Request,
@@ -165,7 +165,7 @@ def _make_decode(cfg, top_k, donate):
 @lru_cache(maxsize=None)
 def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
                      mp_key=None, anomaly=False, quant=None,
-                     qkernel=False, adapters=None):
+                     qkernel=False, adapters=None, model=GPT):
     """Build the FUSED chunk/decode executable over the paged pool: every
     batch row is a slot processing a T-token window (ids' second dim) at
     its own offset. The engine dispatches it at exactly two steady-state
@@ -206,32 +206,32 @@ def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
     included) shares this one executable at its two steady-state shapes,
     and adapter load/evict/swap (content-only slab rewrites) never
     retrace. adapters=None is byte-identical to the adapter-less
-    builder."""
-    config = _cfg_view(cfg)
+    builder.
+
+    ``model`` is the served model's seam (serving/served_model.py): its
+    forward takes and returns the pools, as many arrays as its cache
+    geometry names (GPT: kc, vc), which ride as the operands after
+    ``params`` and come back first; where the forward returns statistics
+    they are the step's last output."""
+    config = model.view(cfg)
+    n_pools = len(model.geometry(config).names)
     kvq = quant is not None and quant[1] != "bf16"
 
-    def fn(params, kc, vc, ids, start, valid, emit, table, do_sample,
-           temperature, top_p, key_data, *extra):
+    def fn(params, *operands):
         metrics.bump("paged_traces")  # body runs only when traced
-        rest = list(extra)
+        pools = operands[:n_pools]
+        (ids, start, valid, emit, table, do_sample, temperature, top_p,
+         key_data, *rest) = operands[n_pools:]
         scales = None
         if kvq:
             scales = (rest[0], rest[1])
             rest = rest[2:]
         ad = (rest[0], rest[1]) if adapters is not None else None
-        if mp_key is None:
-            logits, kc, vc = paged_forward(params, config, ids, kc, vc,
-                                           start, valid, table, page_size,
-                                           use_kernel, kv_scales=scales,
-                                           wq_kernel=qkernel, adapters=ad)
-        else:
-            from .mp_forward import mp_paged_forward
-            logits, kc, vc = mp_paged_forward(params, config, ids, kc, vc,
-                                              start, valid, table,
-                                              page_size, use_kernel,
-                                              mp_key[0], mp_key[1],
-                                              kv_scales=scales,
-                                              adapters=ad)
+        logits, pools, stats = model.forward(
+            params, config, ids, pools, start, valid, table, page_size,
+            use_kernel=use_kernel, kv_scales=scales, wq_kernel=qkernel,
+            adapters=ad, mp_key=mp_key)
+        tail = () if stats is None else (stats,)
         keys = jax.random.wrap_key_data(key_data)           # [B] keys
         pair = jax.vmap(jax.random.split)(keys)             # [B, 2] keys
         subs = pair[:, 1]
@@ -244,8 +244,8 @@ def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
                              key_data)
         if anomaly:
             ok = jnp.all(jnp.isfinite(logits), axis=-1)     # [B] per-slot
-            return kc, vc, nxt, new_keys, ok
-        return kc, vc, nxt, new_keys
+            return (*pools, nxt, new_keys, ok, *tail)
+        return (*pools, nxt, new_keys, *tail)
 
     return jax.jit(fn, donate_argnums=donate)
 
@@ -255,11 +255,9 @@ def _make_page_copy(donate):
     """Physical page copy (the CoW split): one executable, src/dst traced
     scalars, reused for every copy-on-write divergence."""
 
-    def fn(kc, vc, src, dst):
+    def fn(pools, src, dst):
         metrics.bump("copy_traces")  # body runs only when traced
-        kc = kc.at[:, dst].set(kc[:, src])
-        vc = vc.at[:, dst].set(vc[:, src])
-        return kc, vc
+        return tuple(a.at[:, dst].set(a[:, src]) for a in pools)
 
     return jax.jit(fn, donate_argnums=donate)
 
@@ -400,6 +398,9 @@ class Engine:
             raise ValueError("Engine needs a GPTForCausalLM model, or "
                              "params= (init_gpt_params layout) + config=")
         self.config = config
+        # the served model's seam (serving/served_model.py): its cache
+        # geometry and its paged forward are all the engine asks of it
+        self._model = served_model(config)
         flags = get_flags()
 
         # -- quantized serving (serving/quant.py): resolve the dtype
@@ -409,6 +410,7 @@ class Engine:
         # below is skipped: the engine is byte-identical to the
         # unquantized one (the flags-off parity contract).
         self._quant = _squant.resolve(quant, flags)
+        self._refuse("quant", self._quant is not None)
         if self._quant is not None:
             _squant.validate(self._quant, params, config)
             # fill missing KV clip ranges by the automatic one-forward
@@ -425,6 +427,7 @@ class Engine:
         # identical to the single-chip engine on every collective rung.
         if mesh is None and mp is None:
             mp = int(flags.get("FLAGS_serving_mp", 0) or 0)
+        self._refuse("mp", mesh is not None or int(mp or 0) > 1)
         if mesh is None and mp is not None and int(mp) > 1:
             from .mp_forward import replica_mesh
             mesh = replica_mesh(int(mp))
@@ -452,10 +455,7 @@ class Engine:
                                                quant_spec=self._quant)
             metrics.set_mp_info(self.mp, self._mp_cfg.backend)
         else:
-            # undo head-major qkv storage (sequence-parallel
-            # HybridTrainStep) once at construction — single-chip decode
-            # splits qkv logically
-            params = _logical_qkv(params, config)
+            params = self._model.prepare(params, config)
             if self._quant is not None and self._quant.quantizes_weights:
                 params = _squant.quantize_params(params, config,
                                                  self._quant)
@@ -475,6 +475,7 @@ class Engine:
         if self.kv_layout not in ("paged", "pooled"):
             raise ValueError(f"kv_layout must be 'paged' or 'pooled', got "
                              f"{self.kv_layout!r}")
+        self._refuse("pooled", self.kv_layout == "pooled")
         if self.mp > 1 and self.kv_layout != "paged":
             raise ValueError(
                 "tensor-parallel serving shards the PAGED pool (the "
@@ -527,6 +528,7 @@ class Engine:
             if adapter_slots is None else adapter_slots,
             flags.get("FLAGS_serving_adapter_rank", 8)
             if adapter_rank is None else adapter_rank)
+        self._refuse("adapters", self._adapter_spec is not None)
         self.adapters = None            # AdapterRegistry once constructed
         self._tenant_adapters = {}
         if self._adapter_spec is not None and self.kv_layout != "paged":
@@ -587,6 +589,7 @@ class Engine:
         # parity contract every serving PR carries).
         self._spec = _squant.resolve_draft(speculate_k, draft_source,
                                            draft_layers, flags)
+        self._refuse("spec", self._spec is not None)
         self.speculate_k = 0 if self._spec is None else self._spec.k
         self._draft_params = None
         self._spec_draft = None
@@ -629,12 +632,12 @@ class Engine:
 
         from ..framework.compilation_cache import ensure_persistent_cache
         ensure_persistent_cache()
-        cfg = _cfg_key(config)
+        cfg = self._model.key(config)
         donate_ok = jax.default_backend() != "cpu"  # cpu: donation unimplemented
         B = self.num_slots
-        nh = config.num_heads
-        d = config.hidden_size // nh
-        compute = jnp.dtype(config.compute_dtype or "float32")
+        self._geo = geo = self._model.geometry(config)
+        n_pools = len(geo.names)
+        compute = jnp.dtype(geo.dtype)
         self._kv_quant = False
 
         if self.kv_layout == "pooled":
@@ -642,7 +645,7 @@ class Engine:
                                           (1, 2) if donate_ok else ())
             self._decode = _make_decode(cfg, self.top_k,
                                         (1, 2) if donate_ok else ())
-            shape = (config.num_layers, B, self.max_seq_len, nh, d)
+            shape = (geo.layers, B, self.max_seq_len) + geo.row
         else:
             self.page_size = int(page_size or
                                  flags.get("FLAGS_serving_page_size", 16))
@@ -678,9 +681,8 @@ class Engine:
                 prefix_cache=prefix_cache, **pool_kw)
             self._kv_quant = kv_dtype != "bf16"
             use_kernel = bool(flags.get("FLAGS_serving_paged_kernel", True)
-                              ) and paged_kernel_supported(
-                                  nh // self.mp, d, self.page_size,
-                                  why="serving engine")
+                              ) and self._model.kernel_ok(
+                                  config, self.mp, self.page_size)
             quant_key = None if self._quant is None else self._quant.key()
             qkernel = (self._quant is not None
                        and self._quant.quantizes_weights
@@ -700,10 +702,10 @@ class Engine:
             else:
                 self._paged_step = _make_paged_step(
                     cfg, self.top_k, self.page_size, use_kernel,
-                    (1, 2) if donate_ok else (), anomaly=self._anomaly,
-                    quant=quant_key, qkernel=qkernel,
-                    adapters=adapter_key)
-            self._page_copy = _make_page_copy((0, 1) if donate_ok else ())
+                    tuple(range(1, 1 + n_pools)) if donate_ok else (),
+                    anomaly=self._anomaly, quant=quant_key, qkernel=qkernel,
+                    adapters=adapter_key, model=self._model)
+            self._page_copy = _make_page_copy((0,) if donate_ok else ())
             if self._spec is not None:
                 # one draft + one verify builder, memoized per config like
                 # every other serving executable: a second spec engine
@@ -715,14 +717,15 @@ class Engine:
                 self._spec_draft = _make_spec_draft(
                     cfg, self.page_size, self._spec.k, quant=quant_key)
                 self._build_draft_params()
-            # head_dim padded to whole lanes on the device; snapshots and
-            # page payloads keep the model's d (_logical / pad_lanes)
-            shape = (config.num_layers, self.pool.num_pages, self.page_size,
-                     nh, pool_head_dim(d))
+            # a row's last axis padded to whole lanes on the device;
+            # snapshots and page payloads keep the model's own width
+            # (_logical / pad_lanes)
+            shape = geo.pool_shape(self.pool.num_pages, self.page_size)
             if self._kv_quant:
                 compute = _squant.STORE_DTYPES[kv_dtype]
-        self._kc = jnp.zeros(shape, compute)
-        self._vc = jnp.zeros(shape, compute)
+        # the pool arrays a layer keeps, in the geometry's order (GPT: K
+        # and V, also reachable as _kc / _vc)
+        self._pools = tuple(jnp.zeros(shape, compute) for _ in geo.names)
         if self._quant is not None:
             metrics.set_quant_info(
                 self._quant.weight_dtype, self._quant.kv_dtype,
@@ -738,8 +741,8 @@ class Engine:
             from jax.sharding import NamedSharding
             from .mp_forward import KV_SPEC
             self._kv_sharding = NamedSharding(self._mesh, KV_SPEC)
-            self._kc = jax.device_put(self._kc, self._kv_sharding)
-            self._vc = jax.device_put(self._vc, self._kv_sharding)
+            self._pools = tuple(jax.device_put(a, self._kv_sharding)
+                                for a in self._pools)
 
         # host-authoritative per-slot state (numpy; re-uploaded every step —
         # tiny arrays, and exactly why joins/evicts can never retrace)
@@ -801,6 +804,33 @@ class Engine:
         self._snapshot_every = 0
         self._drained = []                # requests the last drain() handed back
 
+    def _refuse(self, option, asked):
+        """What the served model does not support yet raises here, at
+        construction, in one sentence that names the option."""
+        if asked and option in self._model.unsupported:
+            raise ValueError(
+                f"serving.Engine does not serve the {self._model.name} "
+                f"model with {option!r} yet (unsupported: "
+                f"{', '.join(sorted(self._model.unsupported))})")
+
+    # GPT's two pool arrays by name, for the paths only GPT takes (pooled
+    # layout, speculative verify, KV transfer, the chaos hooks) and tests
+    @property
+    def _kc(self):
+        return self._pools[0]
+
+    @_kc.setter
+    def _kc(self, a):
+        self._pools = (a,) + self._pools[1:]
+
+    @property
+    def _vc(self):
+        return self._pools[1]
+
+    @_vc.setter
+    def _vc(self, a):
+        self._pools = self._pools[:1] + (a,) + self._pools[2:]
+
     # -- submission ----------------------------------------------------------
     def _check_stopped(self):
         if self._stopped:
@@ -855,6 +885,7 @@ class Engine:
         if role not in ("both", "prefill", "decode"):
             raise ValueError(
                 f"role must be 'both', 'prefill' or 'decode', got {role!r}")
+        self._refuse("kv_transfer", role != "both")
         if role != "both" and self.kv_layout != "paged":
             raise ValueError(
                 "disaggregated roles ride the paged layout (KV pages are "
@@ -1266,10 +1297,25 @@ class Engine:
 
     def _logical(self, pool):
         """A host copy of a pool array (or of pages of it) at the model's
-        head_dim, contiguous: what snapshots, page payloads and the chaos
-        hooks see. The device holds ``pool_head_dim`` lanes."""
-        d = self.config.hidden_size // self.config.num_heads
-        return np.ascontiguousarray(np.asarray(pool)[..., :d])
+        own row width, contiguous: what snapshots, page payloads and the
+        chaos hooks see. The device holds whole lanes."""
+        return self._geo.logical(pool)
+
+    def _take(self, out):
+        """A fused step's outputs: the pools go back into the engine; the
+        rest come back as (next tokens, keys, per-slot verdict or None, the
+        model's statistics or None), still on the device."""
+        n = len(self._pools)
+        self._pools = tuple(out[:n])
+        nxt, keys, *rest = out[n:]
+        ok = rest.pop(0) if self._anomaly else None
+        return nxt, keys, ok, (rest[0] if rest else None)
+
+    def _record_stats(self, stats, kind):
+        """Hands a dispatch's statistics (``kind`` chunk | decode) to the
+        model, once the dispatch's other outputs are on the host."""
+        if stats is not None:
+            self._model.record(np.asarray(stats), kind, self.config)
 
     def _kv_scale_args(self):
         """Per-page dequant scale operands of a quantized pool: host-
@@ -1300,8 +1346,8 @@ class Engine:
         page before the dispatch that writes the range."""
         copied = 0
         for src, dst in self.pool.make_writable(b, start, end):
-            self._kc, self._vc = self._page_copy(
-                self._kc, self._vc, jnp.int32(src), jnp.int32(dst))
+            self._pools = self._page_copy(self._pools, jnp.int32(src),
+                                          jnp.int32(dst))
             metrics.bump("cow_copies")
             copied += 1
         if copied:
@@ -1359,7 +1405,7 @@ class Engine:
             self._cow(b, int(self._pos[b]), int(self._pos[b]) + 1)
         self._decode_dispatches += 1     # per-role gate: prefill workers
         out = self._paged_step(          # must never reach this dispatch
-            self.params, self._kc, self._vc,
+            self.params, *self._pools,
             jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos),
             jnp.asarray(valid), jnp.asarray(emit),
             jnp.asarray(self.pool.table), jnp.asarray(self._do_sample),
@@ -1367,13 +1413,11 @@ class Engine:
             jnp.asarray(self._keys), *self._kv_scale_args(),
             *self._adapter_args())
         clk.wait()
-        if self._anomaly:
-            self._kc, self._vc, nxt, keys, ok = out
+        nxt, keys, ok, stats = self._take(out)
+        if ok is not None:
             ok = np.asarray(ok)
-        else:
-            self._kc, self._vc, nxt, keys = out
-            ok = None
         nxt = np.asarray(nxt)
+        self._record_stats(stats, "decode")
         now = clk.emit()
         self._keys = np.array(keys)
         self._record_mp_comm(B, 1, t0, now,
@@ -1391,6 +1435,29 @@ class Engine:
                                pos=int(self._pos[b]))
             self._pos[b] += 1
             self._emit_token(req, b, int(nxt[b]), first=False)
+
+    def warm_up(self):
+        """Compile the steady-state executables of the paged layout now:
+        every rung of the chunk ladder, the [B, 1] decode step and the page
+        copy, each dispatched once with no live lane (valid=0 sends every
+        write to the trash page, emit=False parks the keys), so that no
+        request meets a compile. Changes no state; returns the engine."""
+        if self.kv_layout != "paged" or self._spec is not None:
+            raise ValueError("warm_up covers the paged fused step (no "
+                             "pooled layout, no speculative dispatch)")
+        for b, t in [(1, c) for c in self._chunk_ladder] \
+                + [(self.num_slots, 1)]:
+            out = self._paged_step(
+                self.params, *self._pools, jnp.zeros((b, t), jnp.int32),
+                jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32),
+                jnp.zeros(b, bool), jnp.zeros_like(self.pool.table[:b]),
+                jnp.zeros(b, bool), jnp.ones(b, jnp.float32),
+                jnp.ones(b, jnp.float32), jnp.zeros((b, 2), jnp.uint32),
+                *self._kv_scale_args(), *self._adapter_args(slice(0, b)))
+            self._take(out)
+        self._pools = self._page_copy(self._pools, jnp.int32(0), jnp.int32(0))
+        jax.block_until_ready(self._pools)
+        return self
 
     def _build_draft_params(self):
         """(Re)derive the draft params from the SERVED weights — at
@@ -1540,7 +1607,7 @@ class Engine:
         ids[0, :v] = req.prompt[off:off + v]
         self._cow(b, off, off + v)
         out = self._paged_step(
-            self.params, self._kc, self._vc, jnp.asarray(ids),
+            self.params, *self._pools, jnp.asarray(ids),
             jnp.asarray([off], np.int32), jnp.asarray([v], np.int32),
             jnp.asarray([emit]), jnp.asarray(self.pool.table[b:b + 1]),
             jnp.asarray(self._do_sample[b:b + 1]),
@@ -1549,17 +1616,14 @@ class Engine:
             jnp.asarray(self._keys[b:b + 1]), *self._kv_scale_args(),
             *self._adapter_args(slice(b, b + 1)))
         clk.wait()
-        if self._anomaly:
-            # the verdict is only consulted on the emitting (final) chunk
-            # — fetch it there, not per chunk (no extra host sync on the
-            # interleaved bulk-prefill path)
-            self._kc, self._vc, nxt, keys, ok_dev = out
-        else:
-            self._kc, self._vc, nxt, keys = out
-            ok_dev = None
+        # the anomaly verdict is only consulted on the emitting (final)
+        # chunk — fetched there, not per chunk (no extra host sync on the
+        # interleaved bulk-prefill path)
+        nxt, keys, ok_dev, stats = self._take(out)
         # the chunk step ends when its outputs are on the host: the fetch
         # of the slot's key row is where the host waits for the device
         keys = np.asarray(keys)
+        self._record_stats(stats, "chunk")
         t1 = clk.emit()
         self._keys[b] = keys[0]
         self._record_mp_comm(1, C, t0, t1, [req])
@@ -1647,6 +1711,7 @@ class Engine:
         pages install between decode boundaries and the request seats in
         a free slot once all pages landed. Re-offering a transfer already
         in flight (a supervisor retry) restarts its install cleanly."""
+        self._refuse("kv_transfer", True)
         if self.kv_layout != "paged":
             raise ValueError("KV transfers ride the paged layout")
         if self.role == "prefill":
@@ -2274,7 +2339,7 @@ class Engine:
             new = shard_serving_params(params, self.config, self._mesh,
                                        self._mp_cfg, quant_spec=swap_spec)
         else:
-            params = _logical_qkv(params, self.config)
+            params = self._model.prepare(params, self.config)
             if swap_spec is not None:
                 params = _squant.quantize_params(params, self.config,
                                                  swap_spec)
@@ -2443,7 +2508,7 @@ class Engine:
         meta = {"kv_layout": self.kv_layout, "num_slots": self.num_slots,
                 "max_seq_len": self.max_seq_len, "top_k": self.top_k,
                 "params_version": int(self.params_version),
-                "cfg": _cfg_key(self.config),
+                "cfg": self._model.key(self.config),
                 # dtype config: part of the restore contract — quantized
                 # KV bytes do not reinterpret across dtypes, so a
                 # mismatched restore is REFUSED (typed) up front
@@ -2490,17 +2555,16 @@ class Engine:
         unpopped results, and the serving metrics ledger. Safe for
         ``CheckpointManager``/``framework.io`` round trips; pair with
         ``load_state_dict`` for bitwise mid-decode resume."""
-        kc_np = self._logical(jax.device_get(self._kc))
-        vc_np = self._logical(jax.device_get(self._vc))
-        if kc_np.dtype not in (np.int8, np.float32, np.float64, np.float16):
+        pools_np = [self._logical(jax.device_get(a)) for a in self._pools]
+        if pools_np[0].dtype not in (np.int8, np.float32, np.float64,
+                                     np.float16):
             # fp8/bf16 pools: numpy IO paths don't all speak ml_dtypes —
             # snapshot the raw bytes; meta's kv dtype restores the view
-            kc_np = kc_np.view(np.uint8)
-            vc_np = vc_np.view(np.uint8)
+            pools_np = [a.view(np.uint8) for a in pools_np]
         state = {
             "meta": self._snapshot_meta(),
-            "kc": kc_np,
-            "vc": vc_np,
+            # each pool array under its geometry's name (GPT: kc, vc)
+            **dict(zip(self._geo.names, pools_np)),
             "pos": self._pos.copy(), "tok": self._tok.copy(),
             "keys": self._keys.copy(), "temp": self._temp.copy(),
             "top_p": self._top_p.copy(),
@@ -2591,23 +2655,23 @@ class Engine:
             raise ValueError(
                 f"engine snapshot meta {meta} does not match this engine "
                 f"{mine}; build the restoring Engine with the same config")
-        compute = self._kc.dtype
-        kc_np = np.asarray(state["kc"])
-        vc_np = np.asarray(state["vc"])
-        if kc_np.dtype == np.uint8 and compute != jnp.uint8:
-            # raw-byte snapshot of an fp8 pool: restore the dtype view
-            kc_np = kc_np.view(compute)
-            vc_np = vc_np.view(compute)
-        self._kc = pad_lanes(jnp.asarray(kc_np, compute), self._kc)
-        self._vc = pad_lanes(jnp.asarray(vc_np, compute), self._vc)
+        compute = self._pools[0].dtype
+        restored = []
+        for name, like in zip(self._geo.names, self._pools):
+            a_np = np.asarray(state[name])
+            if a_np.dtype == np.uint8 and compute != jnp.uint8:
+                # raw-byte snapshot of an fp8 pool: restore the dtype view
+                a_np = a_np.view(compute)
+            restored.append(pad_lanes(jnp.asarray(a_np, compute), like))
+        self._pools = tuple(restored)
         if self._kv_sharding is not None:
             # snapshots hold the GLOBAL pool (mp-independent geometry, and
             # the gather-only schedule makes its contents bitwise equal at
             # every mp) — lay the head axis back out across this engine's
             # chips. A snapshot therefore restores across mp degrees, incl.
             # single-chip <-> sharded.
-            self._kc = jax.device_put(self._kc, self._kv_sharding)
-            self._vc = jax.device_put(self._vc, self._kv_sharding)
+            self._pools = tuple(jax.device_put(a, self._kv_sharding)
+                                for a in self._pools)
         self._pos = np.asarray(state["pos"], np.int32).copy()
         self._tok = np.asarray(state["tok"], np.int32).copy()
         self._keys = np.asarray(state["keys"], np.uint32).copy()
@@ -2846,15 +2910,14 @@ class Engine:
         plus the amortized per-page scale bytes on a quantized pool — the
         bytes-per-token-by-dtype gauge of the capacity story (int8 ~4x
         fewer than fp32, fp8 likewise)."""
-        cfg = self.config
-        nh_l = cfg.num_heads // self.mp
-        d = self._kc.shape[-1]        # the lanes the device holds
-        item = int(self._kc.dtype.itemsize)
-        per_tok = 2 * cfg.num_layers * nh_l * d * item
+        # a token's row in every pool array, as many lanes as the device
+        # holds, over the chips that share the head axis
+        per_tok = sum(a.shape[0] * int(np.prod(a.shape[3:]))
+                      * int(a.dtype.itemsize) for a in self._pools) // self.mp
         if self._kv_quant:
             # two fp32 scales per (layer, page), shared by page_size
             # tokens — rounded UP so the gauge never underreports to 0
-            per_tok += -(-2 * cfg.num_layers * 4 // self.page_size)
+            per_tok += -(-2 * self._geo.layers * 4 // self.page_size)
         return per_tok
 
     def kv_shard_bytes(self):

@@ -130,6 +130,16 @@ def _zero():
         # or replayed request's first token counts once, as in observe_ttft)
         "admit_queue_wait_s": 0.0, "admit_queue_waits": 0,
         "prefill_span_s": 0.0, "first_tokens": 0,
+        # expert layers (a model whose paged step returns routing
+        # statistics, models/xing4.py), by dispatch kind: expert layers
+        # dispatched, (token, expert) assignments of real tokens to held
+        # experts, and held experts that got at least one token, each
+        # summed over expert layers and dispatches; moe_load_max is the
+        # most tokens one expert of one layer got in one dispatch
+        "moe_layer_dispatches_decode": 0, "moe_layer_dispatches_chunk": 0,
+        "moe_assignments_decode": 0, "moe_assignments_chunk": 0,
+        "moe_touched_decode": 0, "moe_touched_chunk": 0,
+        "moe_load_max": 0,
         # occupancy: sum of active slots over decode steps / (steps * slots)
         "active_slot_steps": 0, "slot_steps": 0,
         # queue depth observed at step boundaries
@@ -224,6 +234,16 @@ def observe_logit_drift(drift):
     with _lock:
         _C["quant_logit_drift_max"] = max(_C["quant_logit_drift_max"],
                                           float(drift))
+
+
+def observe_moe(kind, layers, assignments, touched, load_max):
+    """One dispatch's routing statistics (``kind`` chunk | decode) over its
+    ``layers`` expert layers."""
+    with _lock:
+        _C[f"moe_layer_dispatches_{kind}"] += int(layers)
+        _C[f"moe_assignments_{kind}"] += int(assignments)
+        _C[f"moe_touched_{kind}"] += int(touched)
+        _C["moe_load_max"] = max(_C["moe_load_max"], int(load_max))
 
 
 def add_time(name, dt):

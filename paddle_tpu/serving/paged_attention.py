@@ -383,6 +383,27 @@ def paged_attention_read(q, kc, vc, l, table, pos, page_size, use_kernel,
                       kv_v).astype(out_dtype)
 
 
+# ---------------------------------------------------------------------------
+# a latent cache: one row a token a layer, no head axis (models/xing4.py)
+
+
+def latent_scatter(pool, l, rows, table, pos, valid, page_size):
+    """``paged_kv_scatter`` for a pool of one array and no head axis
+    ``[L, P, page_size, lanes]``: the window's rows [B, T, row] go to layer
+    ``l`` (traced scalar) at (phys, off) through the table, lanes past
+    valid[b] to trash page 0; one in-place scatter into the carried pool."""
+    phys, off = _write_slots(table, pos, jnp.arange(pos.shape[1])[None, :]
+                             < valid[:, None], page_size)
+    return pool.at[l, phys, off].set(pad_lanes(rows.astype(pool.dtype), pool))
+
+
+def latent_window(pool, l, table, row):
+    """Layer ``l``'s rows of every slot in virtual order [B, S, row],
+    gathered through the table; the layer is addressed by the gather."""
+    B, MP = table.shape
+    return pool[l, table][..., :row].reshape(B, MP * pool.shape[2], row)
+
+
 def layer_ids(params):
     """The layer scan's index operand, [L] int32 over the tree's blocks (a
     layer-truncated draft tree walks the pool's leading layers)."""
