@@ -186,12 +186,18 @@ def _forward_decode_slots(params, config, tok, kc, vc, pos):
     return _final_logits(params, config, x[:, 0]), kc, vc
 
 
-def _mask_logits(logits, temperature, top_k, top_p):
+def _mask_logits(logits, temperature, top_k, top_p, rows=None):
     """Sampling logits transform: temperature scale, static top-k cut,
     nucleus (top-p) cut. temperature/top_p are TRACED operands (scalar or
     per-row [B] — sweeping them never recompiles); top_k stays static (it
     changes the top_k kernel's shape). top_p=None skips the nucleus branch
-    structurally (the old static `top_p in (None, 1.0)` contract)."""
+    structurally (the old static `top_p in (None, 1.0)` contract).
+
+    ``rows`` (traced bool, scalar or per-row [B]: the rows whose result
+    is read, _next_token's sample_mask) gates the nucleus cut at run
+    time: its sorts and gathers run only when some such row asks for
+    top_p < 1.0. A traced 1.0 keeps every token, so skipping the cut
+    then is bitwise the same."""
     t = jnp.maximum(jnp.asarray(temperature, jnp.float32), 1e-6)
     if getattr(t, "ndim", 0) == logits.ndim - 1:
         t = t[..., None]
@@ -199,25 +205,55 @@ def _mask_logits(logits, temperature, top_k, top_p):
     if top_k is not None and top_k > 0:
         kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
         logits = jnp.where(logits < kth, -jnp.inf, logits)
-    if top_p is not None:
-        p = jnp.asarray(top_p, jnp.float32)
-        if getattr(p, "ndim", 0) == logits.ndim - 1:
-            p = p[..., None]
+    if top_p is None:
+        return logits
+    p = jnp.asarray(top_p, jnp.float32)
+
+    def nucleus(logits):
+        pk = p[..., None] if p.ndim == logits.ndim - 1 else p
         sort_idx = jnp.argsort(-logits, axis=-1)
         sorted_logits = jnp.take_along_axis(logits, sort_idx, axis=-1)
         probs = jax.nn.softmax(sorted_logits, axis=-1)
         cum = jnp.cumsum(probs, axis=-1)
-        keep_sorted = (cum - probs) < p          # always keeps the top token
+        keep_sorted = (cum - probs) < pk         # always keeps the top token
         # p >= 1.0 must keep EVERY token (a traced 1.0 stands in for
         # "no nucleus cut" — the serving engine's per-slot top_p=None):
         # float32 cumsum saturates at 1.0 before the tail, so without this
         # the comparison would mask tiny-probability tail tokens and break
         # bitwise parity with the structural top_p=None skip.
-        keep_sorted = keep_sorted | (p >= 1.0)
+        keep_sorted = keep_sorted | (pk >= 1.0)
         inv = jnp.argsort(sort_idx, axis=-1)
         keep = jnp.take_along_axis(keep_sorted, inv, axis=-1)
-        logits = jnp.where(keep, logits, -jnp.inf)
-    return logits
+        return jnp.where(keep, logits, -jnp.inf)
+
+    if rows is None:
+        return nucleus(logits)
+    return jax.lax.cond(jnp.any(rows & (p < 1.0)), nucleus,
+                        lambda logits: logits, logits)
+
+
+def _next_token(logits, subs, sample_mask, temperature, top_k, top_p):
+    """The sampling tail of every serving executable: the next token of
+    each row of logits [B, V] as [B] int32 — a categorical draw under
+    ``subs`` (one key a row, or one key for all of logits) where
+    ``sample_mask`` (traced bool, per-row [B] or scalar) is set, the
+    argmax elsewhere. The temperature scale, the cuts and the Gumbel draw
+    over [B, V] run only in a dispatch where some row samples, and the
+    nucleus cut only where such a row asks for one (_mask_logits' rows):
+    a greedy dispatch pays for the argmax alone. Both branches live in
+    the one executable; tokens are bitwise those of
+    ``where(sample_mask, categorical(subs, _mask_logits(...)), argmax)``."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    draw = jax.random.categorical if subs.ndim == 0 else \
+        jax.vmap(jax.random.categorical)
+
+    def sampled():
+        masked = _mask_logits(logits, temperature, top_k, top_p,
+                              rows=sample_mask)
+        return jnp.where(sample_mask, draw(subs, masked).astype(jnp.int32),
+                         greedy)
+
+    return jax.lax.cond(jnp.any(sample_mask), sampled, lambda: greedy)
 
 
 def _select_token(logits, key, do_sample, temperature, top_k, top_p):
@@ -262,11 +298,8 @@ def _verify_accept(logits, ids_next, nprop, emit, do_sample, temperature,
         lg, nxt_prop, i = xs
         pair = jax.vmap(jax.random.split)(
             jax.random.wrap_key_data(key_data))
-        greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-        sampled = jax.vmap(jax.random.categorical)(
-            pair[:, 1],
-            _mask_logits(lg, temperature, top_k, top_p)).astype(jnp.int32)
-        t = jnp.where(do_sample & going, sampled, greedy)
+        t = _next_token(lg, pair[:, 1], do_sample & going, temperature,
+                        top_k, top_p)
         new_kd = jnp.where(going[:, None],
                            jax.random.key_data(pair[:, 0]), key_data)
         n_emit = n_emit + going

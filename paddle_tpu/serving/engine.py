@@ -65,7 +65,7 @@ from ..observability import tracing as obs_tracing
 from ..utils import fault_injection as _fi
 from ..models.generation import (
     _cfg_key, _cfg_view, _collect_params, _forward_cached,
-    _forward_decode_slots, _mask_logits, _verify_accept,
+    _forward_decode_slots, _next_token, _verify_accept,
 )
 from . import metrics
 from . import quant as _squant
@@ -129,11 +129,8 @@ def _make_prefill(cfg, top_k, donate):
         kc = jax.lax.dynamic_update_slice_in_dim(kc, kcs, slot, axis=1)
         vc = jax.lax.dynamic_update_slice_in_dim(vc, vcs, slot, axis=1)
         key, sub = jax.random.split(jax.random.wrap_key_data(key_data))
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        sampled = jax.random.categorical(
-            sub, _mask_logits(logits, temperature, top_k, top_p)
-        ).astype(jnp.int32)
-        tok = jnp.where(do_sample, sampled, greedy)[0]
+        tok = _next_token(logits, sub, do_sample, temperature, top_k,
+                          top_p)[0]
         return kc, vc, tok, jax.random.key_data(key)
 
     return jax.jit(fn, donate_argnums=donate)
@@ -151,12 +148,8 @@ def _make_decode(cfg, top_k, donate):
                                                pos)
         keys = jax.random.wrap_key_data(key_data)           # [B] keys
         pair = jax.vmap(jax.random.split)(keys)             # [B, 2] keys
-        subs = pair[:, 1]
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        sampled = jax.vmap(jax.random.categorical)(
-            subs, _mask_logits(logits, temperature, top_k, top_p)
-        ).astype(jnp.int32)
-        nxt = jnp.where(do_sample & active, sampled, greedy)
+        nxt = _next_token(logits, pair[:, 1], do_sample & active,
+                          temperature, top_k, top_p)
         return kc, vc, nxt, jax.random.key_data(pair[:, 0])
 
     return jax.jit(fn, donate_argnums=donate)
@@ -234,12 +227,8 @@ def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
         tail = () if stats is None else (stats,)
         keys = jax.random.wrap_key_data(key_data)           # [B] keys
         pair = jax.vmap(jax.random.split)(keys)             # [B, 2] keys
-        subs = pair[:, 1]
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        sampled = jax.vmap(jax.random.categorical)(
-            subs, _mask_logits(logits, temperature, top_k, top_p)
-        ).astype(jnp.int32)
-        nxt = jnp.where(do_sample & emit, sampled, greedy)
+        nxt = _next_token(logits, pair[:, 1], do_sample & emit,
+                          temperature, top_k, top_p)
         new_keys = jnp.where(emit[:, None], jax.random.key_data(pair[:, 0]),
                              key_data)
         if anomaly:
@@ -1422,7 +1411,7 @@ class Engine:
         self._keys = np.array(keys)
         self._record_mp_comm(B, 1, t0, now,
                              [self._slots[b] for b in decoding])
-        metrics.bump("paged_steps")
+        self._count_paged_step(self._do_sample & emit)
         for b in decoding:
             req = self._slots[b]
             if ok is not None and not ok[b]:
@@ -1435,6 +1424,17 @@ class Engine:
                                pos=int(self._pos[b]))
             self._pos[b] += 1
             self._emit_token(req, b, int(nxt[b]), first=False)
+
+    @staticmethod
+    def _count_paged_step(sample_mask):
+        """Ledger of one paged dispatch (decode, chunk or verify), and of
+        whether its sampling tail ran: the executable draws only when a
+        row that emits also samples (generation._next_token's
+        sample_mask), and the host holds the operands it uploaded, so it
+        counts without a sync."""
+        metrics.bump("paged_steps")
+        if np.any(sample_mask):
+            metrics.bump("sampled_steps")
 
     def warm_up(self):
         """Compile the steady-state executables of the paged layout now:
@@ -1546,7 +1546,7 @@ class Engine:
         n_emit = np.asarray(n_emit)
         now = clk.emit()
         self._keys = np.array(keys)
-        metrics.bump("paged_steps")
+        self._count_paged_step(self._do_sample & emit)
         metrics.bump("verify_dispatches")
         for b in decoding:
             req = self._slots[b]
@@ -1627,7 +1627,7 @@ class Engine:
         t1 = clk.emit()
         self._keys[b] = keys[0]
         self._record_mp_comm(1, C, t0, t1, [req])
-        metrics.bump("paged_steps")
+        self._count_paged_step(emit and self._do_sample[b])
         metrics.bump("chunk_steps")
         metrics.bump("prefill_chunks")
         if req.trace is not None:

@@ -32,6 +32,10 @@ def _zero():
         # after warmup at 1 (the [B,1] decode shape) + one [1,rung] trace
         # per chunk-ladder rung actually used; copy_traces at <= 1.
         "paged_steps": 0, "paged_traces": 0,
+        # paged dispatches in which some row both emitted and sampled: the
+        # only ones whose sampling tail (cuts, sorts, Gumbel draw over
+        # [slots, vocab]) ran; in the others the step took the argmax
+        "sampled_steps": 0,
         "chunk_steps": 0, "prefill_chunks": 0,
         "cow_copies": 0, "copy_traces": 0,
         # prefix cache

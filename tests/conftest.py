@@ -68,3 +68,19 @@ def devices8():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual cpu devices, got {len(devs)}"
     return devs
+
+
+def _primitives(jaxpr, inside=False):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, inside
+        under = inside or eqn.primitive.name == "cond"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub, under)
+
+
+@pytest.fixture(scope="session")
+def primitives():
+    """``primitives(jaxpr)``: (primitive name, whether it lies under a
+    ``cond`` branch) of every equation, nested jaxprs included — what the
+    gates on the serving steps' sampling tail walk."""
+    return _primitives

@@ -7,7 +7,10 @@ whole serving run at a toy width to ``correct`` on the CPU) run here too,
 imported and not copied, so that a cell or a family that no longer loads
 fails the tests the driver runs. So do the readers of the xing4 cell's own
 per-layer metrics on their hand-made context
-(``benchmark/tests/test_xing4_readers.py``)."""
+(``benchmark/tests/test_xing4_readers.py``), and those of the program's spans
+and counters (``benchmark/tests/test_program_span_readers.py``: the engine's
+phase clock; ``benchmark/tests/test_greedy_tail_share.py``: ``sampled_steps``),
+which break when the program renames what they read."""
 import importlib.util
 import os
 import sys
@@ -44,3 +47,5 @@ def _keep_the_workers_arrays(monkeypatch):
 globals().update(_cases("test_families"))
 globals().update(_cases("test_rehearsal_xing4"))
 globals().update(_cases("test_xing4_readers"))
+globals().update(_cases("test_program_span_readers"))
+globals().update(_cases("test_greedy_tail_share"))

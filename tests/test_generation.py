@@ -3,8 +3,11 @@ re-forward (ref capability: PaddleNLP-class model.generate)."""
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu.models.generation import (
+    _mask_logits, _next_token, _verify_accept)
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 
 
@@ -116,7 +119,149 @@ def test_beam_score_not_worse_than_greedy():
 def test_beam_rejects_sampling():
     model, cfg = _tiny_model()
     prompt = np.array([[1]], np.int64)
-    import pytest
     with pytest.raises(ValueError):
         model.generate(paddle.to_tensor(prompt), max_new_tokens=2,
                        num_beams=2, do_sample=True)
+
+
+# ---------------------------------------------------------------------------
+# the serving executables' sampling tail (generation._next_token): the draw
+# and the cuts run behind predicates on the traced operands, and every token
+# stays bitwise what the ungated expression gives
+
+_B, _V = 6, 211
+# rows whose result is the draw: do_sample & emit of the paged step
+_MASKS = {
+    "all-greedy": ([0] * 6, [1] * 6),
+    "all-sampled": ([1] * 6, [1] * 6),
+    "mixed-rows": ([1, 0, 0, 1, 0, 1], [1] * 6),
+    # rows 1 and 4 ask to sample and do not emit: they read the argmax
+    "emit-false-rows": ([1, 1, 0, 0, 1, 0], [1, 0, 1, 1, 0, 1]),
+    "sampling-rows-none-emit": ([1, 0, 1, 0, 0, 1], [0, 1, 0, 1, 1, 0]),
+}
+_TOP_P = {
+    "none": None,                                   # structural skip
+    "ones": [1.0] * 6,                              # traced stand-in for None
+    "cut": [0.8] * 6,
+    # rows 0 and 3 sample under "mixed-rows": one with a cut, one without
+    "mixed": [0.8, 0.5, 1.0, 1.0, 0.9, 0.3],
+    # the only rows with a cut (1, 2, 4) never sample under "mixed-rows":
+    # the nucleus branch is skipped while the draw runs
+    "cut-on-greedy-rows": [1.0, 0.6, 0.7, 1.0, 0.5, 1.0],
+}
+
+
+def _tail_operands(seed=0):
+    rng = np.random.default_rng(seed)
+    logits = jnp.asarray(rng.normal(0, 3, (_B, _V)), jnp.float32)
+    subs = jax.random.split(jax.random.key(seed + 11), _B)
+    temp = jnp.asarray(rng.uniform(0.4, 1.6, _B), jnp.float32)
+    return logits, subs, temp
+
+
+def _ungated(logits, subs, mask, temperature, top_k, top_p):
+    """The tail as every builder spelled it before the helper."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    draw = jax.random.categorical if subs.ndim == 0 else \
+        jax.vmap(jax.random.categorical)
+    sampled = draw(subs, _mask_logits(logits, temperature, top_k, top_p)
+                   ).astype(jnp.int32)
+    return jnp.where(mask, sampled, greedy)
+
+
+@pytest.mark.parametrize("top_k", [None, 7], ids=["no-top-k", "top-k-7"])
+@pytest.mark.parametrize("top_p", sorted(_TOP_P))
+@pytest.mark.parametrize("rows", sorted(_MASKS))
+def test_next_token_is_bitwise_the_ungated_tail(rows, top_p, top_k):
+    logits, subs, temp = _tail_operands()
+    do_sample, emit = (jnp.asarray(m, bool) for m in _MASKS[rows])
+    p = _TOP_P[top_p]
+    p = None if p is None else jnp.asarray(p, jnp.float32)
+    run = lambda fn: np.asarray(jax.jit(
+        lambda lg, s, ds, em, t, pp: fn(lg, s, ds & em, t, top_k, pp)
+    )(logits, subs, do_sample, emit, temp, p))
+    got, want = run(_next_token), run(_ungated)
+    np.testing.assert_array_equal(got, want)
+    assert got.dtype == np.int32 and got.shape == (_B,)
+    greedy = np.asarray(jnp.argmax(logits, -1))
+    quiet = ~(np.asarray(do_sample) & np.asarray(emit))
+    np.testing.assert_array_equal(got[quiet], greedy[quiet])
+
+
+@pytest.mark.parametrize("do_sample", [False, True])
+@pytest.mark.parametrize("top_p", [1.0, 0.8])
+def test_next_token_one_key_for_the_batch(do_sample, top_p):
+    """The pooled prefill's call: one key, logits [1, V], scalar operands."""
+    logits, subs, temp = _tail_operands(3)
+    args = (logits[:1], subs[0], jnp.asarray(do_sample), temp[0])
+    run = lambda fn: np.asarray(jax.jit(
+        lambda lg, s, m, t, p: fn(lg, s, m, t, None, p))(
+            *args, jnp.float32(top_p)))
+    np.testing.assert_array_equal(run(_next_token), run(_ungated))
+
+
+def test_next_token_keeps_the_sorts_and_the_draw_in_branches(primitives):
+    """Structure of the helper itself: argmax outside, the draw under one
+    cond, the nucleus cut's sorts under a second one inside it; no nucleus
+    code at all with a structural top_p=None."""
+    logits, subs, temp = _tail_operands()
+    mask = jnp.zeros(_B, bool)
+    for top_p, sorts in ((jnp.ones(_B), True), (None, False)):
+        closed = jax.make_jaxpr(
+            lambda lg, s, m, t, p: _next_token(lg, s, m, t, None, p))(
+                logits, subs, mask, temp, top_p)
+        found = list(primitives(closed.jaxpr))
+        assert ("argmax", False) in found
+        assert ("random_bits", True) in found
+        assert ("random_bits", False) not in found
+        assert (("sort", True) in found) == sorts
+        assert ("sort", False) not in found
+        depth = [n for n, _ in found].count("cond")
+        assert depth == (2 if sorts else 1)
+
+
+@pytest.mark.parametrize("rows", ["all-greedy", "mixed-rows", "all-sampled"])
+def test_verify_accept_lanes_are_bitwise_the_ungated_tail(rows):
+    """The accept scan of speculative verify draws through the helper once a
+    lane: tokens, emitted counts and keys equal a scan over the ungated
+    expression, whatever the mix of greedy and sampled slots."""
+    T = 4
+    rng = np.random.default_rng(5)
+    logits = jnp.asarray(rng.normal(0, 3, (_B, T, _V)), jnp.float32)
+    do_sample = jnp.asarray(_MASKS[rows][0], bool)
+    emit = jnp.asarray([1, 1, 0, 1, 1, 1], bool)
+    temp = jnp.asarray(rng.uniform(0.5, 1.5, _B), jnp.float32)
+    top_p = jnp.asarray(_TOP_P["mixed"], jnp.float32)
+    kd = jax.random.key_data(jax.random.split(jax.random.key(9), _B))
+    nprop = jnp.asarray([3, 0, 2, 1, 3, 2], jnp.int32)
+
+    def reference(ids_next):
+        def step(carry, xs):
+            kd, going, n = carry
+            lg, prop, i = xs
+            pair = jax.vmap(jax.random.split)(jax.random.wrap_key_data(kd))
+            t = _ungated(lg, pair[:, 1], do_sample & going, temp, None,
+                         top_p)
+            kd = jnp.where(going[:, None], jax.random.key_data(pair[:, 0]),
+                           kd)
+            n = n + going
+            going = going & (i < nprop) & (t == prop)
+            return (kd, going, n), t
+        (kd_, _, n), toks = jax.lax.scan(
+            step, (kd, emit, jnp.zeros(_B, jnp.int32)),
+            (jnp.swapaxes(logits, 0, 1), ids_next.T, jnp.arange(T)))
+        return toks.T, n, kd_
+
+    # proposals that agree with the target for a while, so lanes past 0 run
+    first = jax.jit(reference)(jnp.zeros((_B, T), jnp.int32))[0]
+    ids_next = jnp.asarray(np.asarray(first)).at[:, 2].add(1) % _V
+    want = jax.jit(reference)(ids_next)
+    got = jax.jit(lambda x: _verify_accept(
+        logits, x, nprop, emit, do_sample, temp, top_p, kd, None))(ids_next)
+    assert int(np.asarray(want[1]).max()) > 1
+    for g, w, what in zip(got, want, ("tokens", "n_emit", "keys")):
+        g, w = np.asarray(g), np.asarray(w)
+        if what == "tokens":        # lanes past n_emit are garbage
+            live = np.arange(T)[None, :] < np.asarray(want[1])[:, None]
+            g, w = g[live], w[live]
+        np.testing.assert_array_equal(g, w, err_msg=what)

@@ -517,6 +517,37 @@ def test_pool_pads_head_dim_to_lanes_and_nothing_sees_the_pad():
     assert (state["kc"] == kc[..., :d]).all()
 
 
+@pytest.mark.parametrize("speculate_k", [0, 2], ids=["plain", "verify"])
+def test_sampled_steps_counts_the_dispatches_whose_tail_drew(speculate_k):
+    """``sampled_steps`` is the host's count of paged dispatches in which a
+    row both emitted and sampled (the predicate of the step's cond): none
+    in a greedy run, and with one sampled request among greedy ones, one
+    for each dispatch that request emitted from."""
+    eng = _engine(speculate_k=speculate_k)
+    rng = np.random.default_rng(8)
+    profiler.reset_serving_counters()
+    eng.run(_mixed_requests(5, rng))
+    c = profiler.serving_counters()
+    assert c["paged_steps"] > 0 and c["sampled_steps"] == 0
+
+    profiler.reset_serving_counters()
+    reqs = _mixed_requests(4, rng)
+    prompt = rng.integers(0, CFG.vocab_size, 19)    # three chunks: the first
+    sampled = serving.Request(prompt, max_new_tokens=6, do_sample=True,
+                              temperature=0.9, top_p=0.9, seed=3)
+    emitted_at = []                                 # two emit nothing
+    sampled.on_token = lambda *_: emitted_at.append(
+        profiler.serving_counters()["paged_steps"])
+    res = eng.run(reqs[:2] + [sampled] + reqs[2:])[sampled.request_id]
+    c = profiler.serving_counters()
+    assert res.tokens == _ref_tokens(prompt, 6, do_sample=True,
+                                     temperature=0.9, top_p=0.9, seed=3)
+    assert c["sampled_steps"] == len(set(emitted_at))
+    assert 0 < c["sampled_steps"] <= 6 < c["paged_steps"]
+    if not speculate_k:          # one token a dispatch without speculation
+        assert c["sampled_steps"] == 6
+
+
 # ---------------------------------------------------------------------------
 # structural gate: the pool is the layer scan's carry, never its xs or ys
 
@@ -567,14 +598,11 @@ def _assert_pool_is_carried(closed, pool_shape):
         [tuple(pool_shape)] * 2
 
 
-@pytest.mark.parametrize("quant", [None, "int8"], ids=["fp", "int8"])
-@pytest.mark.parametrize("variant", ["plain", "kernel", "verify", "mp"])
-def test_pool_is_the_layer_scans_carry(variant, quant):
-    """On the jaxpr of the step the engine builds, at its steady-state
-    shapes [B, 1] and [1, chunk] ([B, k+1] for verify): what made every
-    dispatch copy the pool in and out of the scan, layer by layer, was the
-    pool riding as xs and coming back as stacked ys. Platform independent:
-    the kernel variant traces the step the engine builds on a TPU."""
+def _step_jaxprs(variant, quant=None):
+    """The engine, and the jaxprs of the step it builds at its steady-state
+    shapes [B, 1] and [1, chunk] ([B, k+1] for verify). Platform
+    independent: the kernel variant traces the step the engine builds on a
+    TPU."""
     from paddle_tpu.serving import engine as E
     kw = {"verify": {"speculate_k": 3}, "mp": {"mp": 2}}.get(variant, {})
     # num_slots=9 is unique in the suite: these traces warm no executable
@@ -604,8 +632,31 @@ def test_pool_is_the_layer_scans_carry(variant, quant):
         traced = [jax.make_jaxpr(step)(*operands(b, t), *sampling(b),
                                        *eng._kv_scale_args())
                   for b, t in ((B, 1), (1, C))]
+    return eng, traced
+
+
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("variant", ["plain", "kernel", "verify", "mp"])
+def test_pool_is_the_layer_scans_carry(variant, quant):
+    """What made every dispatch copy the pool in and out of the scan, layer
+    by layer, was the pool riding as xs and coming back as stacked ys."""
+    eng, traced = _step_jaxprs(variant, quant)
     for closed in traced:
         _assert_pool_is_carried(closed, eng._kc.shape)
+
+
+@pytest.mark.parametrize("variant", ["plain", "kernel", "verify", "mp"])
+def test_sampling_tail_lies_in_cond_branches(variant, primitives):
+    """A dispatch in which no emitting row samples pays for the argmax
+    alone: in the step's jaxpr every sort (the nucleus cut) and every draw
+    of random bits lies under a cond, the argmax outside; the per-slot key
+    split stays outside too, so a greedy token still advances its stream."""
+    _, traced = _step_jaxprs(variant)
+    for closed in traced:
+        found = set(primitives(closed.jaxpr))
+        assert {("sort", True), ("random_bits", True),
+                ("argmax", False), ("random_split", False)} <= found
+        assert not {("sort", False), ("random_bits", False)} & found
 
 
 def test_paged_kernel_routing_predicate():

@@ -4,7 +4,8 @@ KV pool. The CPU jaxpr gate (test_paged_serving) shows the pool is the layer
 scan's carry; this shows the chip's compiler then updates it in place — no
 copy, zero fill or relayout of a whole pool, the outputs in the donated
 buffers — and that the decode kernel keeps the name the benchmark
-finds it by.
+finds it by. And what it makes of the sampling tail: a conditional whose
+branches hold the sorts, so a greedy dispatch skips them.
 
 The topology is described inside a fixture (never at import: one process
 holds the TPU library, and every xdist worker imports this file)."""
@@ -42,6 +43,12 @@ def chip():
     cc.reset_cache()
 
 
+def _gpt(hidden, heads, vocab=1024):
+    return GPTConfig(vocab_size=vocab, hidden_size=hidden, num_layers=2,
+                     num_heads=heads, max_seq_len=MAX_SEQ, dropout=0.0,
+                     use_flash=False, compute_dtype="bfloat16", remat=False)
+
+
 def _compile_step(chip, cfg, num_pages, batch, window, use_kernel):
     """The engine's own builder, lowered on shapes placed on the described
     chip; the pool as the engine allocates it."""
@@ -72,11 +79,8 @@ def _compile_step(chip, cfg, num_pages, batch, window, use_kernel):
 ])
 def test_paged_step_updates_the_pool_in_place_on_the_chip(
         chip, name, hidden, heads, num_pages, batch, window, kernel):
-    cfg = GPTConfig(vocab_size=1024, hidden_size=hidden, num_layers=2,
-                    num_heads=heads, max_seq_len=MAX_SEQ, dropout=0.0,
-                    use_flash=False, compute_dtype="bfloat16", remat=False)
-    pool, compiled = _compile_step(chip, cfg, num_pages, batch, window,
-                                   kernel)
+    pool, compiled = _compile_step(chip, _gpt(hidden, heads), num_pages,
+                                   batch, window, kernel)
     text = compiled.as_text()
     dims = ",".join(str(n) for n in pool.shape)
     whole = re.findall(
@@ -94,3 +98,18 @@ def test_paged_step_updates_the_pool_in_place_on_the_chip(
         assert [c for c in calls if c.startswith("%paged_decode_attention")]
     else:
         assert not calls
+
+
+def test_decode_step_keeps_the_sampling_tail_in_a_conditional_on_the_chip(
+        chip):
+    """The [16, 1] step of the 1.3B cell at its own vocabulary: XLA:TPU
+    keeps generation._next_token's cond a real ``conditional`` (it does not
+    flatten it into a select), so a greedy dispatch runs neither sort of the
+    nucleus cut: both lie in branch computations, none in the entry."""
+    _, compiled = _compile_step(chip, _gpt(2048, 16, vocab=50304), 2049,
+                                SLOTS, 1, True)
+    text = compiled.as_text()
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    assert re.search(r" conditional\(", entry)
+    assert len(re.findall(r" sort\(", text)) == 2
+    assert not re.search(r" sort\(", entry)

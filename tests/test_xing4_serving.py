@@ -333,12 +333,23 @@ def test_geometry_warm_up_and_snapshot(weights):
     assert profiler.serving_counters()["paged_traces"] == warm
 
 
+def _step_jaxprs(eng):
+    """The jaxprs of the step the engine builds, at its two steady-state
+    shapes [B, 1] and [1, chunk]."""
+    B, MP = eng.num_slots, eng.pool.table.shape[1]
+    z = lambda *sh, dt=np.int32: jnp.zeros(sh, dt)
+    return [jax.make_jaxpr(eng._paged_step)(
+        eng.params, *eng._pools, z(b, t), z(b), z(b), z(b, dt=bool),
+        z(b, MP), z(b, dt=bool), jnp.ones(b, np.float32),
+        jnp.ones(b, np.float32), z(b, 2, dt=np.uint32))
+        for b, t in ((B, 1), (1, CHUNK))]
+
+
 def test_latent_pool_is_the_layer_scans_carry(weights):
     """As for GPT (test_paged_serving.py): on the jaxpr of the step the
     engine builds, no scan takes the pool as xs or returns it as ys."""
     eng = _engine(weights, num_slots=7)
     shape = eng._pools[0].shape
-    MP = eng.pool.table.shape[1]
 
     def scans(jaxpr):
         for eqn in jaxpr.eqns:
@@ -347,12 +358,7 @@ def test_latent_pool_is_the_layer_scans_carry(weights):
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from scans(sub)
 
-    for b, t in ((7, 1), (1, CHUNK)):
-        z = lambda *sh, dt=np.int32: jnp.zeros(sh, dt)
-        closed = jax.make_jaxpr(eng._paged_step)(
-            eng.params, *eng._pools, z(b, t), z(b), z(b), z(b, dt=bool),
-            z(b, MP), z(b, dt=bool), jnp.ones(b, np.float32),
-            jnp.ones(b, np.float32), z(b, 2, dt=np.uint32))
+    for closed in _step_jaxprs(eng):
         found = list(scans(closed.jaxpr))
         assert len(found) == 2                      # dense layers, expert layers
         for eqn in found:
@@ -362,6 +368,17 @@ def test_latent_pool_is_the_layer_scans_carry(weights):
             assert not [s for s in xs + ys if s[1:] == shape[1:]], (xs, ys)
             assert shape in [v.aval.shape for v in eqn.outvars[:nk]]
         assert closed.jaxpr.outvars[0].aval.shape == shape
+
+
+def test_sampling_tail_lies_in_cond_branches(weights, primitives):
+    """As for GPT: the tail is the engine's, so this model's step too keeps
+    every sort and every draw of random bits under a cond, and the argmax
+    and the per-slot key split outside (the router's top-k is no sort)."""
+    for closed in _step_jaxprs(_engine(weights, num_slots=7)):
+        found = set(primitives(closed.jaxpr))
+        assert {("sort", True), ("random_bits", True),
+                ("argmax", False), ("random_split", False)} <= found
+        assert not {("sort", False), ("random_bits", False)} & found
 
 
 def test_engine_step_holds_no_branch_on_a_models_name():
