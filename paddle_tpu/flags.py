@@ -59,32 +59,25 @@ _FLAGS = {
     # Consecutive bad steps tolerated under "rollback" before restoring.
     "FLAGS_anomaly_max_bad_steps": 3,
     # -- continuous-batching serving engine (serving/engine.py) -------------
-    # Decode-batch slot count B: the fixed batch dim of the pooled KV cache
-    # and the one-token decode executable. More slots = more requests decoded
-    # per iteration (throughput) at B x Smax x L x H KV memory.
+    # Decode-batch slot count B: the fixed batch dim of the one-token decode
+    # dispatch and of the slot->page table. More slots = more requests
+    # decoded per iteration (throughput); KV memory is FLAGS_serving_num_pages.
     "FLAGS_serving_slots": 8,
-    # KV pool sequence capacity Smax per slot; 0 = the model's max_seq_len.
+    # KV table sequence capacity Smax per slot; 0 = the model's max_seq_len.
     # Every request needs prompt_len + max_new_tokens <= Smax.
     "FLAGS_serving_max_seq_len": 0,
-    # Prefill length buckets: a prompt is right-padded to the smallest
-    # bucket that holds it, so steady state compiles ONE prefill executable
-    # per bucket instead of one per prompt length. Buckets above Smax clamp.
-    "FLAGS_serving_prefill_buckets": (64, 256, 1024),
     # Wait-queue bound: submit() past this raises QueueFullError — the
     # backpressure signal a frontend turns into HTTP 429 / retry-after.
     "FLAGS_serving_max_queue": 256,
-    # KV-cache layout: "paged" (block-paged pool [L,P,page,nh,d] + slot->page
-    # table, vLLM-style — admission is bounded by PAGES, not worst-case
-    # Smax slots, long prompts prefill in chunks interleaved with decode,
-    # and common prompt prefixes share physical pages copy-on-write) or
-    # "pooled" (the PR 5 contiguous [L,B,Smax,nh,d] layout, kept as the
-    # bitwise parity baseline).
-    "FLAGS_serving_kv_layout": "paged",
+    # The KV cache is a block-paged pool [L,P,page,nh,d] + slot->page table
+    # (vLLM-style): admission is bounded by PAGES, not worst-case Smax
+    # slots, long prompts prefill in chunks interleaved with decode, and
+    # common prompt prefixes share physical pages copy-on-write.
     # Tokens per KV page. Smaller pages = less per-request fragmentation
     # (waste < page_size tokens per sequence) but a bigger page table.
     "FLAGS_serving_page_size": 16,
     # Physical pages in the paged pool. 0 = auto: num_slots * ceil(Smax /
-    # page_size) + 1 (memory-equal to the pooled layout, +1 trash page).
+    # page_size) + 1 (every slot can reach Smax at once, +1 trash page).
     "FLAGS_serving_num_pages": 0,
     # Chunked-prefill budget: long prompts prefill in chunks interleaved
     # between decode iterations (Sarathi-style), so admitting a 1024-token

@@ -92,13 +92,9 @@ def _final_logits(params, config, xlast):
         params["head_w"].astype(jnp.float32)
 
 
-def _forward_cached(params, config, ids, kc, vc, start, last_index=None,
-                    adapters=None):
+def _forward_cached(params, config, ids, kc, vc, start, adapters=None):
     """ids [B,T] at absolute positions [start, start+T); returns logits of
-    the LAST position [B,V] and the updated cache. ``last_index`` (traced
-    scalar) selects which position's logits to return instead of T-1 — the
-    serving engine prefills prompts right-padded to a bucket length and
-    reads logits at the true last prompt token. ``adapters`` = (aid [B],
+    the LAST position [B,V] and the updated cache. ``adapters`` = (aid [B],
     slabs) — the solo-reference adapter path (slabs ride the layer scan,
     exactly like the paged engine's fused step)."""
     compute = jnp.dtype(config.compute_dtype or "float32")
@@ -123,67 +119,7 @@ def _forward_cached(params, config, ids, kc, vc, start, last_index=None,
     if adapters is not None:
         xs = xs + (slabs,)
     x, (kc, vc) = jax.lax.scan(layer_fn, x, xs)
-    if last_index is None:
-        xlast = x[:, -1]
-    else:
-        xlast = jax.lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)[:, 0]
-    return _final_logits(params, config, xlast), kc, vc
-
-
-def _layer_decode_slots(p, h, kc, vc, pos, nh, eps):
-    """One transformer block over h [B,1,H] where each batch row is an
-    independent serving SLOT at its own absolute position pos[b]. KV is
-    scattered row-wise at pos[b]; attention masks keys per slot
-    (key_pos <= pos[b]). Math mirrors _layer_cached exactly so a slot's
-    token stream is bitwise identical to single-request decode."""
-    B, T, H = h.shape
-    d = H // nh
-
-    def ln(x, g, b):
-        return ln_fp32(x, g, b, eps)
-
-    h1 = ln(h, p["ln1_g"], p["ln1_b"])
-    qkv = h1 @ p["qkv_w"].astype(h.dtype) + p["qkv_b"].astype(h.dtype)
-    q, k, v = jnp.split(qkv.reshape(B, T, 3, nh, d), 3, axis=2)
-    q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]
-    rows = jnp.arange(B)
-    kc = kc.at[rows, pos].set(k[:, 0].astype(kc.dtype))
-    vc = vc.at[rows, pos].set(v[:, 0].astype(vc.dtype))
-    Smax = kc.shape[1]
-    mask = jnp.arange(Smax)[None, :] <= pos[:, None]          # [B, Smax]
-    scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
-                        kc.astype(jnp.float32)) / (d ** 0.5)
-    scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhts,bshd->bthd", probs,
-                     vc.astype(jnp.float32)).astype(h.dtype)
-    attn = ctx.reshape(B, T, H) @ p["out_w"].astype(h.dtype) + \
-        p["out_b"].astype(h.dtype)
-    h = h + attn
-    h2 = ln(h, p["ln2_g"], p["ln2_b"])
-    up = h2 @ p["up_w"].astype(h.dtype) + p["up_b"].astype(h.dtype)
-    up = jax.nn.gelu(up, approximate=True)
-    return h + up @ p["down_w"].astype(h.dtype) + p["down_b"].astype(h.dtype), \
-        kc, vc
-
-
-def _forward_decode_slots(params, config, tok, kc, vc, pos):
-    """One decode step over B independent slots: tok [B] is each slot's
-    last token, fed at absolute position pos[b]. Returns logits [B,V] and
-    the updated cache [L,B,Smax,nh,d]."""
-    compute = jnp.dtype(config.compute_dtype or "float32")
-    x = params["wte"].astype(compute)[tok[:, None]] + \
-        jnp.take(params["wpe"].astype(compute), pos, axis=0)[:, None]
-    nh = config.num_heads
-
-    def layer_fn(h, xs):
-        p_l, kc_l, vc_l = xs
-        h, kc_l, vc_l = _layer_decode_slots(p_l, h, kc_l, vc_l, pos, nh,
-                                            config.layer_norm_epsilon)
-        return h, (kc_l, vc_l)
-
-    x, (kc, vc) = jax.lax.scan(layer_fn, x, (params["blocks"], kc, vc))
-    return _final_logits(params, config, x[:, 0]), kc, vc
+    return _final_logits(params, config, x[:, -1]), kc, vc
 
 
 def _mask_logits(logits, temperature, top_k, top_p, rows=None):

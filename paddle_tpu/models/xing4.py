@@ -592,7 +592,7 @@ class _Served(ServedModel):
     states the seam): the cache geometry and the paged forward. What is not
     built for it yet is refused by name at construction."""
     name = "xing4"
-    unsupported = frozenset({"spec", "quant", "adapters", "mp", "pooled",
+    unsupported = frozenset({"spec", "quant", "adapters", "mp",
                              "kv_transfer"})
 
     def key(self, config):

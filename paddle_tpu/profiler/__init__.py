@@ -413,17 +413,17 @@ def fault_summary():
 
 # -- serving counters ---------------------------------------------------------
 # The continuous-batching engine (serving/engine.py) ledgers every request,
-# prefill call/chunk, decode iteration and token. The trace counters
-# (prefill/decode for the pooled layout; paged_traces/copy_traces for the
-# paged layout's fused step and CoW page copy) are the no-recompile audit
-# trail: each jitted body counts only when actually traced, so after warmup
-# the counts freeze — joins, evicts, chunked admissions, CoW remaps and
-# sampling-param changes must not move them (and an Engine RESTORED from a
-# snapshot re-dispatches the warm executables, so a restore must not move
-# them either). TTFT/token-latency percentiles, tokens/s, slot occupancy
-# and queue depth are the serving SLO surface; the paged layout adds page
-# occupancy, prefix-cache hit rate / tokens reused, chunk-interleave
-# counters and per-prefill padded-token waste. The self-healing runtime
+# prefill chunk, decode iteration and token. The trace counters
+# (paged_traces/copy_traces for the fused step and the CoW page copy) are
+# the no-recompile audit trail: each jitted body counts only when actually
+# traced, so after warmup the counts freeze — joins, evicts, chunked
+# admissions, CoW remaps and sampling-param changes must not move them (and
+# an Engine RESTORED from a snapshot re-dispatches the warm executables, so
+# a restore must not move them either). TTFT/token-latency percentiles,
+# tokens/s, slot occupancy and queue depth are the serving SLO surface,
+# beside page occupancy, prefix-cache hit rate / tokens reused,
+# chunk-interleave counters and per-prefill padded-token waste. The
+# self-healing runtime
 # (engine snapshots + ServingSupervisor) adds the recovery ledger:
 # snapshots/snapshot_restores, preempt_drains, requeued/replayed,
 # respawns, stale_failovers, rolling_restarts — and "dropped", which must
@@ -447,8 +447,8 @@ def serving_counters():
     ``prefill_span_s`` / ``first_tokens`` admission to the first token.
     The same phases are ``jax.profiler.TraceAnnotation`` spans
     (``pt.serve.step`` around ``pt.serve.admit | feed | wait | emit``, a
-    dispatch's feed and wait with ``kind=chunk|decode|draft|verify|
-    pooled``; the trainers' dispatch is ``pt.train.step``): see them in a
+    dispatch's feed and wait with ``kind=chunk|decode|draft|verify``;
+    the trainers' dispatch is ``pt.train.step``): see them in a
     ``jax.profiler.start_trace`` session, on the device trace's clock, or
     on the ``boundaries`` thread of ``Engine.export_trace()`` with
     ``FLAGS_serving_trace`` on. (Thin view over the registry's "serving"

@@ -1,11 +1,9 @@
 """paddle_tpu.serving — continuous-batching TPU serving engine.
 
 Iteration-level (Orca-style) scheduling over a fixed B-slot decode batch.
-The default KV layout is block-PAGED (vLLM-style: fixed-size pages + a
-slot->page table, prefix reuse copy-on-write, chunked prefill fused into
-the decode step); the PR 5 pooled ``[L, B, Smax, nh, d]`` layout remains
-available as the bitwise parity baseline (``kv_layout="pooled"``). See
-engine.py for the design; `profiler.serving_counters()` /
+The KV cache is block-PAGED (vLLM-style: fixed-size pages + a slot->page
+table, prefix reuse copy-on-write, chunked prefill fused into the decode
+step). See engine.py for the design; `profiler.serving_counters()` /
 `serving_summary()` for observability.
 
 Self-healing (engine.py + supervisor.py): `Engine.state_dict()` /

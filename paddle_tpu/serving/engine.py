@@ -2,49 +2,31 @@
 family, built on the fixed-shape / cached-executable discipline of the
 eager+jit runtime.
 
-Two KV layouts (``kv_layout`` / FLAGS_serving_kv_layout):
+The KV cache is a block-paged pool ``[L, P, page_size, nh, d]`` (d padded
+to whole lanes on the device, ``pool_head_dim``) plus a slot->page table
+(vLLM-style PagedAttention): admission is bounded by physical PAGES, not
+worst-case-length slots, so effective batch tracks ACTUAL sequence lengths;
+prompts with a cached prefix map the same physical pages copy-on-write
+(serving/paged_kv.py); and long prompts prefill in fixed-size CHUNKS fused
+into the regular decode step (Sarathi-style), so admitting a 1024-token
+prompt does not stall all B decode streams for a monolithic prefill.
 
-* **paged** (default) — block-paged pool ``[L, P, page_size, nh, d]``
-  (d padded to whole lanes on the device, ``pool_head_dim``) plus a
-  slot->page table (vLLM-style PagedAttention): admission is
-  bounded by physical PAGES, not worst-case-length slots, so effective
-  batch tracks ACTUAL sequence lengths; prompts with a cached prefix map
-  the same physical pages copy-on-write (serving/paged_kv.py); and long
-  prompts prefill in fixed-size CHUNKS fused into the regular decode step
-  (Sarathi-style), so admitting a 1024-token prompt no longer stalls all
-  B decode streams for a monolithic prefill. Steady state uses a small
-  static executable set — ONE fused step dispatched at its two shapes
-  ([B, 1] decode over all slots, [1, chunk] prefill chunk), plus the CoW
-  page copy — all trace-counter gated, and
-  every per-request quantity (chunk offset, is-prefill/emit, page table,
-  sampling params, PRNG keys) is a traced operand. Token streams stay
-  bitwise identical to single-request ``generate_from_params`` for any
-  admission order, greedy and sampled, with sharing and chunking on.
-* **pooled** — the PR 5 contiguous ``[L, B, Smax, nh, d]`` layout, kept
-  as the bitwise parity baseline.
-
-Pooled design
--------------
-The engine owns a fixed batch of B decode SLOTS backed by one pooled KV
-cache ``[L, B, Smax, nh, d]`` and exactly TWO steady-state executables:
-
-* **prefill** — ONE sequence, prompt right-padded to a length bucket,
-  forwarded with the slot's cache rows sliced out of the pool
-  (`dynamic_slice`), KV written back via `dynamic_update_slice`, logits read
-  at the true last prompt token. One executable per configured bucket; the
-  bucket ladder is static so steady state never sees a new shape.
-* **decode** — one token for ALL B slots at once. Every per-slot quantity
-  that varies across requests — absolute position, active mask, do_sample
-  mask, temperature, top_p, PRNG key — is a TRACED operand, so admission,
-  eviction, slot recycling and sampling-config changes are pure data
-  changes: the executable is reused, never re-traced (`top_k` stays static,
-  it shapes the top_k kernel).
+The engine owns a fixed batch of B decode SLOTS and a small static
+executable set — ONE fused step dispatched at its steady-state shapes
+([B, 1] decode over all slots, [1, chunk] prefill chunk, one shape a rung of
+the chunk ladder), plus the CoW page copy — all trace-counter gated. Every
+per-request quantity that varies (chunk offset, is-prefill/emit, page table,
+absolute position, do_sample mask, temperature, top_p, PRNG keys) is a TRACED
+operand, so admission, eviction, slot recycling, chunk progress and
+sampling-config changes are pure data changes: the executable is reused,
+never re-traced (`top_k` stays static, it shapes the top_k kernel).
 
 Requests join and leave at step boundaries (continuous batching): a finished
-request's slot is recycled into a prefill for the next queued request while
-the other slots' decode continues undisturbed — each slot's token stream is
-bitwise identical to running that request alone through
-`models.generation.generate_from_params` (greedy; tested).
+request's slot is recycled for the next queued request while the other
+slots' decode continues undisturbed — each slot's token stream is bitwise
+identical to running that request alone through
+`models.generation.generate_from_params`, for any admission order, greedy
+and sampled, with sharing and chunking on (tested).
 
 The host loop fetches each step's B next-tokens (serving must stream tokens
 out anyway) and keeps all scheduling state in numpy; only the KV pool stays
@@ -64,8 +46,7 @@ from ..flags import get_flags
 from ..observability import tracing as obs_tracing
 from ..utils import fault_injection as _fi
 from ..models.generation import (
-    _cfg_key, _cfg_view, _collect_params, _forward_cached,
-    _forward_decode_slots, _next_token, _verify_accept,
+    _cfg_key, _cfg_view, _collect_params, _next_token, _verify_accept,
 )
 from . import metrics
 from . import quant as _squant
@@ -108,53 +89,11 @@ class EngineStoppedError(RuntimeError):
         self.retry_after = retry_after
 
 
-# Both builders are memoized on (cfg, top_k, donate): every Engine with the
-# same model config shares ONE jit wrapper, so a rebuilt/second engine reuses
-# the already-compiled executables instead of re-tracing (fast restart). The
-# trace counters are correspondingly GLOBAL — a new engine over warm shapes
-# adds zero traces.
-@lru_cache(maxsize=None)
-def _make_prefill(cfg, top_k, donate):
-    """Build the bucketed single-sequence prefill executable. Distinct
-    bucket lengths arrive as distinct ids shapes -> one trace per bucket."""
-    config = _cfg_view(cfg)
-
-    def fn(params, kc, vc, ids, plen, slot, key_data, do_sample,
-           temperature, top_p):
-        metrics.bump("prefill_traces")  # body runs only when traced
-        kcs = jax.lax.dynamic_slice_in_dim(kc, slot, 1, axis=1)
-        vcs = jax.lax.dynamic_slice_in_dim(vc, slot, 1, axis=1)
-        logits, kcs, vcs = _forward_cached(params, config, ids[None],
-                                           kcs, vcs, 0, last_index=plen - 1)
-        kc = jax.lax.dynamic_update_slice_in_dim(kc, kcs, slot, axis=1)
-        vc = jax.lax.dynamic_update_slice_in_dim(vc, vcs, slot, axis=1)
-        key, sub = jax.random.split(jax.random.wrap_key_data(key_data))
-        tok = _next_token(logits, sub, do_sample, temperature, top_k,
-                          top_p)[0]
-        return kc, vc, tok, jax.random.key_data(key)
-
-    return jax.jit(fn, donate_argnums=donate)
-
-
-@lru_cache(maxsize=None)
-def _make_decode(cfg, top_k, donate):
-    """Build the one-token decode executable over all B slots."""
-    config = _cfg_view(cfg)
-
-    def fn(params, kc, vc, tok, pos, active, do_sample, temperature, top_p,
-           key_data):
-        metrics.bump("decode_traces")  # body runs only when traced
-        logits, kc, vc = _forward_decode_slots(params, config, tok, kc, vc,
-                                               pos)
-        keys = jax.random.wrap_key_data(key_data)           # [B] keys
-        pair = jax.vmap(jax.random.split)(keys)             # [B, 2] keys
-        nxt = _next_token(logits, pair[:, 1], do_sample & active,
-                          temperature, top_k, top_p)
-        return kc, vc, nxt, jax.random.key_data(pair[:, 0])
-
-    return jax.jit(fn, donate_argnums=donate)
-
-
+# Every builder is memoized on its static configuration: every Engine with
+# the same model config shares ONE jit wrapper, so a rebuilt/second engine
+# reuses the already-compiled executables instead of re-tracing (fast
+# restart). The trace counters are correspondingly GLOBAL — a new engine over
+# warm shapes adds zero traces.
 @lru_cache(maxsize=None)
 def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
                      mp_key=None, anomaly=False, quant=None,
@@ -371,9 +310,9 @@ class Engine:
     """
 
     def __init__(self, model=None, *, params=None, config=None,
-                 num_slots=None, max_seq_len=None, prefill_buckets=None,
-                 max_queue=None, top_k=None, kv_layout=None, page_size=None,
-                 num_pages=None, prefill_chunk=None, prefix_cache=None,
+                 num_slots=None, max_seq_len=None, max_queue=None,
+                 top_k=None, page_size=None, num_pages=None,
+                 prefill_chunk=None, prefix_cache=None,
                  tag=None, trace=None, priority=None, tenant_weights=None,
                  shed=None, params_version=0, mesh=None, mp=None,
                  comm_backend=None, anomaly=None, quant=None, role=None,
@@ -459,23 +398,6 @@ class Engine:
         # serving runtime (no-op at the default 0; idempotent otherwise)
         from ..observability import prometheus as _prom
         _prom.start_from_flags()
-        self.kv_layout = (kv_layout or
-                          flags.get("FLAGS_serving_kv_layout", "paged"))
-        if self.kv_layout not in ("paged", "pooled"):
-            raise ValueError(f"kv_layout must be 'paged' or 'pooled', got "
-                             f"{self.kv_layout!r}")
-        self._refuse("pooled", self.kv_layout == "pooled")
-        if self.mp > 1 and self.kv_layout != "paged":
-            raise ValueError(
-                "tensor-parallel serving shards the PAGED pool (the "
-                "pooled layout is the single-chip parity baseline); use "
-                "kv_layout='paged' with mp > 1")
-        if self._quant is not None and self.kv_layout != "paged":
-            raise ValueError(
-                "quantized serving rides the paged layout (pages are the "
-                "KV quantization block; the pooled layout is the "
-                "full-precision parity baseline); use kv_layout='paged' "
-                "with FLAGS_serving_weight_dtype/kv_dtype != 'bf16'")
         self.num_slots = int(num_slots or flags.get("FLAGS_serving_slots", 8))
         self.max_seq_len = int(max_seq_len or
                                flags.get("FLAGS_serving_max_seq_len", 0) or
@@ -484,9 +406,6 @@ class Engine:
             raise ValueError(
                 f"max_seq_len {self.max_seq_len} exceeds the model's wpe "
                 f"table ({config.max_seq_len})")
-        buckets = prefill_buckets or flags.get(
-            "FLAGS_serving_prefill_buckets", (64, 256, 1024))
-        buckets = sorted({min(int(b), self.max_seq_len) for b in buckets})
         # SLO traffic management (serving/slo.py) — ALL policy, no traced
         # operand or executable changes: with both knobs off, admission is
         # the strict FCFS the parity suites gate, byte-identical to the
@@ -520,16 +439,9 @@ class Engine:
         self._refuse("adapters", self._adapter_spec is not None)
         self.adapters = None            # AdapterRegistry once constructed
         self._tenant_adapters = {}
-        if self._adapter_spec is not None and self.kv_layout != "paged":
-            raise ValueError(
-                "adapter serving rides the paged layout (per-slot adapter "
-                "ids are traced operands of the fused paged step; the "
-                "pooled layout is the parity baseline); use "
-                "kv_layout='paged' with FLAGS_serving_adapter_slots > 0")
         lane_key = (None if self._adapter_spec is None
                     else (lambda r: r.adapter or 0))
         self.scheduler = Scheduler(
-            buckets,
             max_queue=int(max_queue or
                           flags.get("FLAGS_serving_max_queue", 256)),
             priority=self.priority_mode, tenant_weights=tenant_weights,
@@ -561,11 +473,6 @@ class Engine:
             raise ValueError(
                 f"FLAGS_serving_anomaly_policy must be 'off' or "
                 f"'quarantine', got {policy!r}")
-        if policy != "off" and self.kv_layout != "paged":
-            raise ValueError(
-                "the serving anomaly guard rides the fused paged step; "
-                "use kv_layout='paged' (the pooled layout is the "
-                "unguarded parity baseline)")
         self.anomaly_policy = policy
         self._anomaly = policy != "off"
         self.top_k = (None if top_k in (None, 0)
@@ -584,12 +491,6 @@ class Engine:
         self._spec_draft = None
         self._spec_verify = None
         self._draft_params_version = None
-        if self._spec is not None and self.kv_layout != "paged":
-            raise ValueError(
-                "speculative decoding rides the paged layout (the draft "
-                "shares the paged pool and rejected writes rewind "
-                "per-page; the pooled layout is the parity baseline); "
-                "use kv_layout='paged' with FLAGS_serving_speculate_k > 0")
         if self._spec is not None and self.mp > 1:
             raise ValueError(
                 "speculative decoding is single-chip for now (the draft/"
@@ -627,91 +528,80 @@ class Engine:
         self._geo = geo = self._model.geometry(config)
         n_pools = len(geo.names)
         compute = jnp.dtype(geo.dtype)
-        self._kv_quant = False
 
-        if self.kv_layout == "pooled":
-            self._prefill = _make_prefill(cfg, self.top_k,
-                                          (1, 2) if donate_ok else ())
-            self._decode = _make_decode(cfg, self.top_k,
-                                        (1, 2) if donate_ok else ())
-            shape = (geo.layers, B, self.max_seq_len) + geo.row
+        self.page_size = int(page_size or
+                             flags.get("FLAGS_serving_page_size", 16))
+        self.prefill_chunk = int(
+            prefill_chunk or flags.get("FLAGS_serving_prefill_chunk", 16))
+        if self.prefill_chunk < self.page_size:
+            raise ValueError(
+                f"prefill_chunk ({self.prefill_chunk}) must be >= "
+                f"page_size ({self.page_size})")
+        # the chunk LADDER: power-of-two multiples of page_size up to
+        # prefill_chunk. Bulk prefill rides the largest rung; the tail
+        # drops down the ladder so the final chunk's padding is always
+        # < page_size. One executable per rung, all trace-gated.
+        self._chunk_ladder = [self.page_size]
+        while self._chunk_ladder[-1] * 2 <= self.prefill_chunk:
+            self._chunk_ladder.append(self._chunk_ladder[-1] * 2)
+        if prefix_cache is None:
+            prefix_cache = bool(flags.get("FLAGS_serving_prefix_cache", True))
+        kv_dtype = self._quant.kv_dtype if self._quant is not None else "bf16"
+        pool_kw = {}
+        if kv_dtype != "bf16":
+            pool_kw = dict(kv_dtype=kv_dtype,
+                           num_layers=config.num_layers,
+                           k_clip=self._quant.kv_k_clip,
+                           v_clip=self._quant.kv_v_clip,
+                           qmax=_squant.QMAX[kv_dtype])
+        self.pool = PagedKVPool(
+            B, self.max_seq_len, self.page_size,
+            num_pages=int(num_pages or
+                          flags.get("FLAGS_serving_num_pages", 0) or 0),
+            prefix_cache=prefix_cache, **pool_kw)
+        self._kv_quant = kv_dtype != "bf16"
+        use_kernel = bool(flags.get("FLAGS_serving_paged_kernel", True)
+                          ) and self._model.kernel_ok(
+                              config, self.mp, self.page_size)
+        quant_key = None if self._quant is None else self._quant.key()
+        qkernel = (self._quant is not None
+                   and self._quant.quantizes_weights
+                   and self.mp == 1
+                   and bool(flags.get("FLAGS_serving_quant_kernel", True))
+                   and jax.default_backend() == "tpu")
+        adapter_key = (None if self._adapter_spec is None
+                       else self._adapter_spec.key())
+        if self.mp > 1:
+            self._paged_step = _make_paged_step(
+                cfg, self.top_k, self.page_size, use_kernel,
+                (1, 2) if donate_ok else (),
+                mp_key=(self._mesh, self._mp_cfg),
+                anomaly=self._anomaly, quant=quant_key,
+                qkernel=qkernel, adapters=adapter_key)
         else:
-            self.page_size = int(page_size or
-                                 flags.get("FLAGS_serving_page_size", 16))
-            self.prefill_chunk = int(
-                prefill_chunk or flags.get("FLAGS_serving_prefill_chunk", 16))
-            if self.prefill_chunk < self.page_size:
-                raise ValueError(
-                    f"prefill_chunk ({self.prefill_chunk}) must be >= "
-                    f"page_size ({self.page_size})")
-            # the chunk LADDER: power-of-two multiples of page_size up to
-            # prefill_chunk. Bulk prefill rides the largest rung; the tail
-            # drops down the ladder so the final chunk's padding is always
-            # < page_size. One executable per rung, all trace-gated.
-            self._chunk_ladder = [self.page_size]
-            while self._chunk_ladder[-1] * 2 <= self.prefill_chunk:
-                self._chunk_ladder.append(self._chunk_ladder[-1] * 2)
-            if prefix_cache is None:
-                prefix_cache = bool(
-                    flags.get("FLAGS_serving_prefix_cache", True))
-            kv_dtype = (self._quant.kv_dtype if self._quant is not None
-                        else "bf16")
-            pool_kw = {}
-            if kv_dtype != "bf16":
-                pool_kw = dict(kv_dtype=kv_dtype,
-                               num_layers=config.num_layers,
-                               k_clip=self._quant.kv_k_clip,
-                               v_clip=self._quant.kv_v_clip,
-                               qmax=_squant.QMAX[kv_dtype])
-            self.pool = PagedKVPool(
-                B, self.max_seq_len, self.page_size,
-                num_pages=int(num_pages or
-                              flags.get("FLAGS_serving_num_pages", 0) or 0),
-                prefix_cache=prefix_cache, **pool_kw)
-            self._kv_quant = kv_dtype != "bf16"
-            use_kernel = bool(flags.get("FLAGS_serving_paged_kernel", True)
-                              ) and self._model.kernel_ok(
-                                  config, self.mp, self.page_size)
-            quant_key = None if self._quant is None else self._quant.key()
-            qkernel = (self._quant is not None
-                       and self._quant.quantizes_weights
-                       and self.mp == 1
-                       and bool(flags.get("FLAGS_serving_quant_kernel",
-                                          True))
-                       and jax.default_backend() == "tpu")
-            adapter_key = (None if self._adapter_spec is None
-                           else self._adapter_spec.key())
-            if self.mp > 1:
-                self._paged_step = _make_paged_step(
-                    cfg, self.top_k, self.page_size, use_kernel,
-                    (1, 2) if donate_ok else (),
-                    mp_key=(self._mesh, self._mp_cfg),
-                    anomaly=self._anomaly, quant=quant_key,
-                    qkernel=qkernel, adapters=adapter_key)
-            else:
-                self._paged_step = _make_paged_step(
-                    cfg, self.top_k, self.page_size, use_kernel,
-                    tuple(range(1, 1 + n_pools)) if donate_ok else (),
-                    anomaly=self._anomaly, quant=quant_key, qkernel=qkernel,
-                    adapters=adapter_key, model=self._model)
-            self._page_copy = _make_page_copy((0,) if donate_ok else ())
-            if self._spec is not None:
-                # one draft + one verify builder, memoized per config like
-                # every other serving executable: a second spec engine
-                # over warm shapes adds zero traces
-                self._spec_verify = _make_spec_verify(
-                    cfg, self.top_k, self.page_size,
-                    (1, 2) if donate_ok else (), anomaly=self._anomaly,
-                    quant=quant_key, qkernel=qkernel)
-                self._spec_draft = _make_spec_draft(
-                    cfg, self.page_size, self._spec.k, quant=quant_key)
-                self._build_draft_params()
-            # a row's last axis padded to whole lanes on the device;
-            # snapshots and page payloads keep the model's own width
-            # (_logical / pad_lanes)
-            shape = geo.pool_shape(self.pool.num_pages, self.page_size)
-            if self._kv_quant:
-                compute = _squant.STORE_DTYPES[kv_dtype]
+            self._paged_step = _make_paged_step(
+                cfg, self.top_k, self.page_size, use_kernel,
+                tuple(range(1, 1 + n_pools)) if donate_ok else (),
+                anomaly=self._anomaly, quant=quant_key, qkernel=qkernel,
+                adapters=adapter_key, model=self._model)
+        self._page_copy = _make_page_copy((0,) if donate_ok else ())
+        if self._spec is not None:
+            # one draft + one verify builder, memoized per config like
+            # every other serving executable: a second spec engine
+            # over warm shapes adds zero traces
+            self._spec_verify = _make_spec_verify(
+                cfg, self.top_k, self.page_size,
+                (1, 2) if donate_ok else (), anomaly=self._anomaly,
+                quant=quant_key, qkernel=qkernel)
+            self._spec_draft = _make_spec_draft(
+                cfg, self.page_size, self._spec.k, quant=quant_key)
+            self._build_draft_params()
+        # a row's last axis padded to whole lanes on the device;
+        # snapshots and page payloads keep the model's own width
+        # (_logical / pad_lanes)
+        shape = geo.pool_shape(self.pool.num_pages, self.page_size)
+        if self._kv_quant:
+            compute = _squant.STORE_DTYPES[kv_dtype]
         # the pool arrays a layer keeps, in the geometry's order (GPT: K
         # and V, also reachable as _kc / _vc)
         self._pools = tuple(jnp.zeros(shape, compute) for _ in geo.names)
@@ -743,7 +633,7 @@ class Engine:
         self._top_p = np.ones(B, np.float32)
         self._do_sample = np.zeros(B, bool)
         self._aid = np.zeros(B, np.int32)       # per-slot adapter row id
-        # paged: next prompt index to prefill for slot b (== prompt_len once
+        # next prompt index to prefill for slot b (== prompt_len once
         # prefill is done and the slot is decoding), plus the admission
         # sequence number that keeps chunked prefill FCFS across slots
         self._chunk_off = np.zeros(B, np.int32)
@@ -802,8 +692,8 @@ class Engine:
                 f"model with {option!r} yet (unsupported: "
                 f"{', '.join(sorted(self._model.unsupported))})")
 
-    # GPT's two pool arrays by name, for the paths only GPT takes (pooled
-    # layout, speculative verify, KV transfer, the chaos hooks) and tests
+    # GPT's two pool arrays by name, for the paths only GPT takes
+    # (speculative verify, KV transfer, the chaos hooks) and tests
     @property
     def _kc(self):
         return self._pools[0]
@@ -868,17 +758,12 @@ class Engine:
         IDLE (no slots, no queue, no in-flight transfers): a mid-stream
         flip would strand half-prefilled slots with no decoder. The
         supervisor flips roles only through a drain (``_set_replica_role``).
-        Non-"both" roles require the paged layout (the handoff is a page
-        copy + a table splice)."""
+        The handoff is a page copy + a table splice."""
         role = str(role)
         if role not in ("both", "prefill", "decode"):
             raise ValueError(
                 f"role must be 'both', 'prefill' or 'decode', got {role!r}")
         self._refuse("kv_transfer", role != "both")
-        if role != "both" and self.kv_layout != "paged":
-            raise ValueError(
-                "disaggregated roles ride the paged layout (KV pages are "
-                "the transfer unit); use kv_layout='paged'")
         if role != "both" and getattr(self, "adapters", None) is not None:
             raise ValueError(
                 "adapter serving is single-role for now (a prefill/decode "
@@ -908,9 +793,6 @@ class Engine:
         chunk tokens of every mid-prefill slot plus every queued prompt.
         The supervisor folds this into its load probe — queue depth alone
         makes a replica mid-giant-prefill look idle."""
-        if self.kv_layout != "paged":
-            return sum(r.prompt_len for r in self.scheduler._q
-                       if r.state != FINISHED)
         backlog = 0
         for b, req in enumerate(self._slots):
             if req is not None:
@@ -925,10 +807,8 @@ class Engine:
         full-page prefix ``prompt[:(j+1)*page_size]`` and ``exact_key``
         digests the whole prompt — the same keys (hashed) the prefix
         cache indexes by, so the router and tests never reach into cache
-        internals. Paged layout only."""
+        internals."""
         import hashlib
-        if self.kv_layout != "paged":
-            raise ValueError("prefix_page_hashes needs the paged layout")
         prompt = np.ascontiguousarray(np.asarray(prompt, np.int32))
         ps = self.page_size
         hashes = tuple(
@@ -941,9 +821,7 @@ class Engine:
 
     def prefix_coverage(self, prompt):
         """Tokens of ``prompt`` this engine's prefix cache already holds
-        (longest cached prefix, LRU-neutral probe). 0 for pooled engines."""
-        if self.kv_layout != "paged":
-            return 0
+        (longest cached prefix, LRU-neutral probe)."""
         prompt = np.ascontiguousarray(np.asarray(prompt, np.int32))
         return self.pool.peek_coverage(prompt)
 
@@ -967,29 +845,18 @@ class Engine:
             metrics.bump("rejected")
             raise ValueError(
                 f"prompt ({plen}) + max_new_tokens "
-                f"({request.max_new_tokens}) exceeds the KV "
-                f"{'table capacity' if self.kv_layout == 'paged' else 'pool'}"
-                f" max_seq_len ({self.max_seq_len})")
-        if self.kv_layout == "pooled":
-            # the pooled layout additionally caps prompts at the largest
-            # prefill bucket; paged prompts prefill in chunks of any count
-            if plen > self.scheduler.buckets[-1]:
-                metrics.bump("rejected")
-                raise ValueError(
-                    f"prompt length {plen} exceeds the largest prefill "
-                    f"bucket {self.scheduler.buckets[-1]}")
-        else:
-            # worst-case demand is exactly the lifetime page count: a CoW
-            # spare is reserved only when >= 1 page is prefix-shared, and
-            # every shared page reduces the fresh-page need by one. A
-            # request that can NEVER fit must fail fast instead of
-            # deadlocking the FCFS queue head.
-            worst = pages_for(plen + request.max_new_tokens, self.page_size)
-            if worst > self.pool.num_pages - 1:
-                metrics.bump("rejected")
-                raise ValueError(
-                    f"request needs up to {worst} KV pages but the pool "
-                    f"only has {self.pool.num_pages - 1}")
+                f"({request.max_new_tokens}) exceeds the KV table capacity "
+                f"max_seq_len ({self.max_seq_len})")
+        # worst-case demand is exactly the lifetime page count: a CoW spare
+        # is reserved only when >= 1 page is prefix-shared, and every shared
+        # page reduces the fresh-page need by one. A request that can NEVER
+        # fit must fail fast instead of deadlocking the FCFS queue head.
+        worst = pages_for(plen + request.max_new_tokens, self.page_size)
+        if worst > self.pool.num_pages - 1:
+            metrics.bump("rejected")
+            raise ValueError(
+                f"request needs up to {worst} KV pages but the pool "
+                f"only has {self.pool.num_pages - 1}")
         if request.top_k not in (None, self.top_k):
             metrics.bump("rejected")
             raise ValueError(
@@ -1182,17 +1049,16 @@ class Engine:
         #     BEFORE admission, so a handed-off request (older by FCFS —
         #     it was admitted on the prefill worker already) takes a free
         #     slot ahead of fresh queue arrivals
-        if self.kv_layout == "paged" and self._transfers_in:
+        if self._transfers_in:
             self._pump_transfers(now)
 
         #    then admission into free slots at the boundary, FCFS or
-        #    class-aware WFQ (page-aware for the paged layout: a candidate
-        #    is admitted when PAGES suffice for its whole lifetime, not
-        #    when a whole-Smax slot does)
+        #    class-aware WFQ, page-aware: a candidate is admitted when
+        #    PAGES suffice for its whole lifetime, not when a whole-Smax
+        #    slot does
         free = [b for b, r in enumerate(self._slots) if r is None]
-        fits = self._try_reserve if self.kv_layout == "paged" else None
-        admitted, admit_expired = self.scheduler.admit(len(free), now,
-                                                       fits=fits)
+        admitted, admit_expired = self.scheduler.admit(
+            len(free), now, fits=self._try_reserve)
         for req in expired + admit_expired:
             # already _finish(EXPIRED)ed by the scheduler; _resolve stores
             # the result, bumps the ledger and closes the trace
@@ -1206,13 +1072,9 @@ class Engine:
         active = np.array([r is not None for r in self._slots])
         metrics.observe_boundary(self.scheduler.qsize(), int(active.sum()),
                                  self.num_slots)
-        if self.kv_layout == "paged":
-            metrics.observe_pages(self.pool.pages_in_use,
-                                  self.pool.num_pages - 1)
-            if active.any():
-                self._iterate_paged()
-        elif active.any():
-            self._iterate_pooled(active)
+        metrics.observe_pages(self.pool.pages_in_use, self.pool.num_pages - 1)
+        if active.any():
+            self._iterate_paged()
 
         self._step_count += 1
         if self._ckpt is not None and self._snapshot_every > 0 \
@@ -1223,40 +1085,6 @@ class Engine:
         return self.scheduler.qsize() > 0 or \
             any(r is not None for r in self._slots) or \
             bool(self._transfers_in) or bool(self._outbound)
-
-    def _iterate_pooled(self, active):
-        """One pooled-layout decode iteration: one token for every active
-        slot through the [L, B, Smax, nh, d] cache."""
-        clk = self._clock
-        t0 = clk.feed("pooled", "decode_time_s")
-        self._kc, self._vc, nxt, keys = self._decode(
-            self.params, self._kc, self._vc,
-            jnp.asarray(self._tok), jnp.asarray(self._pos),
-            jnp.asarray(active), jnp.asarray(self._do_sample),
-            jnp.asarray(self._temp), jnp.asarray(self._top_p),
-            jnp.asarray(self._keys))
-        clk.wait()
-        nxt = np.asarray(nxt)
-        t1 = clk.emit()
-        # copy: device_get views are read-only and _admit writes rows
-        self._keys = np.array(keys)
-        metrics.bump("decode_steps")
-        for b, req in enumerate(self._slots):
-            if req is None:
-                continue
-            if req.trace is not None:
-                req.trace.span("decode_step", t0, t1, pos=int(self._pos[b]))
-            tok = int(nxt[b])
-            req._emit(tok)
-            metrics.bump("tokens_out")
-            self._tok[b] = tok
-            self._pos[b] += 1
-            if req.stop_token_ids and tok in req.stop_token_ids:
-                self._free_slot(b)
-                self._resolve(req, STOP)
-            elif len(req.tokens) >= req.max_new_tokens:
-                self._free_slot(b)
-                self._resolve(req, LENGTH)
 
     def _record_mp_comm(self, B, T, t0, t1, reqs=()):
         """mp-rung observability per fused-step dispatch: the STATIC
@@ -1437,14 +1265,14 @@ class Engine:
             metrics.bump("sampled_steps")
 
     def warm_up(self):
-        """Compile the steady-state executables of the paged layout now:
-        every rung of the chunk ladder, the [B, 1] decode step and the page
-        copy, each dispatched once with no live lane (valid=0 sends every
-        write to the trash page, emit=False parks the keys), so that no
-        request meets a compile. Changes no state; returns the engine."""
-        if self.kv_layout != "paged" or self._spec is not None:
+        """Compile the steady-state executables now: every rung of the
+        chunk ladder, the [B, 1] decode step and the page copy, each
+        dispatched once with no live lane (valid=0 sends every write to
+        the trash page, emit=False parks the keys), so that no request
+        meets a compile. Changes no state; returns the engine."""
+        if self._spec is not None:
             raise ValueError("warm_up covers the paged fused step (no "
-                             "pooled layout, no speculative dispatch)")
+                             "speculative dispatch)")
         for b, t in [(1, c) for c in self._chunk_ladder] \
                 + [(self.num_slots, 1)]:
             out = self._paged_step(
@@ -1712,8 +1540,6 @@ class Engine:
         a free slot once all pages landed. Re-offering a transfer already
         in flight (a supervisor retry) restarts its install cleanly."""
         self._refuse("kv_transfer", True)
-        if self.kv_layout != "paged":
-            raise ValueError("KV transfers ride the paged layout")
         if self.role == "prefill":
             raise ValueError(f"engine {self.tag!r} is a prefill worker; "
                              f"offer transfers to a decode-capable engine")
@@ -1929,13 +1755,11 @@ class Engine:
 
     def _capacity_for(self, req):
         """Could ``req`` be admitted right now without preempting? Exact:
-        the paged check runs the real reservation as a side-effect-free
-        probe (pages allocated then immediately released, no ledger/plan
+        the check runs the real reservation as a side-effect-free probe
+        (pages allocated then immediately released, no ledger/plan
         writes)."""
         if not any(r is None for r in self._slots):
             return False
-        if self.kv_layout != "paged":
-            return True
         return self._try_reserve(req, probe=True)
 
     def _preempt_slot(self, than_rank):
@@ -1988,7 +1812,7 @@ class Engine:
                     continue          # free more slots/pages for it
             if not self.scheduler.cancel(risk):
                 return                # resolved concurrently: nothing owed
-            if self.kv_layout == "paged" and not self._try_reserve(risk):
+            if not self._try_reserve(risk):
                 # pages raced away between probe and reserve: restore the
                 # queue entry at its arrival position, retry next boundary
                 self.scheduler.requeue(risk)
@@ -2081,11 +1905,6 @@ class Engine:
         return True
 
     def _admit(self, req, b):
-        if self.kv_layout == "paged":
-            return self._admit_paged(req, b)
-        return self._admit_pooled(req, b)
-
-    def _admit_paged(self, req, b):
         """Bind slot b to the request's page plan (reserved by
         _try_reserve): cached prefix pages map logical 0..n_shared-1, fresh
         pages cover the rest of prompt + max_new_tokens. No forward pass
@@ -2130,59 +1949,6 @@ class Engine:
             self._fresh_outbound.append(tr)
             self._stream_pages(b, tr)
 
-    def _admit_pooled(self, req, b):
-        """Prefill req's prompt into slot b (prompt padded to its bucket);
-        the prefill emits the request's FIRST token (TTFT stops here)."""
-        plen = req.prompt_len
-        self._observe_admission(req, b)
-        req.params_version = self.params_version
-        bucket = self.scheduler.bucket_for(plen)
-        metrics.observe_prefill_waste(bucket - plen)
-        clk = self._clock
-        t0 = clk.feed("pooled", "prefill_time_s")
-        ids = np.zeros(bucket, np.int32)
-        ids[:plen] = req.prompt
-        key0 = jax.random.key_data(jax.random.key(req.seed))
-        self._kc, self._vc, tok, key = self._prefill(
-            self.params, self._kc, self._vc, jnp.asarray(ids),
-            jnp.int32(plen), jnp.int32(b), jnp.asarray(key0),
-            jnp.asarray(bool(req.do_sample)),
-            jnp.float32(req.temperature),
-            jnp.float32(1.0 if req.top_p is None else req.top_p))
-        clk.wait()
-        tok = int(np.asarray(tok))
-        t1 = clk.emit()
-        metrics.bump("prefill_calls")
-        metrics.bump("admitted")
-        if req.trace is not None:
-            req.trace.span("prefill", t0, t1, bucket=bucket, tokens=plen)
-
-        req.state = RUNNING
-        req.slot = b
-        fresh_first = req.first_token_t is None  # replays don't re-observe
-        req._emit(tok)
-        metrics.bump("tokens_out")
-        if fresh_first:
-            metrics.observe_ttft(req.first_token_t - req.submit_t,
-                                 priority=req.priority)
-            clk.add("prefill_span_s", req.first_token_t - self._admit_t[b])
-            clk.add("first_tokens", 1)
-            if req.trace is not None:
-                req.trace.instant("first_token", req.first_token_t)
-        if req.stop_token_ids and tok in req.stop_token_ids:
-            self._resolve(req, STOP)
-        elif req.max_new_tokens == 1:
-            self._resolve(req, LENGTH)
-        else:
-            self._slots[b] = req
-            self._keys[b] = np.asarray(key)
-            self._tok[b] = tok
-            self._pos[b] = plen        # first decode writes token's KV here
-            self._do_sample[b] = bool(req.do_sample)
-            self._temp[b] = float(req.temperature)
-            self._top_p[b] = 1.0 if req.top_p is None else float(req.top_p)
-        clk.admit()                    # back to the boundary's admission
-
     def _quarantine(self, req, b):
         """Anomaly-guard resolution (``FLAGS_serving_anomaly_policy=
         quarantine``): the fused step's per-slot all-finite check flagged
@@ -2211,7 +1977,7 @@ class Engine:
             tr = self._outbound.pop(req.request_id, None)
             if tr is not None and not tr.done:
                 tr.aborted = True
-        if self.kv_layout == "paged" and req is not None and register \
+        if req is not None and register \
                 and int(self._chunk_off[b]) >= req.prompt_len:
             # publish the prompt's pages for prefix reuse ON RELEASE
             # (vLLM-style cache-on-free): the slot never decodes into a
@@ -2245,8 +2011,7 @@ class Engine:
             metrics.observe_adapter_tokens(int(self._aid[b]),
                                            len(req.tokens))
         self._aid[b] = 0
-        if self.kv_layout == "paged":
-            self.pool.release_slot(b)
+        self.pool.release_slot(b)
 
     def _observe_admission(self, req, b):
         """Admission into slot b, one instant for all it feeds: the
@@ -2360,15 +2125,14 @@ class Engine:
         self.params = new
         self.params_version = (int(version) if version is not None
                                else self.params_version + 1)
-        if self.kv_layout == "paged":
-            # the prefix cache holds KV pages COMPUTED UNDER THE OLD
-            # WEIGHTS — a post-swap prompt that prefix-hit them would
-            # decode against stale KV (caught by the parity gate). Version
-            # bump invalidates the whole cache. This full flush is scoped
-            # to BASE-weight swaps only: adapter load/evict/swap
-            # (load_adapter & co.) never touch attention, so their pages
-            # stay valid and those ops deliberately skip this.
-            self.pool.clear_cache()
+        # the prefix cache holds KV pages COMPUTED UNDER THE OLD WEIGHTS —
+        # a post-swap prompt that prefix-hit them would decode against
+        # stale KV (caught by the parity gate). Version bump invalidates
+        # the whole cache. This full flush is scoped to BASE-weight swaps
+        # only: adapter load/evict/swap (load_adapter & co.) never touch
+        # attention, so their pages stay valid and those ops deliberately
+        # skip this.
+        self.pool.clear_cache()
         if self._spec is not None:
             # the draft must propose against the NEW weights (a stale
             # draft would only cost accept rate, never correctness — the
@@ -2505,7 +2269,9 @@ class Engine:
         # on mixed weights. The mismatch raises in load_state_dict; the
         # supervisor then falls back to replay-from-scratch on the new
         # version — zero drops either way, single-version results always.
-        meta = {"kv_layout": self.kv_layout, "num_slots": self.num_slots,
+        # "kv_layout" is a constant: the key stays so that snapshots load
+        # across versions of the program that wrote it.
+        meta = {"kv_layout": "paged", "num_slots": self.num_slots,
                 "max_seq_len": self.max_seq_len, "top_k": self.top_k,
                 "params_version": int(self.params_version),
                 "cfg": self._model.key(self.config),
@@ -2519,13 +2285,10 @@ class Engine:
                 # adapter CAPACITY is a compatibility axis (slab shapes);
                 # the resident SET is data and rides state["adapters"]
                 "adapters": (None if self._adapter_spec is None
-                             else self._adapter_spec.key())}
-        if self.kv_layout == "paged":
-            meta.update(page_size=self.page_size,
-                        prefill_chunk=self.prefill_chunk,
-                        num_pages=self.pool.num_pages)
-        else:
-            meta["buckets"] = tuple(self.scheduler.buckets)
+                             else self._adapter_spec.key()),
+                "page_size": self.page_size,
+                "prefill_chunk": self.prefill_chunk,
+                "num_pages": self.pool.num_pages}
         return meta
 
     @staticmethod
@@ -2546,9 +2309,9 @@ class Engine:
 
     def state_dict(self):
         """Snapshot the FULL engine as host numpy / plain python: device
-        KV (both layouts — for paged including the slot->page table,
-        refcounted allocator and prefix-cache entries via
-        ``PagedKVPool.state_dict``), the host slot table (last token,
+        KV (including the slot->page table, refcounted allocator and
+        prefix-cache entries via ``PagedKVPool.state_dict``), the host slot
+        table (last token,
         write position, per-slot threefry streams, sampling params, chunk
         progress, admission sequence), every in-flight and queued request
         (``Request.to_state``; ``on_token`` callbacks are not captured),
@@ -2585,9 +2348,8 @@ class Engine:
             # the outage when the perf origin changed (other host/boot)
             "snapshot_t": time.perf_counter(),
             "snapshot_wall": time.time(),
+            "pool": self.pool.state_dict(),
         }
-        if self.kv_layout == "paged":
-            state["pool"] = self.pool.state_dict()
         if self.adapters is not None:
             # the resident adapter SET rides every snapshot: a restored
             # (or supervisor-respawned) engine serves the same many-model
@@ -2616,7 +2378,7 @@ class Engine:
         """Restore a ``state_dict()`` snapshot into this (compatibly
         configured) engine and resume exactly: mid-decode slots continue
         token-for-token bitwise identically to an uninterrupted run,
-        greedy and sampled, on both layouts. No retracing happens — the
+        greedy and sampled. No retracing happens — the
         executable builders are memoized per config, so a restored engine
         over warm shapes re-dispatches the already-compiled fused step
         (trace counters do not move; gated in tests).
@@ -2690,13 +2452,12 @@ class Engine:
         self._admit_count = int(state["admit_count"])
         self._admit_t = [time.perf_counter()] * self.num_slots
         self._step_count = int(state["step_count"])
-        if self.kv_layout == "paged":
-            self.pool.load_state_dict(state["pool"])
-            # in-flight transfer state is NOT part of a snapshot (the
-            # KVTransfer objects live with the supervisor, which replays
-            # or re-offers them): staged pages restored by the pool have
-            # no owning stream anymore — return them to the free list
-            self.pool.clear_staged()
+        self.pool.load_state_dict(state["pool"])
+        # in-flight transfer state is NOT part of a snapshot (the
+        # KVTransfer objects live with the supervisor, which replays or
+        # re-offers them): staged pages restored by the pool have no
+        # owning stream anymore — return them to the free list
+        self.pool.clear_staged()
         self._transfers_in = []
         self._install_progress = {}
         self._outbound = {}
@@ -2746,7 +2507,7 @@ class Engine:
             for d in state["results"]}
         if restore_metrics:
             metrics.import_state(state["metrics"])
-        elif self.kv_layout == "paged" and self.pool.prefix_cache_enabled \
+        elif self.pool.prefix_cache_enabled \
                 and self.pool.cache_entries > 0:
             # the restored pool carries REAL cache entries whose lookups/
             # hits were counted before the snapshot: without the matching
@@ -2793,16 +2554,15 @@ class Engine:
         # by _free_slot above; inbound streams return their staged pages —
         # their requests live on with the SUPERVISOR (payloads retained on
         # the KVTransfer), which re-offers or replays them elsewhere
-        if self.kv_layout == "paged":
-            for tr in self._transfers_in:
-                self.pool.release_staged(tr.request_id)
-            self._transfers_in = []
-            self._install_progress = {}
-            for tr in self._outbound.values():
-                if not tr.done:
-                    tr.aborted = True
-            self._outbound = {}
-            self._fresh_outbound = []
+        for tr in self._transfers_in:
+            self.pool.release_staged(tr.request_id)
+        self._transfers_in = []
+        self._install_progress = {}
+        for tr in self._outbound.values():
+            if not tr.done:
+                tr.aborted = True
+        self._outbound = {}
+        self._fresh_outbound = []
         drained.extend(self.scheduler.drain_queue())
         drained.sort(key=lambda r: (
             r.submit_t if r.submit_t is not None else float("inf"),

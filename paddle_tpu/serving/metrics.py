@@ -2,10 +2,10 @@
 dispatch/comm/mp_comm/fault: a module-level ledger, snapshot via
 `profiler.serving_counters()`, one-line `profiler.serving_summary()`).
 
-The two trace counters are the engine's no-recompile audit trail: each jitted
-body bumps its counter only when actually TRACED, so after warmup
-(one prefill trace per bucket + one decode trace) the counts must freeze —
-admission, eviction and sampling-param changes reuse the cached executables.
+The ``*_traces`` counters are the engine's no-recompile audit trail: each
+jitted body bumps its counter only when actually TRACED, so after warmup (one
+trace a dispatch shape) the counts must freeze — admission, eviction and
+sampling-param changes reuse the cached executables.
 """
 from __future__ import annotations
 
@@ -25,10 +25,7 @@ def _zero():
         "submitted": 0, "admitted": 0, "completed": 0, "rejected": 0,
         "expired": 0, "cancelled": 0,
         "finished_stop": 0, "finished_length": 0,
-        # executables
-        "prefill_calls": 0, "prefill_traces": 0,
-        "decode_steps": 0, "decode_traces": 0,
-        # paged engine: fused chunk/decode dispatches. paged_traces freezes
+        # executables: fused chunk/decode dispatches. paged_traces freezes
         # after warmup at 1 (the [B,1] decode shape) + one [1,rung] trace
         # per chunk-ladder rung actually used; copy_traces at <= 1.
         "paged_steps": 0, "paged_traces": 0,
@@ -43,8 +40,8 @@ def _zero():
         # page occupancy observed at step boundaries
         "pages_inuse_sum": 0, "pages_inuse_max": 0, "pages_total": 0,
         "page_boundaries": 0,
-        # per-prefill padded-token waste: bucket - prompt_len (pooled) or
-        # n_chunks*chunk - prefilled_tokens (paged; < chunk per request)
+        # per-prefill padded-token waste: n_chunks*chunk - prefilled_tokens
+        # (< page_size per request)
         "prefill_padded_tokens": 0, "prefill_padded_reqs": 0,
         "prefill_padded_max": 0,
         # self-healing: engine snapshots + drain/replay recovery ledger.
@@ -119,9 +116,9 @@ def _zero():
         "adapter_admit_blocked": 0,
         "adapters_resident": 0, "adapter_delta_bytes": 0,
         # tokens / time. decode_time_s / prefill_time_s are feed + wait of
-        # the decode-side (decode, draft, verify, pooled decode) and of the
-        # prefill-side (chunk, pooled prefill) dispatches: each ends when
-        # the dispatch's outputs are on the host
+        # the decode-side (decode, draft, verify) and of the prefill-side
+        # (chunk) dispatches: each ends when the dispatch's outputs are on
+        # the host
         "tokens_out": 0,
         "decode_time_s": 0.0, "prefill_time_s": 0.0,
         # phase clock of Engine.step (PhaseClock below): seconds of every
@@ -653,7 +650,6 @@ def serving_summary():
             f"tokens: {c['tokens_out']}  tokens/s: {c['tokens_per_s']:.1f}  "
             f"ttft p50/p99: {ttft}  occupancy: {c['occupancy'] * 100:.1f}%  "
             f"queue: {c['queue_depth_mean']:.1f} avg/{c['queue_depth_max']} max  "
-            f"executables: {c['prefill_traces']} prefill + "
-            f"{c['decode_traces']} decode + {c['paged_traces']} paged"
+            f"executables: {c['paged_traces']} paged"
             f"{paged}{quant}{spec}{mp}{adapters}{disagg}{waste}{slo}{heal}"
             f"{sdc}")

@@ -6,10 +6,10 @@ Two implementations of the decode-attention read:
 
 * **pure-jnp page gather** (default, every backend) — gather each slot's
   pages into virtual ``[B, S, nh, d]`` order and run exactly the math of
-  ``models.generation._layer_decode_slots``. Because appended masked keys
+  ``models.generation._layer_cached``. Because appended masked keys
   contribute exact zeros to the softmax and context sums, the result is
-  BITWISE identical to the pooled layout and to single-request
-  ``generate_from_params`` — this is the tier-1 parity path.
+  BITWISE identical to single-request ``generate_from_params`` — this is
+  the tier-1 parity path.
 * **Pallas TPU kernel** (``paged_decode_attention``) — one-token decode
   that walks each slot's page list via scalar-prefetched table indices, so
   only that slot's LIVE pages move HBM->VMEM (the gather path materializes
@@ -318,7 +318,7 @@ def paged_attention_read(q, kc, vc, l, table, pos, page_size, use_kernel,
     pool_head_dim(d)] through the table; returns ctx [B, T, nh', d] in ``out_dtype``. The layer is
     addressed inside the consuming operation (the kernel's index_map, the
     page gather's start indices), never sliced out first. Every head's
-    math is independent and mirrors generation._layer_decode_slots
+    math is independent and mirrors generation._layer_cached
     exactly, so any head SUBSET (the mp engine's per-chip shard) is
     bitwise identical to the same heads of the full computation.
 
@@ -435,7 +435,7 @@ def _layer_paged(p, h, kc, vc, l, table, pos, valid, nh, eps, page_size,
     the whole pool kc/vc through the page table (padding lanes -> trash
     page 0), which comes back updated; attention reads the gathered
     virtual window with the absolute causal mask. Math mirrors
-    generation._layer_decode_slots / _layer_cached exactly, so a slot's
+    generation._layer_cached exactly, so a slot's
     stream is bitwise identical to single-request decode. Quantized
     engines route the GEMMs through ``_proj`` (epilogue dequant) and the
     KV writes/reads through the per-page scales. With adapters enabled,

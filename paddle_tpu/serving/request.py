@@ -107,9 +107,8 @@ class Request:        # OBJECT, and field-wise eq would compare numpy prompts
             self.prompt._data if hasattr(self.prompt, "_data") else self.prompt,
             np.int32).reshape(-1)
         if self.prompt.shape[0] == 0:
-            # an empty prompt would read logits at the pad token (the
-            # prefill's last_index clamps to 0) — plausible-looking output
-            # conditioned on nothing the user sent
+            # an empty prompt would read logits at a pad token —
+            # plausible-looking output conditioned on nothing the user sent
             raise ValueError("prompt must be non-empty")
         if self.max_new_tokens < 0:
             raise ValueError(

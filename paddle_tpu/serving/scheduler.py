@@ -4,10 +4,8 @@ FCFS admission at STEP boundaries (Orca-style iteration-level scheduling):
 between decode iterations the engine asks the scheduler for requests to
 prefill into free slots. The scheduler owns the wait queue (bounded —
 `submit` raises `QueueFullError` past `max_queue`, the backpressure signal a
-frontend turns into HTTP 429), prefill-bucket selection (prompt padded up to
-the smallest configured bucket, so steady state compiles one prefill
-executable per bucket, not per length), and per-request deadlines (expired
-requests are failed at the boundary instead of wasting a prefill).
+frontend turns into HTTP 429) and per-request deadlines (expired requests
+are failed at the boundary instead of wasting a prefill).
 """
 from __future__ import annotations
 
@@ -63,12 +61,8 @@ class Scheduler:
     fine-tune's burst cannot starve the other models sharing the engine.
     ``tenant_weights`` keys by whatever the lane key returns."""
 
-    def __init__(self, buckets, max_queue=256, priority=False,
-                 tenant_weights=None, lane_key=None):
-        buckets = sorted(int(b) for b in buckets)
-        if not buckets:
-            raise ValueError("need at least one prefill bucket")
-        self.buckets = tuple(buckets)
+    def __init__(self, max_queue=256, priority=False, tenant_weights=None,
+                 lane_key=None):
         self.max_queue = int(max_queue)
         self.priority = bool(priority)
         # weights clamp to >= 1: a zero credit would starve the tenant's
@@ -133,16 +127,6 @@ class Scheduler:
 
     def qsize(self):
         return len(self._q)
-
-    # -- bucket selection ----------------------------------------------------
-    def bucket_for(self, prompt_len):
-        """Smallest configured bucket >= prompt_len."""
-        for b in self.buckets:
-            if prompt_len <= b:
-                return b
-        raise ValueError(
-            f"prompt length {prompt_len} exceeds largest prefill bucket "
-            f"{self.buckets[-1]}")
 
     # -- expiry --------------------------------------------------------------
     def expire(self, now=None):

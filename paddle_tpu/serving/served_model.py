@@ -16,7 +16,7 @@ A configuration object names its model through a ``served_model`` attribute;
 one without it is the GPT family, whose seam is here. Scheduler, admission,
 page table, prefix cache, copy-on-write, chunk ladder, sampling and the phase
 clock are the engine's and shared by every model; what a model does not
-support yet (``unsupported``: spec, quant, adapters, mp, pooled, kv_transfer)
+support yet (``unsupported``: spec, quant, adapters, mp, kv_transfer)
 the engine refuses by name at construction."""
 from __future__ import annotations
 

@@ -487,7 +487,7 @@ class ServingSupervisor:
             best = None
             for r in decodes:
                 eng = r.engine
-                if eng is None or eng.kv_layout != "paged":
+                if eng is None:
                     continue
                 cov = eng.prefix_coverage(request.prompt)
                 if cov > 0 and plen - cov <= eng.page_size:

@@ -62,7 +62,6 @@ def _engine(**kw):
     kw.setdefault("max_seq_len", 96)
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("adapter_slots", 3)
     kw.setdefault("adapter_rank", 4)
     return serving.Engine(params=_params(), config=CFG, **kw)
@@ -282,8 +281,7 @@ def test_unknown_adapter_typed_errors():
         serving.Request([1, 2, 3], adapter=-1)
     # an adapter-less engine refuses adapter traffic, typed
     plain = serving.Engine(params=_params(), config=CFG, num_slots=2,
-                           max_seq_len=96, page_size=8, prefill_chunk=8,
-                           kv_layout="paged")
+                           max_seq_len=96, page_size=8, prefill_chunk=8)
     with pytest.raises(UnknownAdapterError):
         plain.submit(serving.Request([1, 2, 3], max_new_tokens=2, adapter=1))
     # tenant mapping outside capacity is a construction-time error
@@ -292,8 +290,6 @@ def test_unknown_adapter_typed_errors():
 
 
 def test_construction_gates():
-    with pytest.raises(ValueError, match="paged"):
-        _engine(kv_layout="pooled")
     with pytest.raises(ValueError, match="speculative"):
         _engine(speculate_k=2)
     eng = _engine()
@@ -350,7 +346,7 @@ def test_wfq_lanes_rotate_across_adapters():
     """Scheduler(lane_key=) generalization: admission deficit-round-
     robins across ADAPTER lanes, weights keyed by the lane value (string
     spelling accepted for flag-file weights)."""
-    sch = serving.Scheduler(buckets=(8,), priority=True,
+    sch = serving.Scheduler(priority=True,
                             tenant_weights={"1": 2},
                             lane_key=lambda r: r.adapter or 0)
     reqs = [serving.Request([1, 2], max_new_tokens=1, adapter=a)
@@ -439,8 +435,7 @@ def test_pre_adapter_snapshot_restores_on_adapter_engine_and_back():
     adapter-less engine built from the same factory defaults, and the
     meta['adapters'] field defaults cleanly when absent."""
     plain = serving.Engine(params=_params(), config=CFG, num_slots=3,
-                           max_seq_len=96, page_size=8, prefill_chunk=8,
-                           kv_layout="paged")
+                           max_seq_len=96, page_size=8, prefill_chunk=8)
     req = serving.Request(np.arange(3, 9), max_new_tokens=4)
     plain.submit(req)
     plain.step()
@@ -449,8 +444,7 @@ def test_pre_adapter_snapshot_restores_on_adapter_engine_and_back():
     state["meta"].pop("adapters", None)
     state.pop("aid", None)
     plain2 = serving.Engine(params=_params(), config=CFG, num_slots=3,
-                            max_seq_len=96, page_size=8, prefill_chunk=8,
-                            kv_layout="paged")
+                            max_seq_len=96, page_size=8, prefill_chunk=8)
     plain2.load_state_dict(state)
     res = plain2.run()
     assert res[req.request_id].tokens == _ref_tokens(req.prompt, 4)

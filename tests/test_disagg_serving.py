@@ -111,10 +111,6 @@ def test_roles_validation_errors():
     eng = _engine()
     with pytest.raises(ValueError, match="role"):
         eng.set_role("chef")
-    with pytest.raises(ValueError, match="paged"):
-        serving.Engine(params=_params(), config=CFG, kv_layout="pooled",
-                       num_slots=1, max_seq_len=96,
-                       prefill_buckets=(16,)).set_role("prefill")
     # a non-idle engine refuses the flip (mid-stream strand)
     busy = _engine()
     busy.submit(serving.Request([1, 2, 3], max_new_tokens=2))
@@ -145,10 +141,6 @@ def test_prefix_page_hashes_stable_routing_key():
     # sub-page prompts: no full page, exact key only
     hs, xs = e1.prefix_page_hashes([1, 2, 3])
     assert hs == () and xs
-    with pytest.raises(ValueError, match="paged"):
-        serving.Engine(params=_params(), config=CFG, kv_layout="pooled",
-                       num_slots=1, max_seq_len=96,
-                       prefill_buckets=(16,)).prefix_page_hashes(p)
 
 
 # ---------------------------------------------------------------------------

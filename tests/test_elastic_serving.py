@@ -517,8 +517,6 @@ def test_anomaly_policy_off_default_bitwise():
 def test_anomaly_policy_validation():
     with pytest.raises(ValueError, match="quarantine"):
         _engine(anomaly="retry")
-    with pytest.raises(ValueError, match="paged"):
-        _engine(anomaly="quarantine", kv_layout="pooled")
     paddle.set_flags({"FLAGS_serving_anomaly_policy": "quarantine"})
     try:
         assert _engine().anomaly_policy == "quarantine"

@@ -191,7 +191,8 @@ def test_next_token_is_bitwise_the_ungated_tail(rows, top_p, top_k):
 @pytest.mark.parametrize("do_sample", [False, True])
 @pytest.mark.parametrize("top_p", [1.0, 0.8])
 def test_next_token_one_key_for_the_batch(do_sample, top_p):
-    """The pooled prefill's call: one key, logits [1, V], scalar operands."""
+    """``_select_token``'s call (``generate_from_params``): one key for the
+    batch, scalar operands."""
     logits, subs, temp = _tail_operands(3)
     args = (logits[:1], subs[0], jnp.asarray(do_sample), temp[0])
     run = lambda fn: np.asarray(jax.jit(

@@ -345,11 +345,6 @@ def test_swap_params_mp_zero_retrace():
         "same-shape mp swap must not retrace"
 
 
-def test_mp_rejects_pooled_layout():
-    with pytest.raises(ValueError, match="paged"):
-        _engine(mp=2, kv_layout="pooled")
-
-
 def test_mp_rejects_indivisible_heads():
     cfg = GPTConfig(vocab_size=96, hidden_size=60, num_layers=1,
                     num_heads=3, max_seq_len=64, dropout=0.0,

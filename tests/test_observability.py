@@ -393,8 +393,8 @@ def test_supervisor_telemetry_family():
     params = init_gpt_params(CFG, jax.random.key(0))
     sup = ServingSupervisor(
         lambda: serving.Engine(params=params, config=CFG, num_slots=2,
-                               max_seq_len=48, kv_layout="pooled",
-                               prefill_buckets=(16,)),
+                               max_seq_len=48, page_size=8,
+                               prefill_chunk=8),
         num_replicas=2)
     tel = obs.collect("supervisor")
     assert tel["replicas"] == 2 and tel["alive"] == 2

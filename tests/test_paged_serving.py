@@ -1,6 +1,5 @@
-"""Paged-KV serving engine (kv_layout="paged", the default).
-
-Mirror of test_serving.py's bitwise gates on the block-paged layout:
+"""The serving engine's bitwise gates over its block-paged KV cache
+(test_serving.py holds what is the engine's and not the cache's):
   * for ANY admission order, each request's tokens are bitwise identical
     to single-request generate_from_params — greedy AND sampled, with
     chunked prefill and prefix sharing enabled;
@@ -10,9 +9,9 @@ Mirror of test_serving.py's bitwise gates on the block-paged layout:
   * steady state uses a STATIC executable set (fused step at T=1 and
     T=chunk + the CoW page copy), trace-counter gated;
   * the page allocator balances (no leaks) and admission is page-aware
-    (a workload that overflows the pooled layout's per-slot Smax serves
-    fine from pages);
-plus this PR's satellites: temperature validation, recycled-slot state
+    (a request longer than an equal share of the pool serves fine from
+    pages);
+plus the stop-condition matrix and the satellites: temperature validation, recycled-slot state
 reset, prefill padded-waste metric, and the Pallas kernel's interpret-mode
 parity with the jnp gather path.
 """
@@ -44,7 +43,6 @@ def _engine(**kw):
     kw.setdefault("max_seq_len", 96)
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("kv_layout", "paged")
     return serving.Engine(params=_params(), config=CFG, **kw)
 
 
@@ -55,16 +53,16 @@ def _ref_tokens(prompt, max_new, **kw):
     return out[0, len(prompt):].tolist()
 
 
-# shape palette shared with test_serving.py (warm jit cache for the
-# reference); includes prompts longer than the chunk so prefill chunking
+# shape palette whose first four are test_serving.py's (warm jit cache for
+# the reference); includes prompts longer than the chunk so prefill chunking
 # and page crossing are always exercised
 _SHAPES = ((3, 4), (5, 6), (9, 4), (13, 6), (21, 5), (37, 4))
 
 
-def _mixed_requests(n, rng, **kw):
+def _mixed_requests(n, rng, shapes=_SHAPES, **kw):
     reqs = []
     for i in range(n):
-        plen, mnt = _SHAPES[i % len(_SHAPES)]
+        plen, mnt = shapes[i % len(shapes)]
         reqs.append(serving.Request(rng.integers(0, CFG.vocab_size, plen),
                                     max_new_tokens=mnt, **kw))
     return reqs
@@ -74,68 +72,94 @@ def _mixed_requests(n, rng, **kw):
 # bitwise parity gates
 
 
-def test_greedy_bitwise_parity_chunked_mixed_lengths():
+@pytest.mark.parametrize("shapes,n", [(_SHAPES[:4], 7), (_SHAPES, 8)],
+                         ids=["one-chunk", "chunked"])
+def test_greedy_bitwise_parity_mixed_lengths(shapes, n):
+    """Prompts of one or two chunks, and prompts of up to five."""
     eng = _engine()
-    reqs = _mixed_requests(8, np.random.default_rng(0))
+    reqs = _mixed_requests(n, np.random.default_rng(0), shapes)
     results = eng.run(reqs)
     for r in reqs:
         assert results[r.request_id].tokens == \
             _ref_tokens(r.prompt, r.max_new_tokens), \
             f"request {r.request_id} diverged from single-request decode"
+        assert results[r.request_id].finish_reason == serving.LENGTH
 
 
-def test_sampled_stream_matches_generate():
-    eng = _engine()
-    prompt = np.array([5, 17, 33, 2, 9])
-    req = serving.Request(prompt, max_new_tokens=8, do_sample=True,
-                          temperature=0.8, top_p=0.9, seed=7)
+@pytest.mark.parametrize("prefill_chunk", [8, 32])
+@pytest.mark.parametrize("sampling", [
+    # the nucleus cut
+    {"temperature": 0.8, "top_p": 0.9, "seed": 7},
+    # none: the engine's traced top_p=1.0 stand-in must be bitwise
+    # identical to generate's structural top_p=None skip (float32 cumsum
+    # saturation used to mask tail tokens)
+    {"temperature": 1.3, "seed": 11},
+], ids=["top_p", "no-top_p"])
+def test_sampled_stream_matches_generate(sampling, prefill_chunk):
+    """Per-slot PRNG streams replicate generate's split-per-step stream, so
+    even SAMPLED requests match the single-request path exactly, whether
+    the prompt is one chunk of the ladder or three."""
+    eng = _engine(prefill_chunk=prefill_chunk)
+    prompt = np.random.default_rng(6).integers(0, CFG.vocab_size, 21)
+    req = serving.Request(prompt, max_new_tokens=12, do_sample=True,
+                          **sampling)
     res = eng.run([req])[req.request_id]
-    assert res.tokens == _ref_tokens(prompt, 8, do_sample=True,
-                                     temperature=0.8, top_p=0.9, seed=7)
-    # sampled without a nucleus cut: traced top_p=1.0 stand-in vs the
-    # structural None skip
-    req2 = serving.Request(np.arange(3, 11), max_new_tokens=8,
-                           do_sample=True, temperature=1.3, seed=11)
-    res = eng.run([req2])[req2.request_id]
-    assert res.tokens == _ref_tokens(np.arange(3, 11), 8, do_sample=True,
-                                     temperature=1.3, seed=11)
+    assert res.tokens == _ref_tokens(prompt, 12, do_sample=True, **sampling)
 
 
-def test_admission_order_invariance_under_page_contention():
-    """Same request set, two submission orders, a pool small enough that
-    admission WAITS on pages: per-request tokens must be identical. Shared
-    prefixes are included — prefix reuse must be output-invariant."""
-    rng = np.random.default_rng(1)
+def _unshared_prompts(rng):
+    return [rng.integers(0, CFG.vocab_size, int(rng.integers(3, 14)))
+            for _ in range(6)]
+
+
+def _shared_prefix_prompts(rng):
+    # prefix reuse must be output-invariant
     base = rng.integers(0, CFG.vocab_size, 17)
-    prompts = [base.copy(),
-               np.concatenate([base[:8], rng.integers(0, 97, 6)]),
-               rng.integers(0, CFG.vocab_size, 5),
-               rng.integers(0, CFG.vocab_size, 11)]
+    return [base.copy(),
+            np.concatenate([base[:8], rng.integers(0, 97, 6)]),
+            rng.integers(0, CFG.vocab_size, 5),
+            rng.integers(0, CFG.vocab_size, 11)]
+
+
+@pytest.mark.parametrize("make_prompts,new,engine_kw", [
+    (_unshared_prompts, 6, {}),
+    # a pool small enough (12 usable pages) that admission WAITS on pages
+    (_shared_prefix_prompts, 5, {"num_pages": 13}),
+], ids=["free-pages", "page-contention"])
+def test_admission_order_invariance(make_prompts, new, engine_kw):
+    """The same request set in two different submission orders produces the
+    same per-request tokens (slot assignment is irrelevant to output)."""
+    prompts = make_prompts(np.random.default_rng(1))
+    n = len(prompts)
     outs = []
-    for order in ((0, 1, 2, 3), (3, 2, 1, 0)):
-        eng = _engine(num_slots=2, num_pages=13)   # 12 usable pages
-        reqs = [serving.Request(prompts[i], max_new_tokens=5) for i in order]
+    for order in (range(n), reversed(range(n))):
+        eng = _engine(num_slots=2, **engine_kw)
+        reqs = [serving.Request(prompts[i], max_new_tokens=new)
+                for i in order]
         results = eng.run(reqs)
         outs.append({tuple(r.prompt.tolist()): results[r.request_id].tokens
                      for r in reqs})
     assert outs[0] == outs[1]
     for p, toks in outs[0].items():
-        assert toks == _ref_tokens(np.asarray(p, np.int32), 5)
+        assert toks == _ref_tokens(np.asarray(p, np.int32), new)
 
 
-def test_midflight_join_and_evict_keep_slots_bitwise_stable():
-    eng = _engine(num_slots=3)
+@pytest.mark.parametrize("prefill_chunk", [8, 32])
+def test_midflight_join_and_evict_keep_slots_bitwise_stable(prefill_chunk):
+    """A long-running request's stream must be untouched by other requests
+    joining mid-flight and by a neighbor slot being evicted."""
+    eng = _engine(num_slots=3, prefill_chunk=prefill_chunk)
     long_req = serving.Request(np.arange(2, 9), max_new_tokens=24)
     victim = serving.Request(np.arange(30, 40), max_new_tokens=24)
     eng.submit(long_req)
     eng.submit(victim)
-    for _ in range(4):
+    for _ in range(4):                      # both running, mid-flight
         eng.step()
     joiners = _mixed_requests(4, np.random.default_rng(2))
     for r in joiners:
-        eng.submit(r)
+        eng.submit(r)                       # join while long_req decodes
     eng.step()
-    eng.cancel(victim)
+    eng.cancel(victim)                      # evict a live neighbor slot
     results = eng.run()
     assert results[victim.request_id].finish_reason == serving.CANCELLED
     assert results[long_req.request_id].tokens == \
@@ -227,7 +251,6 @@ def test_steady_state_static_executable_set():
     warm = profiler.serving_counters()
     assert warm["paged_traces"] == 2
     assert warm["copy_traces"] <= 1
-    assert warm["prefill_traces"] == 0 and warm["decode_traces"] == 0
 
     rng = np.random.default_rng(4)
     reqs = []
@@ -278,18 +301,12 @@ def test_page_allocator_balances_no_leaks():
     assert c["pages_inuse_max"] <= 24
 
 
-def test_page_aware_admission_beyond_pooled_capacity():
-    """The paged engine serves a request whose prompt+max_new exceeds a
-    memory-equal pooled engine's per-slot Smax — admission is bounded by
-    pages, not worst-case slots. (The smoke tool benches the same setup.)"""
-    pooled = serving.Engine(params=_params(), config=CFG, num_slots=4,
-                            max_seq_len=48, prefill_buckets=(48,),
-                            kv_layout="pooled")
-    # same KV bytes: 4 slots x 48 = 192 token-slots = 24 pages x 8 (+trash)
+def test_page_aware_admission_beyond_an_equal_share():
+    """The engine serves a request whose prompt+max_new exceeds an equal
+    share of the pool (24 pages x 8 over 4 slots = 48 tokens a slot) —
+    admission is bounded by pages, not worst-case slots."""
     paged = _engine(num_slots=4, max_seq_len=128, num_pages=25)
     long_req = serving.Request(np.arange(1, 45), max_new_tokens=16)  # 60 > 48
-    with pytest.raises(ValueError):
-        pooled.submit(serving.Request(np.arange(1, 45), max_new_tokens=16))
     shorts = [serving.Request(np.arange(2, 8), max_new_tokens=5)
               for _ in range(3)]
     results = paged.run([long_req] + shorts)
@@ -326,27 +343,24 @@ def test_admission_waits_for_pages_then_proceeds():
 def test_recycled_slot_sampled_stream_is_bitwise_independent():
     """A recycled slot must not leak its predecessor's sampling state
     (_keys/_temp/_top_p/_do_sample are reset by _free_slot): the second
-    occupant's stream is bitwise what a fresh engine would produce —
-    gated on BOTH layouts."""
-    for layout in ("paged", "pooled"):
-        kw = {"prefill_buckets": (16,)} if layout == "pooled" else {}
-        eng = _engine(num_slots=1, kv_layout=layout, **kw)
-        hot = serving.Request(np.arange(1, 6), max_new_tokens=6,
-                              do_sample=True, temperature=0.3, top_p=0.8,
-                              seed=13)
-        eng.run([hot])
-        # slot state must be fully reset after recycling
-        assert eng._slots[0] is None
-        assert not eng._do_sample[0] and eng._temp[0] == 1.0 \
-            and eng._top_p[0] == 1.0 and not eng._keys[0].any()
-        cold = serving.Request(np.arange(7, 13), max_new_tokens=6)
-        res = eng.run([cold])[cold.request_id]
-        assert res.tokens == _ref_tokens(np.arange(7, 13), 6), layout
-        cold2 = serving.Request(np.arange(7, 13), max_new_tokens=6,
-                                do_sample=True, temperature=0.9, seed=3)
-        res = eng.run([cold2])[cold2.request_id]
-        assert res.tokens == _ref_tokens(np.arange(7, 13), 6, do_sample=True,
-                                         temperature=0.9, seed=3), layout
+    occupant's stream is bitwise what a fresh engine would produce."""
+    eng = _engine(num_slots=1)
+    hot = serving.Request(np.arange(1, 6), max_new_tokens=6,
+                          do_sample=True, temperature=0.3, top_p=0.8,
+                          seed=13)
+    eng.run([hot])
+    # slot state must be fully reset after recycling
+    assert eng._slots[0] is None
+    assert not eng._do_sample[0] and eng._temp[0] == 1.0 \
+        and eng._top_p[0] == 1.0 and not eng._keys[0].any()
+    cold = serving.Request(np.arange(7, 13), max_new_tokens=6)
+    res = eng.run([cold])[cold.request_id]
+    assert res.tokens == _ref_tokens(np.arange(7, 13), 6)
+    cold2 = serving.Request(np.arange(7, 13), max_new_tokens=6,
+                            do_sample=True, temperature=0.9, seed=3)
+    res = eng.run([cold2])[cold2.request_id]
+    assert res.tokens == _ref_tokens(np.arange(7, 13), 6, do_sample=True,
+                                     temperature=0.9, seed=3)
 
 
 def test_temperature_validation():
@@ -372,9 +386,8 @@ def test_temperature_validation():
 
 
 def test_prefill_waste_metric():
-    """Padded-token waste per prefill: paged chunks pad only the FINAL
-    chunk (< chunk tokens); the pooled layout pads every prompt to its
-    bucket."""
+    """Padded-token waste per prefill: chunks pad only the FINAL chunk
+    (< page_size tokens)."""
     profiler.reset_serving_counters()
     eng = _engine()                          # chunk == page_size == 8
     eng.run([serving.Request(np.arange(1, 14), max_new_tokens=2)])  # plen 13
@@ -384,35 +397,54 @@ def test_prefill_waste_metric():
     assert c["prefill_padded_max"] < eng.page_size
     assert "prefill-waste" in profiler.serving_summary()
 
-    profiler.reset_serving_counters()
-    pooled = serving.Engine(params=_params(), config=CFG, num_slots=2,
-                            max_seq_len=96, prefill_buckets=(16,),
-                            kv_layout="pooled")
-    pooled.run([serving.Request(np.arange(1, 14), max_new_tokens=2)])
-    c = profiler.serving_counters()
-    assert c["prefill_padded_tokens"] == 3           # 16 - 13
 
-
-def test_stop_conditions_and_deadlines_on_paged():
-    """Stop matrix + queue-expiry on the paged path."""
-    prompt = np.array([3, 14, 15, 92])
-    free = _ref_tokens(prompt, 8)
+@pytest.mark.parametrize("plen", [4, 21], ids=["one-chunk", "three-chunks"])
+def test_stop_condition_matrix_and_deadlines(plen):
+    """Stop matrix + queue-expiry, whether the first token (which may
+    itself stop the request) comes from a prompt's only chunk or its
+    last."""
+    prompt = np.random.default_rng(9).integers(0, CFG.vocab_size, plen)
+    free = _ref_tokens(prompt, 8)                 # unconstrained greedy
     eng = _engine()
-    r_eos = serving.Request(prompt, max_new_tokens=8, eos_token_id=free[2])
+
+    # scalar eos alias: stops at (and includes) the first eos
+    k = free.index(free[3])
+    r_eos = serving.Request(prompt, max_new_tokens=8, eos_token_id=free[3])
+    # stop_token_ids list: earliest of several stop ids wins
+    r_list = serving.Request(prompt, max_new_tokens=8,
+                             stop_token_ids=[free[5], free[2]])
+    # max_new_tokens cap
     r_len = serving.Request(prompt, max_new_tokens=4)
     r_one = serving.Request(prompt, max_new_tokens=1)
     dead = serving.Request(np.arange(1, 5), max_new_tokens=4, deadline_s=0.0)
     import time
     eng.submit(dead)
     time.sleep(0.01)
-    results = eng.run([r_eos, r_len, r_one])
-    assert results[r_eos.request_id].tokens == free[:3]
-    assert results[r_eos.request_id].finish_reason == serving.STOP
-    assert results[r_len.request_id].tokens == free[:4]
+    results = eng.run([r_eos, r_list, r_len, r_one])
+
+    res = results[r_eos.request_id]
+    assert res.finish_reason == serving.STOP
+    assert res.tokens == free[:k + 1]
+    first_stop = min(free.index(free[5]), free.index(free[2]))
+    res = results[r_list.request_id]
+    assert res.finish_reason == serving.STOP
+    assert res.tokens == free[:first_stop + 1]
+    res = results[r_len.request_id]
+    assert res.finish_reason == serving.LENGTH
+    assert res.tokens == free[:4]
     assert results[r_one.request_id].tokens == free[:1]
     assert results[dead.request_id].finish_reason == serving.EXPIRED
+    assert results[dead.request_id].tokens == []
     bal = eng.pool.balance()
     assert bal["conserved"] and bal["refcounts_accounted"]
+
+    # max_new_tokens == 0 resolves immediately with the prompt unchanged
+    r0 = serving.Request(prompt, max_new_tokens=0)
+    res = eng.run([r0])[r0.request_id]
+    assert res.tokens == [] and res.finish_reason == serving.LENGTH
+    np.testing.assert_array_equal(res.sequence, prompt)
+    with pytest.raises(ValueError):
+        serving.Request(prompt, max_new_tokens=-1)
 
 
 def test_prefix_cache_disabled_is_private():
@@ -664,38 +696,3 @@ def test_paged_kernel_routing_predicate():
     # off-TPU backends always fall back to the jnp gather path
     assert not paged_kernel_supported(8, 128, 16)   # cpu backend here
     assert not paged_kernel_supported(8, 64, 16)    # head_dim
-
-
-# ---------------------------------------------------------------------------
-# smoke-tool sub-rung: fast + deterministic in tier-1 (full ladder is slow)
-
-
-def _load_smoke():
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "tools_serving_smoke",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tools_serving_smoke.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_smoke_paged_deterministic_subrung():
-    """tools_serving_smoke's paged-vs-pooled rung in deterministic tiny
-    mode: output parity between layouts, chunked waste < page_size, and
-    the over-Smax capacity demo — no wall-clock gates (those are slow)."""
-    mod = _load_smoke()
-    out = mod.run_paged_rung(quick=True, deterministic=True)
-    assert out["outputs_match"]
-    assert out["capacity_only_paged"]
-    assert out["paged"]["prefill_waste_max"] < out["page_size"]
-
-
-@pytest.mark.slow
-def test_smoke_paged_beats_pooled():
-    mod = _load_smoke()
-    out = mod.run_paged_rung(quick=True)
-    assert out["speedup"] >= 1.3
-    assert out["paged"]["intertoken_p99_s"] <= out["pooled"]["intertoken_p99_s"]

@@ -244,8 +244,7 @@ def _params():
 
 def _engine():
     return serving.Engine(params=_params(), config=CFG, num_slots=3,
-                          max_seq_len=96, page_size=8, prefill_chunk=8,
-                          kv_layout="paged")
+                          max_seq_len=96, page_size=8, prefill_chunk=8)
 
 
 def _ref(prompt, n):

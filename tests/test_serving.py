@@ -1,16 +1,16 @@
-"""Continuous-batching serving engine (paddle_tpu.serving).
+"""Continuous-batching serving engine (paddle_tpu.serving): what is the
+engine's and not the KV cache's. The bitwise parity, admission-order,
+join/evict and stop-condition gates live in test_paged_serving.py.
 
-Correctness gates:
-  * for ANY admission order, each request's greedy tokens are bitwise
-    identical to single-request generate_from_params;
-  * mid-flight join/evict leaves untouched slots' token streams
-    bitwise-stable;
-  * steady-state serving uses exactly 2 cached executables (one prefill
-    bucket + one decode) — joins, evicts and sampling-param changes must
-    not re-trace;
-plus scheduler backpressure, deadlines, the stop-condition matrix, metrics
-sanity, and this PR's generation.py satellites (validation parity, traced
-temperature/top_p, stop_token_ids).
+Gates here:
+  * steady-state serving reuses its cached executables (one fused step a
+    dispatch shape) — joins, evicts and sampling-param changes must not
+    re-trace, a rebuilt engine over warm shapes traces nothing;
+  * scheduler backpressure, deadlines, streaming callbacks, cancellation,
+    result hand-off, impossible requests, metrics sanity;
+  * the entry points (Layer, functional params, inference handoff) and the
+    generation.py satellites (validation parity, traced temperature/top_p,
+    stop_token_ids).
 """
 import time
 
@@ -41,10 +41,6 @@ def _params():
 def _engine(**kw):
     kw.setdefault("num_slots", 3)
     kw.setdefault("max_seq_len", 96)
-    kw.setdefault("prefill_buckets", (16,))
-    # this suite gates the POOLED (PR 5 parity-baseline) layout; the paged
-    # layout has its own mirror suite in test_paged_serving.py
-    kw.setdefault("kv_layout", "pooled")
     return serving.Engine(params=_params(), config=CFG, **kw)
 
 
@@ -74,71 +70,21 @@ def _mixed_requests(n, rng, **kw):
 
 
 # ---------------------------------------------------------------------------
-# engine correctness gate
-
-
-def test_greedy_bitwise_parity_mixed_lengths():
-    eng = _engine()
-    reqs = _mixed_requests(7, np.random.default_rng(0))
-    results = eng.run(reqs)
-    for r in reqs:
-        got = results[r.request_id].tokens
-        assert got == _ref_tokens(r.prompt, r.max_new_tokens), \
-            f"request {r.request_id} diverged from single-request decode"
-        assert results[r.request_id].finish_reason == serving.LENGTH
-
-
-def test_admission_order_invariance():
-    """The same request set in two different submission orders produces the
-    same per-request tokens (slot assignment is irrelevant to output)."""
-    rng = np.random.default_rng(1)
-    prompts = [rng.integers(0, CFG.vocab_size, int(rng.integers(3, 14)))
-               for _ in range(6)]
-    outs = []
-    for order in (range(6), reversed(range(6))):
-        eng = _engine(num_slots=2)
-        reqs = [serving.Request(prompts[i], max_new_tokens=6) for i in order]
-        results = eng.run(reqs)
-        outs.append({tuple(r.prompt.tolist()): results[r.request_id].tokens
-                     for r in reqs})
-    assert outs[0] == outs[1]
-
-
-def test_midflight_join_and_evict_keep_slots_bitwise_stable():
-    """A long-running request's stream must be untouched by other requests
-    joining mid-flight and by a neighbor slot being evicted."""
-    eng = _engine(num_slots=3)
-    long_req = serving.Request(np.arange(2, 9), max_new_tokens=24)
-    victim = serving.Request(np.arange(30, 40), max_new_tokens=24)
-    eng.submit(long_req)
-    eng.submit(victim)
-    for _ in range(4):                      # both running, mid-flight
-        eng.step()
-    joiners = _mixed_requests(4, np.random.default_rng(2))
-    for r in joiners:
-        eng.submit(r)                       # join while long_req decodes
-    eng.step()
-    eng.cancel(victim)                      # evict a live neighbor slot
-    results = eng.run()
-    assert results[victim.request_id].finish_reason == serving.CANCELLED
-    assert results[long_req.request_id].tokens == \
-        _ref_tokens(long_req.prompt, 24)
-    for r in joiners:
-        assert results[r.request_id].tokens == \
-            _ref_tokens(r.prompt, r.max_new_tokens)
+# executable gates
 
 
 def test_steady_state_exactly_two_executables():
-    """After warmup (one prefill bucket + one decode), joins/evicts and
-    sampling-param changes must reuse the cached executables: the trace
-    counters freeze. (num_slots=4 is unique in this suite: executables are
+    """After warmup (the fused step at [B, 1] and at the one chunk rung
+    [1, 16] of the default ladder), joins/evicts and sampling-param changes
+    must reuse the cached executables: the trace counter freezes.
+    (num_slots=4 with pages of 16 is unique in the suite: executables are
     shared ACROSS engines per shape, so only a fresh shape shows warmup
     traces after a counter reset.)"""
     profiler.reset_serving_counters()
     eng = _engine(num_slots=4)
     eng.run(_mixed_requests(3, np.random.default_rng(3)))   # warmup
     warm = profiler.serving_counters()
-    assert warm["prefill_traces"] == 1 and warm["decode_traces"] == 1
+    assert warm["paged_traces"] == 2
 
     # mixed greedy/sampled, swept sampling configs, joins + cancel
     rng = np.random.default_rng(4)
@@ -154,36 +100,35 @@ def test_steady_state_exactly_two_executables():
     eng.cancel(reqs[0] if reqs[0].state == serving.RUNNING else reqs[-1])
     eng.run()
     c = profiler.serving_counters()
-    assert c["prefill_traces"] == 1, "prefill re-traced in steady state"
-    assert c["decode_traces"] == 1, "decode re-traced in steady state"
-    assert c["prefill_calls"] > warm["prefill_calls"]
-    assert c["decode_steps"] > warm["decode_steps"]
+    assert c["paged_traces"] == 2, "fused step re-traced in steady state"
+    assert c["paged_steps"] > warm["paged_steps"]
+    assert c["chunk_steps"] > warm["chunk_steps"]
 
 
-def test_one_prefill_executable_per_bucket():
+def test_one_fused_step_trace_per_chunk_rung_used():
+    """A ladder of two rungs (pages of 16, prefill_chunk=32): the fused
+    step traces once a shape it is dispatched at, [B, 1], [1, 16] and
+    [1, 32], and a REBUILT engine over the warm shapes traces nothing.
+    (num_slots=5 with this ladder is unique in the suite.)"""
     profiler.reset_serving_counters()
-    eng = _engine(num_slots=5, prefill_buckets=(8, 32))  # unique shapes
-    eng.generate([np.arange(1, 6), np.arange(1, 21)], max_new_tokens=3)
-    c = profiler.serving_counters()
-    assert c["prefill_traces"] == 2     # one per bucket actually used
-    assert c["decode_traces"] == 1
-    # a REBUILT engine over the same shapes reuses the executables
-    eng2 = _engine(num_slots=5, prefill_buckets=(8, 32))
-    eng2.generate([np.arange(2, 7)], max_new_tokens=3)
-    c = profiler.serving_counters()
-    assert c["prefill_traces"] == 2 and c["decode_traces"] == 1
+    kw = dict(num_slots=5, page_size=16, prefill_chunk=32)
+    eng = _engine(**kw)
+    # 5 tokens ride the [1, 16] rung; 40 one [1, 32] chunk and a [1, 16] tail
+    eng.generate([np.arange(1, 6), np.arange(1, 41)], max_new_tokens=3)
+    assert eng._chunk_rungs == {16, 32}
+    assert profiler.serving_counters()["paged_traces"] == 3
+    eng2 = _engine(**kw)
+    eng2.generate([np.arange(2, 42)], max_new_tokens=3)
+    assert profiler.serving_counters()["paged_traces"] == 3
 
 
-def test_sampled_stream_matches_generate():
-    """Per-slot PRNG streams replicate generate's split-per-step stream, so
-    even SAMPLED requests match the single-request path exactly."""
-    eng = _engine()
-    prompt = np.array([5, 17, 33, 2, 9])
-    req = serving.Request(prompt, max_new_tokens=8, do_sample=True,
-                          temperature=0.8, top_p=0.9, seed=7)
-    res = eng.run([req])[req.request_id]
-    assert res.tokens == _ref_tokens(prompt, 8, do_sample=True,
-                                     temperature=0.8, top_p=0.9, seed=7)
+@pytest.mark.parametrize("name,value", [("kv_layout", "paged"),
+                                        ("prefill_buckets", (16,))])
+def test_the_pooled_layouts_arguments_are_gone(name, value):
+    """One KV layout: the constructor takes no layout and no bucket
+    ladder, not even the value that used to be the default."""
+    with pytest.raises(TypeError, match=name):
+        _engine(**{name: value})
 
 
 # ---------------------------------------------------------------------------
@@ -329,50 +274,13 @@ def test_cancel_queued_non_head_request():
 
 
 # ---------------------------------------------------------------------------
-# stop conditions
-
-
-def test_stop_condition_matrix():
-    prompt = np.array([3, 14, 15, 92])
-    free = _ref_tokens(prompt, 8)                 # unconstrained greedy
-    eng = _engine()
-
-    # scalar eos alias: stops at (and includes) the first eos
-    k = 3
-    r_eos = serving.Request(prompt, max_new_tokens=8, eos_token_id=free[k])
-    # stop_token_ids list: earliest of several stop ids wins
-    r_list = serving.Request(prompt, max_new_tokens=8,
-                             stop_token_ids=[free[5], free[2]])
-    # max_new_tokens cap
-    r_len = serving.Request(prompt, max_new_tokens=4)
-    results = eng.run([r_eos, r_list, r_len])
-
-    res = results[r_eos.request_id]
-    assert res.finish_reason == serving.STOP
-    assert res.tokens == free[:k + 1]
-    first_stop = min(free.index(free[5]), free.index(free[2]))
-    res = results[r_list.request_id]
-    assert res.finish_reason == serving.STOP
-    assert res.tokens == free[:first_stop + 1]
-    res = results[r_len.request_id]
-    assert res.finish_reason == serving.LENGTH
-    assert res.tokens == free[:4]
-
-    # max_new_tokens == 0 resolves immediately with the prompt unchanged
-    r0 = serving.Request(prompt, max_new_tokens=0)
-    res = eng.run([r0])[r0.request_id]
-    assert res.tokens == [] and res.finish_reason == serving.LENGTH
-    np.testing.assert_array_equal(res.sequence, prompt)
-    with pytest.raises(ValueError):
-        serving.Request(prompt, max_new_tokens=-1)
+# submission
 
 
 def test_submit_rejects_impossible_requests():
-    eng = _engine()                               # Smax=96, bucket 16
+    eng = _engine()                               # Smax=96
     with pytest.raises(ValueError):               # prompt+new > Smax
         eng.submit(serving.Request(np.arange(10), max_new_tokens=95))
-    with pytest.raises(ValueError):               # prompt > largest bucket
-        eng.submit(serving.Request(np.arange(20), max_new_tokens=2))
     with pytest.raises(ValueError):               # per-request top_k
         eng.submit(serving.Request(np.arange(4), max_new_tokens=2,
                                    do_sample=True, top_k=5))
@@ -409,19 +317,6 @@ def test_submit_rejects_impossible_requests():
             eng.submit(stale)
 
 
-def test_sampled_top_p_none_matches_generate():
-    """Sampled traffic WITHOUT a nucleus cut: the engine's traced
-    top_p=1.0 stand-in must be bitwise identical to generate's structural
-    top_p=None skip (float32 cumsum saturation used to mask tail tokens)."""
-    eng = _engine()
-    prompt = np.arange(3, 11)
-    req = serving.Request(prompt, max_new_tokens=12, do_sample=True,
-                          temperature=1.3, seed=11)   # top_p=None
-    res = eng.run([req])[req.request_id]
-    assert res.tokens == _ref_tokens(prompt, 12, do_sample=True,
-                                     temperature=1.3, seed=11)
-
-
 # ---------------------------------------------------------------------------
 # metrics
 
@@ -439,18 +334,18 @@ def test_metrics_sanity():
     assert c["ttft_p99"] >= c["ttft_p50"]
     assert 0 < c["occupancy"] <= 1.0
     assert c["tokens_per_s"] > 0
-    assert c["prefill_calls"] == 6
+    assert c["admitted"] == 6 and c["chunk_steps"] > 0
     for r in reqs:
         assert results[r.request_id].ttft > 0
         assert results[r.request_id].latency >= results[r.request_id].ttft
     assert "tokens/s" in profiler.serving_summary()
-    # prefill-only traffic (max_new_tokens=1) emits every token from the
-    # prefill executable — decode never runs, but the rate must still count
+    # prefill-only traffic (max_new_tokens=1) emits every token from a
+    # chunk dispatch, but the rate must still count
     profiler.reset_serving_counters()
     r1 = serving.Request(np.arange(1, 5), max_new_tokens=1)
     eng.run([r1])
     c = profiler.serving_counters()
-    assert c["tokens_out"] == 1 and c["decode_steps"] == 0
+    assert c["tokens_out"] == 1 and c["chunk_steps"] == 1
     assert c["tokens_per_s"] > 0
 
 
@@ -465,8 +360,8 @@ def test_engine_from_layer_matches_model_generate():
     prompt = np.array([[3, 14, 15, 92]], np.int64)
     want = np.asarray(model.generate(paddle.to_tensor(prompt),
                                      max_new_tokens=6).numpy())[0, 4:]
-    eng = serving.Engine(model, num_slots=2, max_seq_len=64,
-                         prefill_buckets=(8,))
+    eng = serving.Engine(model, num_slots=2, max_seq_len=64, page_size=8,
+                         prefill_chunk=8)
     res = eng.generate([prompt[0]], max_new_tokens=6)[0]
     assert res.tokens == want.tolist()
 
@@ -490,7 +385,7 @@ def test_head_major_params_serve_bitwise():
         params_hm, prompt[None], cfg_hm, max_new_tokens=6)._data)
     assert got[0, 4:].tolist() == want
     eng = serving.Engine(params=params_hm, config=cfg_hm, num_slots=2,
-                         max_seq_len=64, prefill_buckets=(8,))
+                         max_seq_len=64, page_size=8, prefill_chunk=8)
     res = eng.generate([prompt], max_new_tokens=6)[0]
     assert res.tokens == want
 
@@ -498,7 +393,7 @@ def test_head_major_params_serve_bitwise():
 def test_inference_serve_handoff():
     from paddle_tpu import inference
     eng = inference.serve(params=_params(), config=CFG, num_slots=2,
-                          max_seq_len=64, prefill_buckets=(8,))
+                          max_seq_len=64, page_size=8, prefill_chunk=8)
     prompt = np.array([7, 8, 9])
     res = eng.generate([prompt], max_new_tokens=4)[0]
     assert res.tokens == _ref_tokens(prompt, 4)
@@ -514,7 +409,8 @@ def test_predictor_serve_handoff(tmp_path):
     inference.save_inference_model(prefix, model,
                                    [InputSpec([1, 8], "int64", "ids")])
     pred = inference.load_inference_model(prefix)
-    eng = pred.serve(CFG, num_slots=2, max_seq_len=64, prefill_buckets=(8,))
+    eng = pred.serve(CFG, num_slots=2, max_seq_len=64, page_size=8,
+                     prefill_chunk=8)
     prompt = np.array([[3, 14, 15, 92]], np.int64)
     want = np.asarray(model.generate(paddle.to_tensor(prompt),
                                      max_new_tokens=6).numpy())[0, 4:]
@@ -626,22 +522,3 @@ def test_stop_token_ids_generalizes_eos():
         _params(), np.array([[1, 2]]), CFG, max_new_tokens=6,
         stop_token_ids=[3, 5]).numpy())
     assert d.shape == (1, 8)
-
-
-# ---------------------------------------------------------------------------
-# smoke-bench gate (slow: tier-1 skips it; the quick ladder runs in CI via
-# the tool itself)
-
-
-@pytest.mark.slow
-def test_smoke_bench_continuous_beats_static():
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "tools_serving_smoke",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tools_serving_smoke.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    out = mod.run_ladder(quick=True)
-    assert out[-1]["speedup"] >= 1.5

@@ -45,17 +45,12 @@ def _params():
     return _PARAMS
 
 
-def _engine(layout="paged", **kw):
+def _engine(**kw):
     kw.setdefault("max_seq_len", 96)
-    if layout == "paged":
-        kw.setdefault("num_slots", 9)   # a batch shape no trace gate owns
-        kw.setdefault("page_size", 8)
-        kw.setdefault("prefill_chunk", 16)
-    else:
-        kw.setdefault("num_slots", 3)
-        kw.setdefault("prefill_buckets", (16, 32))
-    return serving.Engine(params=_params(), config=CFG, kv_layout=layout,
-                          **kw)
+    kw.setdefault("num_slots", 9)   # a batch shape no trace gate owns
+    kw.setdefault("page_size", 8)
+    kw.setdefault("prefill_chunk", 16)
+    return serving.Engine(params=_params(), config=CFG, **kw)
 
 
 def _requests(n=5, seed=3):
@@ -73,17 +68,19 @@ def _clean():
     tracing.clear()
 
 
-def _warm(layout):
+def _warm(**kw):
     """Compile the file's executables, so that a timed run holds no
     compilation (a compile lands in one feed phase and proves nothing)."""
-    _engine(layout).run(_requests())
+    _engine(**kw).run(_requests())
     profiler.reset_serving_counters()
 
 
-@pytest.mark.parametrize("layout", ["paged", "pooled"])
-def test_phases_are_disjoint_and_sum_to_the_step(layout):
-    _warm(layout)
-    eng = _engine(layout)
+@pytest.mark.parametrize("prefill_chunk", [16, 32])
+def test_phases_are_disjoint_and_sum_to_the_step(prefill_chunk):
+    # pages of 16: a chunk ladder of one rung, and of two
+    kw = {"page_size": 16, "prefill_chunk": prefill_chunk}
+    _warm(**kw)
+    eng = _engine(**kw)
     eng.run(_requests())
     c = profiler.serving_counters()
     for k in PHASES + ("step_s",):
@@ -127,7 +124,7 @@ def test_prefill_time_ends_after_the_fetch():
     fetch of the keys: ``prefill_time_s``, ``wait_s`` and the
     ``prefill_chunk`` span hold that wait. (``prefill_time_s`` read the
     enqueue alone before: near 0 here.)"""
-    _warm("paged")
+    _warm()
     delay = 0.05
     eng = _engine(trace=True)
     real = eng._paged_step

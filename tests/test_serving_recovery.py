@@ -4,8 +4,8 @@ with request requeue, and the elastic ServingSupervisor.
 Gates:
   * kill-and-resume of an engine with in-flight requests yields bitwise
     identical per-request outputs vs an uninterrupted run — greedy AND
-    sampled, on BOTH kv layouts, including requests caught mid-chunked-
-    prefill and prefix-shared siblings — with the snapshot round-tripped
+    sampled, with the prefix cache on and off, including requests caught
+    mid-chunked-prefill and prefix-shared siblings — with the snapshot round-tripped
     through the hardened CheckpointManager (CRC manifest on disk);
   * post-restore steady state reuses the existing executables: the trace
     counters do not move across snapshot/restore;
@@ -47,16 +47,12 @@ def _params():
     return _PARAMS
 
 
-def _engine(layout="paged", **kw):
+def _engine(**kw):
     kw.setdefault("num_slots", 3)
     kw.setdefault("max_seq_len", 96)
-    if layout == "paged":
-        kw.setdefault("page_size", 8)
-        kw.setdefault("prefill_chunk", 8)
-    else:
-        kw.setdefault("prefill_buckets", (48,))
-    return serving.Engine(params=_params(), config=CFG, kv_layout=layout,
-                          **kw)
+    kw.setdefault("page_size", 8)
+    kw.setdefault("prefill_chunk", 8)
+    return serving.Engine(params=_params(), config=CFG, **kw)
 
 
 def _ref_tokens(prompt, max_new, **kw):
@@ -118,23 +114,23 @@ def _golden(reqs):
 # kill / resume bitwise gates
 
 
-@pytest.mark.parametrize("layout,sampled,scenario", [
-    ("pooled", False, "plain"),
-    ("pooled", True, "plain"),
-    ("paged", False, "plain"),
-    ("paged", True, "plain"),
-    ("paged", False, "prefix-shared"),
-    ("paged", True, "prefix-shared"),
-    ("paged", False, "chunk-mid-prefill"),
-    ("paged", True, "chunk-mid-prefill"),
+@pytest.mark.parametrize("prefix_cache,sampled,scenario", [
+    (False, False, "plain"),
+    (False, True, "plain"),
+    (True, False, "plain"),
+    (True, True, "plain"),
+    (True, False, "prefix-shared"),
+    (True, True, "prefix-shared"),
+    (True, False, "chunk-mid-prefill"),
+    (True, True, "chunk-mid-prefill"),
 ])
-def test_kill_resume_bitwise(ckpt_dir, layout, sampled, scenario):
+def test_kill_resume_bitwise(ckpt_dir, prefix_cache, sampled, scenario):
     """Mid-flight kill + cold restart from a disk snapshot resumes every
     request token-for-token identically to an uninterrupted run."""
     reqs, steps = _requests(scenario, sampled)
     golden = _golden(reqs)
 
-    eng = _engine(layout)
+    eng = _engine(prefix_cache=prefix_cache)
     mgr = CheckpointManager(ckpt_dir, async_save=False,
                             site="serving_snapshot")
     eng.attach_checkpoint(mgr, every=0)
@@ -151,17 +147,16 @@ def test_kill_resume_bitwise(ckpt_dir, layout, sampled, scenario):
     pre = eng.pop_results()             # results delivered before the kill
     del eng                             # the "kill": engine object gone
 
-    restored = _engine(layout)
+    restored = _engine(prefix_cache=prefix_cache)
     snap = mgr.restore()                # CRC-verified read from disk
     restored.load_state_dict(snap)
     results = restored.run()
     results.update(pre)
     for r in reqs:
         assert results[r.request_id].tokens == golden[r.request_id], \
-            f"{layout}/{scenario} request {r.request_id} diverged after resume"
-    if layout == "paged":
-        bal = restored.pool.balance()
-        assert bal["conserved"] and bal["refcounts_accounted"], bal
+            f"{scenario} request {r.request_id} diverged after resume"
+    bal = restored.pool.balance()
+    assert bal["conserved"] and bal["refcounts_accounted"], bal
 
 
 def test_kill_resume_bitwise_speculative(ckpt_dir):
@@ -174,7 +169,7 @@ def test_kill_resume_bitwise_speculative(ckpt_dir):
     reqs, _ = _requests("prefix-shared", sampled=True)
     golden = _golden(reqs)
 
-    eng = _engine("paged", speculate_k=4)
+    eng = _engine(speculate_k=4)
     mgr = CheckpointManager(ckpt_dir, async_save=False,
                             site="serving_snapshot")
     eng.attach_checkpoint(mgr, every=0)
@@ -191,7 +186,7 @@ def test_kill_resume_bitwise_speculative(ckpt_dir):
     pre = eng.pop_results()
     del eng
 
-    restored = _engine("paged", speculate_k=4)
+    restored = _engine(speculate_k=4)
     restored.load_state_dict(mgr.restore())
     results = restored.run()
     results.update(pre)
@@ -205,13 +200,13 @@ def test_kill_resume_bitwise_speculative(ckpt_dir):
 def test_restore_does_not_retrace():
     """A restored engine re-dispatches the warm executables: the paged
     fused-step trace counter is IDENTICAL before the snapshot and after
-    the resumed run (and the pooled decode counter likewise)."""
+    the resumed run."""
     profiler.reset_serving_counters()
     # num_slots=6 is UNIQUE across the suite: executables are shared per
     # shape process-wide, so borrowing another file's batch shape (e.g.
     # test_paged_serving's num_slots=5 warmup gate) would make this — or
     # that — test's warmup trace count order-dependent
-    eng = _engine("paged", num_slots=6)
+    eng = _engine(num_slots=6)
     rng = np.random.default_rng(3)
     eng.run([serving.Request(rng.integers(0, 97, 11), max_new_tokens=4),
              serving.Request(rng.integers(0, 97, 19), max_new_tokens=5)])
@@ -224,7 +219,7 @@ def test_restore_does_not_retrace():
         eng.step()
     state = eng.state_dict()
     del eng
-    restored = _engine("paged", num_slots=6).load_state_dict(state)
+    restored = _engine(num_slots=6).load_state_dict(state)
     restored.run()
     c = profiler.serving_counters()
     assert c["paged_traces"] == warm["paged_traces"], \
@@ -237,7 +232,7 @@ def test_snapshot_carries_results_and_metrics():
     """Unpopped results ride the snapshot; restore_metrics=True carries
     the SLO ledger across a cold restart."""
     profiler.reset_serving_counters()
-    eng = _engine("paged")
+    eng = _engine()
     r1 = serving.Request(np.arange(1, 8), max_new_tokens=3)
     r2 = serving.Request(np.arange(11, 30), max_new_tokens=12)
     eng.submit(r1)
@@ -250,7 +245,7 @@ def test_snapshot_carries_results_and_metrics():
     del eng
 
     profiler.reset_serving_counters()   # simulate a cold process
-    restored = _engine("paged").load_state_dict(state, restore_metrics=True)
+    restored = _engine().load_state_dict(state, restore_metrics=True)
     assert profiler.serving_counters()["tokens_out"] == tokens_then
     results = restored.run()
     assert results[r1.request_id].tokens == _ref_tokens(np.arange(1, 8), 3)
@@ -258,14 +253,31 @@ def test_snapshot_carries_results_and_metrics():
 
 
 def test_snapshot_meta_mismatch_rejected():
-    eng = _engine("paged")
+    eng = _engine()
     state = eng.state_dict()
-    other = _engine("paged", num_slots=2)
+    other = _engine(num_slots=2)
     with pytest.raises(ValueError, match="does not match"):
         other.load_state_dict(state)
-    pooled = _engine("pooled")
+
+
+# what the parent of the PR that removed the pooled layout wrote for a paged
+# engine, key for key: snapshots load across that PR in both directions
+PAGED_META_KEYS = {"kv_layout", "num_slots", "max_seq_len", "top_k",
+                   "params_version", "cfg", "weight_dtype", "kv_dtype",
+                   "adapters", "page_size", "prefill_chunk", "num_pages"}
+
+
+def test_pooled_snapshot_refused():
+    """The meta keeps its ``kv_layout`` key with the one value the program
+    has; a snapshot that says ``"pooled"`` is input from outside it and is
+    refused."""
+    eng = _engine()
+    state = eng.state_dict()
+    assert set(state["meta"]) == PAGED_META_KEYS
+    assert state["meta"]["kv_layout"] == "paged"
+    state["meta"] = dict(state["meta"], kv_layout="pooled")
     with pytest.raises(ValueError, match="does not match"):
-        pooled.load_state_dict(state)
+        _engine().load_state_dict(state)
 
 
 # ---------------------------------------------------------------------------
@@ -294,7 +306,7 @@ def test_preemption_drain_requeues_and_cold_restart(ckpt_dir):
     """Deferred preemption at a step boundary: snapshot flushed with slots
     INTACT (cold restart resumes mid-decode bitwise), in-flight requests
     requeued with their original arrival, run() unwinds with Preempted."""
-    eng = _engine("paged")
+    eng = _engine()
     mgr = CheckpointManager(ckpt_dir, async_save=False,
                             site="serving_snapshot")
     eng.attach_checkpoint(mgr, every=0)
@@ -315,7 +327,7 @@ def test_preemption_drain_requeues_and_cold_restart(ckpt_dir):
     assert a.tokens == []               # replay re-emits deterministically
     assert eng.stopped
 
-    restored = _engine("paged")
+    restored = _engine()
     restored.load_state_dict(mgr.restore())
     res = restored.run()
     assert res[a.request_id].tokens == _ref_tokens(np.arange(1, 20), 12)
@@ -324,7 +336,7 @@ def test_preemption_drain_requeues_and_cold_restart(ckpt_dir):
 
 
 def test_submit_after_drain_raises_engine_stopped():
-    eng = _engine("paged")
+    eng = _engine()
     a = serving.Request(np.arange(1, 10), max_new_tokens=8)
     b = serving.Request(np.arange(20, 30), max_new_tokens=8)
     eng.submit(a)
@@ -339,7 +351,7 @@ def test_submit_after_drain_raises_engine_stopped():
     assert set(ei.value.requeued) == {a.request_id, b.request_id}
     assert eng.step() is False          # dead state is never mutated
     # the drained requests serve to completion elsewhere, bitwise
-    other = _engine("paged")
+    other = _engine()
     for r in drained:
         assert other.requeue(r)
     res = other.run()
@@ -348,7 +360,7 @@ def test_submit_after_drain_raises_engine_stopped():
 
 
 def test_queue_full_error_carries_backoff_hints():
-    eng = _engine("paged", max_queue=2)
+    eng = _engine(max_queue=2)
     eng.submit(serving.Request(np.arange(1, 5), max_new_tokens=2))
     eng.submit(serving.Request(np.arange(1, 6), max_new_tokens=2))
     with pytest.raises(serving.QueueFullError) as ei:
@@ -361,7 +373,7 @@ def test_requeue_preserves_fcfs_and_cancel_race():
     """Requeue inserts at the ORIGINAL arrival position (FCFS survives a
     drain), and a cancel landing between drain and requeue is race-safe:
     the request resolves cancelled and the requeue skips it."""
-    src = _engine("paged")
+    src = _engine()
     early = serving.Request(np.arange(1, 8), max_new_tokens=4)
     mid = serving.Request(np.arange(2, 9), max_new_tokens=4)
     src.submit(early)
@@ -369,7 +381,7 @@ def test_requeue_preserves_fcfs_and_cancel_race():
     drained = src.drain()
     assert drained == [early, mid]      # arrival order
 
-    dst = _engine("paged")
+    dst = _engine()
     late = dst.submit(serving.Request(np.arange(3, 10), max_new_tokens=4))
     # cancel `mid` while it sits between drain and requeue
     src.cancel(mid)
@@ -394,7 +406,7 @@ def test_snapshot_io_error_retried_and_crc_fallback(ckpt_dir):
     hardened path; a corrupted newest snapshot quarantines and restore
     falls back to the previous good one — which still resumes bitwise."""
     from paddle_tpu.incubate.checkpoint import ckpt_counters
-    eng = _engine("paged")
+    eng = _engine()
     mgr = CheckpointManager(ckpt_dir, async_save=False, retries=2,
                             retry_backoff=0.01, site="serving_snapshot")
     eng.attach_checkpoint(mgr, every=0)
@@ -417,7 +429,7 @@ def test_snapshot_io_error_retried_and_crc_fallback(ckpt_dir):
               "r+b") as f:
         f.seek(-8, 2)
         f.write(b"\x00" * 8)
-    restored = _engine("paged")
+    restored = _engine()
     restored.load_state_dict(mgr.restore())
     assert mgr.last_restored_step < newest
     assert ckpt_counters()["quarantined"] - before["quarantined"] == 1
@@ -442,8 +454,7 @@ def _supervisor_traffic(n=6, seed=0):
 
 def _factory():
     return serving.Engine(params=_params(), config=CFG, num_slots=3,
-                          max_seq_len=96, page_size=8, prefill_chunk=8,
-                          kv_layout="paged")
+                          max_seq_len=96, page_size=8, prefill_chunk=8)
 
 
 def test_supervisor_kill_one_replica_zero_dropped(ckpt_dir):
@@ -587,7 +598,7 @@ def test_warm_restart_reuses_manager_without_insta_drain(ckpt_dir):
     """A preemption leaves mgr.preempted set; reattaching the SAME manager
     for a warm in-process restart must re-arm it (cleared on hook
     install), not preempt-drain the restored engine on its first step."""
-    eng = _engine("paged")
+    eng = _engine()
     mgr = CheckpointManager(ckpt_dir, async_save=False,
                             site="serving_snapshot")
     eng.attach_checkpoint(mgr, every=0)
@@ -599,7 +610,7 @@ def test_warm_restart_reuses_manager_without_insta_drain(ckpt_dir):
     with pytest.raises(Preempted):
         eng.run()
     assert mgr.preempted                   # the handled preemption's residue
-    warm = _engine("paged").attach_checkpoint(mgr, every=0)
+    warm = _engine().attach_checkpoint(mgr, every=0)
     warm.load_state_dict(mgr.restore())
     res = warm.run()                       # completes; no second Preempted
     assert res[a.request_id].tokens == _ref_tokens(a.prompt, 10)
@@ -612,16 +623,16 @@ def test_respawn_snapshot_ids_stay_monotonic(ckpt_dir):
     keeps resurrecting pre-restart state."""
     mgr = CheckpointManager(ckpt_dir, keep_last_n=2, async_save=False,
                             site="serving_snapshot")
-    eng = _engine("paged").attach_checkpoint(mgr, every=2)
+    eng = _engine().attach_checkpoint(mgr, every=2)
     eng.run([serving.Request(np.arange(1, 10), max_new_tokens=10)])
     stale = mgr.latest_step()
     assert stale is not None and stale >= 2
 
-    fresh = _engine("paged").attach_checkpoint(mgr, every=2)
+    fresh = _engine().attach_checkpoint(mgr, every=2)
     assert fresh._step_count >= stale
     fresh.run([serving.Request(np.arange(20, 30), max_new_tokens=10)])
     assert mgr.latest_step() > stale       # new snapshot survived _prune
-    restored = _engine("paged")
+    restored = _engine()
     restored.load_state_dict(mgr.restore())
     assert restored._step_count > stale    # restores the POST-restart state
 
@@ -680,7 +691,7 @@ def test_cross_host_restore_reanchors_deadlines():
     ahead of the local clock) must restore with deadlines still live —
     outage is measured by the wall-clock anchor, not perf skew."""
     for skew in (-864000.0, +864000.0):
-        eng = _engine("paged")
+        eng = _engine()
         a = serving.Request(np.arange(1, 20), max_new_tokens=10,
                             deadline_s=120.0)
         eng.submit(a)
@@ -697,7 +708,7 @@ def test_cross_host_restore_reanchors_deadlines():
                 if spec[k] is not None:
                     spec[k] += skew
         del eng
-        restored = _engine("paged").load_state_dict(state)
+        restored = _engine().load_state_dict(state)
         res = restored.run()
         assert res[a.request_id].finish_reason == serving.LENGTH, skew
         assert res[a.request_id].tokens == _ref_tokens(a.prompt, 10), skew
@@ -707,7 +718,7 @@ def test_sigterm_during_final_step_still_flushes(ckpt_dir):
     """A preemption notice landing during the LAST fused step (step()
     returns False right after) must still flush + raise Preempted — not
     return normally and have the next hook install erase the notice."""
-    eng = _engine("paged")
+    eng = _engine()
     mgr = CheckpointManager(ckpt_dir, async_save=False,
                             site="serving_snapshot")
     eng.attach_checkpoint(mgr, every=0)
@@ -720,7 +731,7 @@ def test_sigterm_during_final_step_still_flushes(ckpt_dir):
     with pytest.raises(Preempted):
         eng.run()
     assert mgr.latest_step() is not None   # boundary snapshot flushed
-    restored = _engine("paged").load_state_dict(mgr.restore())
+    restored = _engine().load_state_dict(mgr.restore())
     res = restored.run()
     res.update(restored.pop_results())
     assert res[a.request_id].tokens == _ref_tokens(a.prompt, 4)
@@ -734,7 +745,7 @@ def test_supervisor_spill_does_not_inflate_ledger():
     sup = ServingSupervisor(
         lambda: serving.Engine(params=_params(), config=CFG, num_slots=3,
                                max_seq_len=96, page_size=8, prefill_chunk=8,
-                               kv_layout="paged", max_queue=1),
+                               max_queue=1),
         num_replicas=2)
     sup.submit(serving.Request(np.arange(1, 5), max_new_tokens=2))
     sup.submit(serving.Request(np.arange(2, 6), max_new_tokens=2))
@@ -757,14 +768,14 @@ def test_requeued_request_contributes_one_ttft_sample():
     be too)."""
     profiler.reset_serving_counters()
     from paddle_tpu.serving import metrics as smetrics
-    eng = _engine("paged")
+    eng = _engine()
     a = serving.Request(np.arange(1, 10), max_new_tokens=10)
     eng.submit(a)
     for _ in range(3):
         eng.step()
     assert a.tokens                        # first token emitted (1 sample)
     drained = eng.drain()
-    dst = _engine("paged")
+    dst = _engine()
     for q in drained:
         dst.requeue(q)
     dst.run()
@@ -783,7 +794,7 @@ def test_rolling_restart_sustained_mixed_traffic(ckpt_dir):
     from paddle_tpu.serving import metrics as smetrics
 
     sup = ServingSupervisor(
-        lambda: _engine("paged", max_queue=64), num_replicas=2,
+        lambda: _engine(max_queue=64), num_replicas=2,
         snapshot_dir=ckpt_dir)
     rng = np.random.default_rng(23)
     reqs, i = [], 0

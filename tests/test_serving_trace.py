@@ -46,18 +46,13 @@ def _params():
     return _PARAMS
 
 
-def _engine(layout="paged", **kw):
+def _engine(**kw):
     kw.setdefault("trace", True)
     kw.setdefault("max_seq_len", 96)
-    if layout == "paged":
-        kw.setdefault("num_slots", 4)   # unique batch shape for this file
-        kw.setdefault("page_size", 8)
-        kw.setdefault("prefill_chunk", 16)
-    else:
-        kw.setdefault("num_slots", 1)
-        kw.setdefault("prefill_buckets", (16,))
-    return serving.Engine(params=_params(), config=CFG, kv_layout=layout,
-                          **kw)
+    kw.setdefault("num_slots", 4)   # unique batch shape for this file
+    kw.setdefault("page_size", 8)
+    kw.setdefault("prefill_chunk", 16)
+    return serving.Engine(params=_params(), config=CFG, **kw)
 
 
 def _spans(rec, name):
@@ -82,11 +77,11 @@ def _clean_traces():
 
 
 def test_solo_request_trace_reconciles_exactly():
-    """One request on a one-slot pooled engine: the span timeline IS the
+    """One request on a one-slot engine: the span timeline IS the
     request's latency story — queue starts at submit_t, first_token lands
     at the TTFT stamp, deliver at finish_t, and span durations tile the
     window."""
-    eng = _engine("pooled")
+    eng = _engine(num_slots=1)
     req = serving.Request(np.arange(1, 10), max_new_tokens=5)
     results = eng.run([req])
     res = results[req.request_id]
@@ -97,7 +92,7 @@ def test_solo_request_trace_reconciles_exactly():
     assert rec["finish_reason"] == serving.LENGTH
 
     q = _span(rec, "queue")
-    pf = _span(rec, "prefill")
+    pf = _span(rec, "prefill_chunk")
     ft = _span(rec, "first_token")
     d = _span(rec, "deliver")
     decs = _spans(rec, "decode_step")
@@ -109,15 +104,19 @@ def test_solo_request_trace_reconciles_exactly():
     assert (ft["t0"] - q["t0"]) == res.ttft == rec["ttft"]
     assert (d["t0"] - q["t0"]) == res.latency == rec["latency"]
 
-    # structure: prefill emits token #1, decode emits the other 4
-    assert pf["bucket"] == 16 and pf["tokens"] == 9
+    # structure: the prompt's one chunk emits token #1, decode the other 4
+    assert pf["chunk"] == 16 and pf["tokens"] == 9
     assert len(decs) == 4
     # TTFT decomposes into its trace: the first token lands inside the
     # prefill+queue window (the emission timestamp follows the dispatch)
     assert q["t1"] <= pf["t0"]
     assert pf["t0"] <= ft["t0"]
+    # the prompt's last chunk and the first decode dispatch share a
+    # boundary, and a decode span covers its whole boundary (the stream's
+    # inter-token gap): the chunk lies inside the first decode span
+    assert decs[0]["t0"] <= pf["t0"] and pf["t1"] <= decs[0]["t1"]
     # the timeline is ordered and inside [submit, finish]
-    ts = [q, pf] + decs + [d]
+    ts = [q] + decs + [d]
     for a, b in zip(ts, ts[1:]):
         assert a["t1"] <= b["t0"] + 1e-9
         assert req.submit_t <= a["t0"] and a["t1"] <= req.finish_t + 1e-9
@@ -133,7 +132,7 @@ def test_paged_chunked_prefill_spans():
     16-chunk plus one 8-rung tail of 4 valid tokens — the trace shows
     exactly that, plus one decode span per emitted token after the
     first."""
-    eng = _engine("paged", num_slots=2)
+    eng = _engine(num_slots=2)
     req = serving.Request(np.arange(1, 21), max_new_tokens=3)
     eng.run([req])
     rec = tracing.traces()[-1]
@@ -150,7 +149,7 @@ def test_paged_chunked_prefill_spans():
 
 
 def test_prefix_hit_recorded_in_trace():
-    eng = _engine("paged", num_slots=3)
+    eng = _engine(num_slots=3)
     prompt = np.arange(1, 18)                        # 17 tokens: 2 full pages
     a = serving.Request(prompt.copy(), max_new_tokens=2)
     eng.run([a])
@@ -182,12 +181,12 @@ def test_tracing_adds_no_executables():
     # memoizes on it, so this gate owns a fresh executable set and the
     # absolute trace count is immune to which suites ran before
     kw = dict(page_size=4, prefill_chunk=8)
-    cold = _engine("paged", trace=False, **kw)
+    cold = _engine(trace=False, **kw)
     burst(cold, 5)
     warm = profiler.serving_counters()
     assert warm["paged_traces"] == 2        # [4,1] decode + one [1,8] rung
 
-    traced = _engine("paged", trace=True, **kw)
+    traced = _engine(trace=True, **kw)
     burst(traced, 6)
     c = profiler.serving_counters()
     assert c["paged_traces"] == warm["paged_traces"], \
@@ -195,25 +194,15 @@ def test_tracing_adds_no_executables():
     assert c["copy_traces"] == warm["copy_traces"]
     assert len(tracing.traces()) == 6
 
-    # pooled two-executable discipline likewise
-    pooled_cold = _engine("pooled", trace=False, num_slots=2)
-    burst(pooled_cold, 3)
-    warm = profiler.serving_counters()
-    pooled = _engine("pooled", trace=True, num_slots=2)
-    burst(pooled, 4)
-    c = profiler.serving_counters()
-    assert c["prefill_traces"] == warm["prefill_traces"]
-    assert c["decode_traces"] == warm["decode_traces"]
-
 
 def test_flag_routes_engine_default():
     paddle.set_flags({"FLAGS_serving_trace": True})
     try:
-        eng = _engine("pooled", trace=None)
+        eng = _engine(trace=None)
         assert eng.trace_enabled
     finally:
         paddle.set_flags({"FLAGS_serving_trace": False})
-    eng = _engine("pooled", trace=None)
+    eng = _engine(trace=None)
     assert not eng.trace_enabled
     req = serving.Request([1, 2, 3], max_new_tokens=1)
     eng.run([req])
@@ -226,7 +215,7 @@ def test_flag_routes_engine_default():
 
 
 def test_trace_survives_kill_and_resume(tmp_path):
-    eng = _engine("paged", num_slots=2)
+    eng = _engine(num_slots=2)
     mgr = CheckpointManager(os.fspath(tmp_path), async_save=False,
                             site="serving_snapshot")
     eng.attach_checkpoint(mgr, every=0)
@@ -242,7 +231,7 @@ def test_trace_survives_kill_and_resume(tmp_path):
     eng.save_snapshot()
     del eng                              # the kill
 
-    restored = _engine("paged", num_slots=2, trace=False)  # flag need not
+    restored = _engine(num_slots=2, trace=False)  # flag need not
     restored.load_state_dict(mgr.restore())                # be on to resume
     results = restored.run()
     for r in reqs:
@@ -269,7 +258,7 @@ def test_trace_survives_kill_and_resume(tmp_path):
 
 
 def test_drain_requeue_hop_recorded():
-    eng = _engine("paged", num_slots=2)
+    eng = _engine(num_slots=2)
     reqs = [serving.Request(np.arange(1, 10), max_new_tokens=6)
             for _ in range(2)]
     for r in reqs:
@@ -289,7 +278,7 @@ def test_supervisor_replay_hop_recorded(tmp_path):
     profiler.reset_serving_counters()
 
     def factory():
-        return _engine("paged", num_slots=2)
+        return _engine(num_slots=2)
 
     sup = ServingSupervisor(factory, num_replicas=2)
     rng = np.random.default_rng(5)
@@ -318,7 +307,7 @@ def test_perfetto_and_jsonl_export():
     jsonl = tempfile.mktemp(suffix=".jsonl")
     sink = obs.JsonlTraceSink(jsonl)
     try:
-        eng = _engine("pooled", num_slots=2)
+        eng = _engine(num_slots=2)
         reqs = [serving.Request(np.arange(1, 8), max_new_tokens=3)
                 for _ in range(3)]
         eng.run(reqs)
@@ -353,7 +342,7 @@ def test_perfetto_and_jsonl_export():
 def test_trace_ring_is_bounded():
     paddle.set_flags({"FLAGS_trace_buffer": 8})
     try:
-        eng = _engine("pooled", num_slots=2)
+        eng = _engine(num_slots=2)
         for i in range(12):
             eng.run([serving.Request([1, 2, 3], max_new_tokens=1)])
         assert len(tracing.traces()) == 8
@@ -380,7 +369,7 @@ def test_restore_metrics_semantics_documented_and_gated(tmp_path):
     saved = metrics.export_state()
     try:
         profiler.reset_serving_counters()
-        eng = _engine("paged", num_slots=2)
+        eng = _engine(num_slots=2)
         mgr = CheckpointManager(os.fspath(tmp_path), async_save=False,
                                 site="serving_snapshot")
         eng.attach_checkpoint(mgr, every=0)
@@ -397,7 +386,7 @@ def test_restore_metrics_semantics_documented_and_gated(tmp_path):
         assert profiler.recovery_counters()["requeued"] == 2
 
         # fresh-restore (default): live ledger kept, one restore bump
-        e1 = _engine("paged", num_slots=2, trace=False)
+        e1 = _engine(num_slots=2, trace=False)
         e1.load_state_dict(mgr.restore())
         c = profiler.recovery_counters()
         assert c["requeued"] == 2            # drain history NOT erased
@@ -406,7 +395,7 @@ def test_restore_metrics_semantics_documented_and_gated(tmp_path):
         # restore_metrics=True: ledger replaced by the snapshot's —
         # requeued back to its pre-drain value, never double-counted by
         # the resumed (slots-intact) run
-        e2 = _engine("paged", num_slots=2, trace=False)
+        e2 = _engine(num_slots=2, trace=False)
         e2.load_state_dict(mgr.restore(), restore_metrics=True)
         c = profiler.recovery_counters()
         assert c["requeued"] == 0
@@ -431,7 +420,7 @@ def test_supervisor_respawn_counts_once(tmp_path):
         profiler.reset_serving_counters()
 
         def factory():
-            return _engine("paged", num_slots=2, trace=False)
+            return _engine(num_slots=2, trace=False)
 
         sup = ServingSupervisor(factory, num_replicas=2,
                                 snapshot_dir=os.fspath(tmp_path),

@@ -59,7 +59,6 @@ def _engine(**kw):
     kw.setdefault("max_seq_len", 96)
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("kv_layout", "paged")
     params = kw.pop("params", None)
     return serving.Engine(params=params if params is not None else _params(),
                           config=CFG, **kw)
@@ -144,7 +143,7 @@ def test_deadline_boundary_unified():
     assert r.expired(105.0)          # the boundary instant counts
     assert r.expired(105.001)
     # scheduler.expire and admit use the same predicate
-    sched = serving.Scheduler((16,))
+    sched = serving.Scheduler()
     sched.submit(r)
     assert sched.expire(now=104.9) == []
     expired = sched.expire(now=105.0)
@@ -162,7 +161,7 @@ def _queued(prompt_start, cls="batch", tenant="default", t=None):
 
 
 def test_priority_admission_interactive_first():
-    sched = serving.Scheduler((16,), priority=True)
+    sched = serving.Scheduler(priority=True)
     be = _queued(1, "best_effort")
     ba = _queued(2, "batch")
     ia = _queued(3, "interactive")
@@ -177,7 +176,7 @@ def test_priority_admission_interactive_first():
 def test_wfq_tenant_fairness_and_weights():
     """Within a class, tenants round-robin: a flood from tenant A cannot
     starve tenant B; a weight-2 tenant gets two slots per rotation."""
-    sched = serving.Scheduler((16,), priority=True)
+    sched = serving.Scheduler(priority=True)
     a = [_queued(10 + i, tenant="A") for i in range(4)]
     b = [_queued(30 + i, tenant="B") for i in range(2)]
     for r in a[:2] + b[:1] + a[2:] + b[1:]:   # A,A,B,A,A,B arrival
@@ -185,7 +184,7 @@ def test_wfq_tenant_fairness_and_weights():
     order = sched._admission_order()
     assert order[:4] == [a[0], b[0], a[1], b[1]]  # interleaved
     # weights: A earns 2 pops per rotation
-    sched2 = serving.Scheduler((16,), priority=True,
+    sched2 = serving.Scheduler(priority=True,
                                tenant_weights={"A": 2})
     for r in a[:2] + b[:1] + a[2:] + b[1:]:
         sched2.submit(r)

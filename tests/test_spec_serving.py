@@ -57,7 +57,6 @@ def _engine(**kw):
     kw.setdefault("max_seq_len", 96)
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("kv_layout", "paged")
     return serving.Engine(params=_params(), config=CFG, **kw)
 
 
@@ -336,7 +335,7 @@ def test_trace_freeze_under_churn():
         res.update(eng.pop_results())
     c2 = profiler.serving_counters()
     for t in ("spec_draft_traces", "spec_verify_traces", "paged_traces",
-              "prefill_traces", "write_traces"):
+              "write_traces"):
         assert c2[t] == c1[t], f"{t} moved under churn: {c1[t]} -> {c2[t]}"
 
 
@@ -377,13 +376,6 @@ def test_plain_engine_unaffected():
 
 # ---------------------------------------------------------------------------
 # composition gates
-
-
-def test_speculate_requires_paged_layout():
-    with pytest.raises(ValueError, match="paged"):
-        serving.Engine(params=_params(), config=CFG, kv_layout="pooled",
-                       num_slots=2, max_seq_len=96, prefill_buckets=(16,),
-                       speculate_k=4)
 
 
 def test_speculate_requires_single_chip():

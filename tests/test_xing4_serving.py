@@ -136,7 +136,7 @@ def test_engine_serves_with_prefix_hit_and_cow_split(weights):
     tolerance."""
     profiler.reset_serving_counters()
     eng = _engine(weights)
-    assert eng.kv_layout == "paged" and eng.pool.num_pages > 0
+    assert eng.pool.num_pages > 0
     rng = np.random.default_rng(1)
     base = rng.integers(0, 256, 37).astype(np.int32)
     sib = np.concatenate([base[:24], rng.integers(0, 256, 9)]).astype(np.int32)
@@ -247,8 +247,8 @@ def test_sinkhorn_gives_rows_and_columns_that_sum_to_one():
     ({"quant": "int8"}, "quant"),
     ({"adapter_slots": 2}, "adapters"),
     ({"mp": 2}, "mp"),
-    ({"kv_layout": "pooled"}, "pooled"),
     ({"role": "prefill"}, "kv_transfer"),
+    ({"role": "decode"}, "kv_transfer"),
 ])
 def test_what_is_not_supported_raises_one_sentence(weights, kwargs, option):
     with pytest.raises(ValueError) as e:

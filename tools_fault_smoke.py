@@ -300,8 +300,7 @@ def _serving_fixture():
 
     def factory():
         return serving.Engine(params=params, config=cfg, num_slots=3,
-                              max_seq_len=96, page_size=8, prefill_chunk=8,
-                              kv_layout="paged")
+                              max_seq_len=96, page_size=8, prefill_chunk=8)
 
     def ref(prompt, n, **kw):
         out = np.asarray(generate_from_params(

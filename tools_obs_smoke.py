@@ -96,8 +96,8 @@ def serving_rung(verbose=True):
         cfg = _tiny_cfg()
         params = init_gpt_params(cfg, jax.random.key(0))
         eng = serving.Engine(params=params, config=cfg, num_slots=3,
-                             max_seq_len=96, kv_layout="paged",
-                             page_size=8, prefill_chunk=16)
+                             max_seq_len=96, page_size=8,
+                             prefill_chunk=16)
         rng = np.random.default_rng(0)
         reqs = [serving.Request(rng.integers(0, cfg.vocab_size, 12),
                                 max_new_tokens=4) for _ in range(6)]

@@ -1,22 +1,14 @@
-"""Serving smoke bench: continuous batching vs static whole-batch generate,
-and (run_paged_rung) the block-paged KV layout vs the pooled layout.
+"""Serving smoke rungs on XLA:CPU: each ``run_*_rung`` drives one serving
+feature (tensor-parallel, quantized, speculative, adapters, disaggregated)
+over a synthetic mixed-length workload — prompts of varying length,
+generation lengths skewed the way real traffic is (many short, a few long),
+Poisson interarrivals or a backlog — and prints one JSON line a rung plus a
+PASS/FAIL summary. The ``*-det`` modes are deterministic parity and
+dispatch-count rigs; a time printed here is XLA:CPU's, not a chip's.
 
-Synthetic-arrivals ladder (Poisson interarrivals) over a mixed-length
-workload — prompts of varying length, generation lengths skewed the way real
-traffic is (many short, a few long). The static baseline is what the repo
-had before `paddle_tpu.serving`: collect B arrived requests, pad prompts to
-one bucket, run ONE whole-batch `generate_from_params` for the worst-case
-max_new_tokens (so it keeps a single cached executable — the most generous
-static baseline), tokens available only when the whole batch finishes. The
-continuous engine admits at iteration boundaries and recycles a slot the
-moment its request finishes.
-
-Reported per rung: useful tokens/s, p50/p99 TTFT, wall time, speedup.
-Quick mode (default) runs one backlogged rung; --full runs the arrival-rate
-ladder. Gate: continuous batching >= 1.5x static tokens/s on the mixed
-workload (asserted by tests/test_serving.py::test_smoke_bench_* [slow]).
-
-Usage:  JAX_PLATFORMS=cpu python tools_serving_smoke.py [--full]
+Usage:  JAX_PLATFORMS=cpu python tools_serving_smoke.py \
+            --mp | --mp-det | --quant | --spec | --spec-det | --adapters |
+            --adapters-det | --disagg | --disagg-det  [--full]
 """
 import json
 import os
@@ -41,106 +33,8 @@ from paddle_tpu.models.generation import generate_from_params
 from paddle_tpu.models.gpt import GPTConfig
 from paddle_tpu.models.gpt_hybrid import init_gpt_params
 
-SLOTS = 8
-PROMPT_BUCKET = 64
-MAX_NEW = 64
-SMAX = 160
-
-
-def _model(quick):
-    # big enough that a decode step dominates host dispatch on CPU, small
-    # enough that the quick rung finishes in tens of seconds
-    cfg = GPTConfig(vocab_size=512, hidden_size=512 if quick else 768,
-                    num_layers=4, num_heads=8, max_seq_len=SMAX,
-                    dropout=0.0, use_flash=False, compute_dtype="float32",
-                    remat=False)
-    return init_gpt_params(cfg, jax.random.key(0)), cfg
-
-
-def _workload(n, rate, rng):
-    """n requests: Poisson arrivals at `rate` req/s, mixed prompt lengths,
-    generation lengths skewed short with a heavy tail (every batch of the
-    static baseline ends up hostage to one long request)."""
-    arrivals = np.cumsum(rng.exponential(1.0 / rate, n))
-    reqs = []
-    for i in range(n):
-        plen = int(rng.integers(8, PROMPT_BUCKET))
-        new = MAX_NEW if i % SLOTS == 0 else int(rng.integers(4, 12))
-        reqs.append({"arrival": float(arrivals[i]),
-                     "prompt": rng.integers(0, 512, plen),
-                     "max_new": new})
-    return reqs
-
-
-def run_static(params, cfg, work):
-    """FCFS batches of SLOTS over ARRIVED requests; one whole-batch generate
-    per batch at the shared worst-case shape (single cached executable)."""
-    # warmup (compile) outside the clock
-    warm = np.zeros((SLOTS, PROMPT_BUCKET), np.int32)
-    generate_from_params(params, warm, cfg, max_new_tokens=MAX_NEW)._data.block_until_ready()
-
-    t0 = time.perf_counter()
-    ttfts, useful = [], 0
-    i = 0
-    while i < len(work):
-        batch = work[i:i + SLOTS]
-        i += SLOTS
-        # static serving cannot start before its whole batch has arrived
-        gate = max(b["arrival"] for b in batch)
-        now = time.perf_counter() - t0
-        if now < gate:
-            time.sleep(gate - now)
-        ids = np.zeros((len(batch), PROMPT_BUCKET), np.int32)
-        for r, b in enumerate(batch):
-            ids[r, :len(b["prompt"])] = b["prompt"]
-        out = generate_from_params(params, ids, cfg, max_new_tokens=MAX_NEW)
-        out._data.block_until_ready()
-        done = time.perf_counter() - t0
-        for b in batch:
-            useful += b["max_new"]            # tokens the user asked for
-            ttfts.append(done - b["arrival"])  # tokens exist only at the end
-    wall = time.perf_counter() - t0
-    return {"tokens": useful, "wall_s": round(wall, 3),
-            "tokens_per_s": round(useful / wall, 1),
-            "ttft_p50_s": round(float(np.percentile(ttfts, 50)), 3),
-            "ttft_p99_s": round(float(np.percentile(ttfts, 99)), 3)}
-
-
-def run_continuous(params, cfg, work):
-    # this ladder gates the PR 5 continuous-vs-static comparison on the
-    # POOLED layout; the paged layout has its own rung (run_paged_rung)
-    eng = serving.Engine(params=params, config=cfg, num_slots=SLOTS,
-                         max_seq_len=SMAX, prefill_buckets=(PROMPT_BUCKET,),
-                         kv_layout="pooled", max_queue=len(work) + 1)
-    # warmup both executables outside the clock
-    eng.generate([np.arange(4)], max_new_tokens=2)
-
-    t0 = time.perf_counter()
-    reqs = [serving.Request(w["prompt"], max_new_tokens=w["max_new"])
-            for w in work]
-    pending = list(zip(work, reqs))
-    done = {}
-    while pending or eng.queue_depth or eng.active_slots:
-        now = time.perf_counter() - t0
-        while pending and pending[0][0]["arrival"] <= now:
-            eng.submit(pending.pop(0)[1])
-        if not (eng.queue_depth or eng.active_slots):
-            time.sleep(max(0.0, pending[0][0]["arrival"] - now))
-            continue
-        eng.step()
-        done.update(eng.pop_results())
-    wall = time.perf_counter() - t0
-    useful = sum(len(r.tokens) for r in done.values())
-    # TTFT vs ARRIVAL time (submit_t is deferred to the arrival instant)
-    ttfts = [done[r.request_id].ttft for r in reqs]
-    return {"tokens": useful, "wall_s": round(wall, 3),
-            "tokens_per_s": round(useful / wall, 1),
-            "ttft_p50_s": round(float(np.percentile(ttfts, 50)), 3),
-            "ttft_p99_s": round(float(np.percentile(ttfts, 99)), 3)}
-
-
 # ---------------------------------------------------------------------------
-# paged vs pooled KV layout (PR 7): same KV memory, mixed-length workload
+# what the rungs share: model, mixed-length workload, driver
 
 
 def _paged_model(deterministic):
@@ -162,14 +56,10 @@ def _mixed_workload(n, rate, rng, short_pl, long_pl, xl_pl, short_new,
                     long_new, xl_new, vocab, sys_len=0, tmpl_len=0):
     """Mixed-length traffic, Poisson arrivals at `rate` req/s (rate=None
     -> backlogged: everything queued at t=0): mostly short turns, every
-    3rd request long, every 6th an XL long-tail request. The tail is what
-    breaks the pooled layout twice over — every slot must reserve
-    worst-case Smax (so the tail sets the whole engine's batch size), and
-    each long admission is a monolithic prefill during which no slot
-    decodes. Long/XL prompts share a `sys_len`-token system prompt and
-    short ones a `tmpl_len`-token chat template (the millions-of-users
-    traffic shape) — the paged engine's prefix cache serves those tokens
-    from shared pages; the pooled engine recomputes them every request."""
+    3rd request long, every 6th an XL long-tail request. Long/XL prompts
+    share a `sys_len`-token system prompt and short ones a `tmpl_len`-token
+    chat template (the millions-of-users traffic shape) — the engine's
+    prefix cache serves those tokens from shared pages."""
     arrivals = (np.zeros(n) if rate is None
                 else np.cumsum(rng.exponential(1.0 / rate, n)))
     sys_p = rng.integers(0, vocab, sys_len)
@@ -230,138 +120,6 @@ def _intertoken_p99(stamps, work):
         if not w["long"]:
             gaps.extend(np.diff(ts))
     return float(np.percentile(gaps, 99)) if gaps else 0.0
-
-
-def run_paged_rung(quick=True, deterministic=False, rate=None, repeats=3):
-    """Pooled vs paged at EQUAL KV memory. Pooled reserves worst-case
-    Smax per slot (the XL tail sets it), so its batch collapses to a few
-    slots and each long admission is a monolithic prefill stall; paged
-    spends the same bytes on pages — admission bounded by ACTUAL request
-    footprints, hot prompt prefixes served from shared pages, prefill
-    chunks interleaved with decode. Gates (timed mode): paged >= 1.3x
-    tokens/s backlogged, inter-token p99 of short requests not regressed,
-    plus a request that only fits in pages (prompt+new > pooled Smax).
-    Each engine is driven `repeats` times with fresh engine state
-    (executables stay jit-cached) and the best run is scored — the
-    standard guard against interference on a shared host."""
-    from paddle_tpu import profiler
-    params, cfg = _paged_model(deterministic)
-    if deterministic:
-        smax, slots, ps, pslots = 48, 4, 8, 16
-        short_pl, long_pl, xl_pl = (3, 15), (20, 33), (34, 41)
-        short_new, long_new, xl_new = (3, 7), (4, 9), (4, 8)
-        sys_len, tmpl_len = 16, 0
-        buckets = (short_pl[1] - 1, (smax + 1) // 2, smax)
-        n = 10
-    else:
-        # Smax is set by the LONGEST admissible request (the XL tail) —
-        # the pooled layout must reserve it for EVERY slot, so the same
-        # KV bytes buy it 4 worst-case slots while the paged layout runs
-        # 24 actual-footprint slots
-        smax, slots, ps, pslots = 768, 4, 16, 24
-        short_pl, long_pl, xl_pl = (18, 49), (96, 129), (520, 641)
-        short_new, long_new, xl_new = (24, 49), (40, 64), (16, 33)
-        sys_len, tmpl_len = 96, 16
-        buckets = (short_pl[1] - 1, 192, smax)
-        n = 72 if quick else 144
-    num_pages = slots * smax // ps + 1      # memory-equal (+trash page)
-    work = _mixed_workload(n, rate, np.random.default_rng(0), short_pl,
-                           long_pl, xl_pl, short_new, long_new, xl_new,
-                           cfg.vocab_size, sys_len=sys_len,
-                           tmpl_len=tmpl_len)
-
-    chunk = ps if deterministic else 4 * ps
-
-    def build():
-        """Fresh engine pair per trial (the jitted executables are shared
-        across engines per shape, so rebuilds are cheap): warm every
-        prefill bucket / chunk-ladder rung, then a throwaway mini-drive
-        over one request of every class so hot prefixes are cached —
-        steady-state serving runs with warm caches."""
-        pooled = serving.Engine(params=params, config=cfg, num_slots=slots,
-                                max_seq_len=smax, kv_layout="pooled",
-                                prefill_buckets=buckets, max_queue=n + 2)
-        # same KV bytes, spent on pages instead of worst-case slots —
-        # admission bounded by each request's ACTUAL footprint
-        paged = serving.Engine(params=params, config=cfg,
-                               num_slots=pslots, max_seq_len=smax,
-                               kv_layout="paged", page_size=ps,
-                               num_pages=num_pages, prefill_chunk=chunk,
-                               max_queue=n + 2)
-        warm_lens = sorted({ps + 1, *paged._chunk_ladder} |
-                           {b - 2 for b in pooled.scheduler.buckets})
-        for eng in (pooled, paged):
-            eng.generate([np.arange(1, ln + 1) for ln in warm_lens],
-                         max_new_tokens=2)
-            if eng is paged:
-                eng.pool.clear_cache()   # drop the warmup prompts' pins
-            _drive(eng, work[:6])        # hot prefixes cached
-        return pooled, paged
-
-    if deterministic:
-        repeats = 1
-    best = {}
-    outputs_match = True
-    for _ in range(max(1, repeats)):
-        pooled, paged = build()
-        trial = {}
-        for name, eng in (("pooled", pooled), ("paged", paged)):
-            profiler.reset_serving_counters()
-            toks, wall, stamps = _drive(eng, work)
-            trial[name] = (toks, wall, stamps, profiler.serving_counters())
-        outputs_match = outputs_match and \
-            trial["pooled"][0] == trial["paged"][0]
-        for name, t in trial.items():
-            if name not in best or t[1] < best[name][1]:
-                best[name] = t
-    pooled_toks, pooled_wall, pooled_stamps, pc = best["pooled"]
-    paged_toks, paged_wall, paged_stamps, gc = best["paged"]
-
-    useful = sum(len(t) for t in paged_toks)
-    # capacity demo (outside the timed section): a request whose
-    # prompt+max_new exceeds the pooled layout's per-slot Smax serves fine
-    # from the same page pool with a longer virtual window
-    cap_prompt = np.arange(1, smax)          # smax-1 + 16 > smax
-    try:
-        pooled.submit(serving.Request(cap_prompt, max_new_tokens=16))
-        cap_only_paged = False
-    except ValueError:
-        cap_eng = serving.Engine(
-            params=params, config=cfg, num_slots=slots,
-            max_seq_len=min(2 * smax, cfg.max_seq_len), kv_layout="paged",
-            page_size=ps, num_pages=num_pages, prefill_chunk=chunk)
-        res = cap_eng.run([serving.Request(cap_prompt, max_new_tokens=16)])
-        cap_only_paged = all(len(r.tokens) == 16 for r in res.values())
-
-    out = {
-        "bench": "serving_paged_smoke", "requests": n,
-        "rate_req_s": rate, "backend": jax.default_backend(),
-        "page_size": ps, "num_pages": num_pages,
-        "outputs_match": outputs_match and pooled_toks == paged_toks,
-        "capacity_only_paged": cap_only_paged,
-        "pooled": {
-            "slots": slots, "smax": smax, "wall_s": round(pooled_wall, 3),
-            "tokens_per_s": round(sum(len(t) for t in pooled_toks)
-                                  / pooled_wall, 1),
-            "intertoken_p99_s": round(_intertoken_p99(pooled_stamps, work), 4),
-            "prefill_waste_mean": round(pc["prefill_waste_mean"], 1),
-            "prefill_waste_max": pc["prefill_padded_max"],
-        },
-        "paged": {
-            "slots": pslots, "wall_s": round(paged_wall, 3),
-            "tokens_per_s": round(useful / paged_wall, 1),
-            "intertoken_p99_s": round(_intertoken_p99(paged_stamps, work), 4),
-            "prefill_waste_mean": round(gc["prefill_waste_mean"], 1),
-            "prefill_waste_max": gc["prefill_padded_max"],
-            "page_occupancy": round(gc["page_occupancy"], 3),
-            "prefix_hit_rate": round(gc["prefix_hit_rate"], 3),
-            "chunk_steps": gc["chunk_steps"], "cow_copies": gc["cow_copies"],
-        },
-    }
-    out["speedup"] = round(out["paged"]["tokens_per_s"]
-                           / max(out["pooled"]["tokens_per_s"], 1e-9), 2)
-    print(json.dumps(out))
-    return out
 
 
 def run_mp_rung(deterministic=False, backends=("gspmd", "ring"),
@@ -1111,29 +869,6 @@ def run_disagg_rung(quick=True, deterministic=False, rate=None, repeats=3):
     return out
 
 
-def run_ladder(quick=True):
-    params, cfg = _model(quick)
-    n = 24 if quick else 48
-    rates = [1e9] if quick else [2.0, 8.0, 1e9]   # req/s; 1e9 = backlogged
-    out = []
-    for rate in rates:
-        work = _workload(n, rate, np.random.default_rng(0))
-        static = run_static(params, cfg, work)
-        cont = run_continuous(params, cfg, work)
-        rung = {
-            "bench": "serving_smoke", "requests": n,
-            "rate_req_s": None if rate > 1e6 else rate,
-            "backend": jax.default_backend(),
-            "static": static, "continuous": cont,
-            "speedup": round(cont["tokens_per_s"] / static["tokens_per_s"], 2),
-            "ttft_p50_ratio": round(
-                static["ttft_p50_s"] / max(cont["ttft_p50_s"], 1e-9), 1),
-        }
-        print(json.dumps(rung))
-        out.append(rung)
-    return out
-
-
 if __name__ == "__main__":
     if "--mp" in sys.argv or "--mp-det" in sys.argv:
         # tensor-parallel ladder: memory-equal single-chip vs mp in {2,4}
@@ -1261,37 +996,4 @@ if __name__ == "__main__":
               f"over-budget context served only quantized: "
               f"{out['capacity_only_quant']}")
         sys.exit(0)
-    if "--paged" in sys.argv:
-        # paged vs pooled ladder: backlogged + (full) a Poisson-arrival rung
-        quick = "--full" not in sys.argv
-        rungs = [run_paged_rung(quick=quick)]
-        if not quick:
-            rungs.append(run_paged_rung(quick=False, rate=8.0))
-        cap = rungs[0]
-        ok_tp = cap["speedup"] >= 1.3
-        ok_it = (cap["paged"]["intertoken_p99_s"]
-                 <= cap["pooled"]["intertoken_p99_s"])
-        ok_waste = cap["paged"]["prefill_waste_max"] < cap["page_size"]
-        print(f"# paged vs pooled (equal KV memory, mixed lengths, "
-              f"backlogged): {cap['speedup']:.2f}x tokens/s "
-              f"({'PASS' if ok_tp else 'FAIL'} >= 1.3x gate), "
-              f"inter-token p99 {cap['paged']['intertoken_p99_s'] * 1e3:.1f}"
-              f"ms vs {cap['pooled']['intertoken_p99_s'] * 1e3:.1f}ms "
-              f"({'PASS' if ok_it else 'FAIL'} not regressed), "
-              f"chunked prefill waste max "
-              f"{cap['paged']['prefill_waste_max']} tok "
-              f"({'PASS' if ok_waste else 'FAIL'} < page_size "
-              f"{cap['page_size']}), over-Smax request served from pages: "
-              f"{cap['capacity_only_paged']}")
-        sys.exit(0)
-    results = run_ladder(quick="--full" not in sys.argv)
-    # tokens/s gates the CAPACITY-bound (backlogged) rungs; in the
-    # arrival-limited rungs both systems idle between requests and the
-    # meaningful win is TTFT (tokens stream per iteration instead of at
-    # whole-batch completion)
-    cap = min(r["speedup"] for r in results if r["rate_req_s"] is None)
-    ttft = max(r["ttft_p50_ratio"] for r in results)
-    print(f"# continuous batching vs static whole-batch: backlogged "
-          f"speedup {cap:.2f}x "
-          f"({'PASS' if cap >= 1.5 else 'FAIL'} >= 1.5x gate), "
-          f"best p50-TTFT ratio {ttft:.1f}x")
+    sys.exit(__doc__)

@@ -89,7 +89,6 @@ def _fixture():
         kw.setdefault("max_seq_len", 96)
         kw.setdefault("page_size", 8)
         kw.setdefault("prefill_chunk", 8)
-        kw.setdefault("kv_layout", "paged")
         return serving.Engine(params=p0, config=cfg, **kw)
 
     _ref_cache = {}
