@@ -563,6 +563,7 @@ class Engine:
         use_kernel = bool(flags.get("FLAGS_serving_paged_kernel", True)
                           ) and self._model.kernel_ok(
                               config, self.mp, self.page_size)
+        self._paged_kernel = use_kernel
         quant_key = None if self._quant is None else self._quant.key()
         qkernel = (self._quant is not None
                    and self._quant.quantizes_weights
@@ -1240,6 +1241,14 @@ class Engine:
         self._record_mp_comm(B, 1, t0, now,
                              [self._slots[b] for b in decoding])
         self._count_paged_step(self._do_sample & emit)
+        # what the attention read of this dispatch visits of its table, by
+        # the host's copy of the operands (a speculative verify makes the
+        # same read k+1 times a window and is not counted)
+        table_pages = self.pool.table.size
+        metrics.bump("decode_pages_table", table_pages)
+        metrics.bump("decode_pages_swept",
+                     int((self._pos // self.page_size + 1).sum())
+                     if self._paged_kernel else table_pages)
         for b in decoding:
             req = self._slots[b]
             if ok is not None and not ok[b]:

@@ -33,6 +33,10 @@ def _zero():
         # only ones whose sampling tail (cuts, sorts, Gumbel draw over
         # [slots, vocab]) ran; in the others the step took the argmax
         "sampled_steps": 0,
+        # page-table entries of the [B, 1] decode dispatches, and those the
+        # read the step was built with visits: the live pages of each slot
+        # under the decode kernel, the whole table under the gather read
+        "decode_pages_table": 0, "decode_pages_swept": 0,
         "chunk_steps": 0, "prefill_chunks": 0,
         "cow_copies": 0, "copy_traces": 0,
         # prefix cache

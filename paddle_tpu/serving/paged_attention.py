@@ -11,9 +11,11 @@ Two implementations of the decode-attention read:
   BITWISE identical to single-request ``generate_from_params`` — this is
   the tier-1 parity path.
 * **Pallas TPU kernel** (``paged_decode_attention``) — one-token decode
-  that walks each slot's page list via scalar-prefetched table indices, so
-  only that slot's LIVE pages move HBM->VMEM (the gather path materializes
-  the full virtual window). Online-softmax accumulation: numerically
+  that sweeps the pages each slot HOLDS (``pos // page_size + 1`` of its
+  table row, found through the scalar-prefetched table and pos), so only
+  live pages move HBM->VMEM and its work follows the contexts, not the
+  table's width (the gather path materializes the full virtual window).
+  Online-softmax accumulation: numerically
   equivalent, not bitwise identical — gated behind
   ``FLAGS_serving_paged_kernel`` and a TPU-backend + shape predicate
   (``paged_kernel_supported``), mirroring the flash-attention routing.
@@ -121,111 +123,173 @@ def paged_kernel_supported(nh, d, page_size, why=""):
 # Pallas TPU kernel: one-token decode through the page table
 
 
-def _decode_kernel(*refs, page_size, scale, quant):
-    """Grid (B, MP): slot b sweeps its logical pages j; the BlockSpec
-    index_map already resolved logical->physical through the prefetched
-    table, so k_ref/v_ref hold THIS slot's j-th page [ps, nh, d]. Online
-    softmax state (m, l, acc) lives in VMEM scratch across the page sweep.
+# pages one step of the sweep carries: their DMAs are in flight together
+# (two steps' worth, double-buffered), so a slot of a few hundred positions
+# is one to three steps and a page's latency hides behind seven others
+# (4, 8 and 16 read within 5% on a v5e: the walk, not the fetch, is the
+# bound; PERF.md section 6, PR 31)
+_SWEEP_PAGES = 8
 
-    The page is walked one key position at a time on the VPU: position s
-    is a native [nh, d] tile, its score column is a lane reduction and its
-    context contribution a broadcast multiply-add. No dot_general: the
-    per-head contraction "hd,shd->hs" has its batch dim in the middle of
-    the page and no free lhs dim, which Mosaic's dot lowering refuses
+
+def _decode_kernel(*refs, page_size, scale, quant, table_pages):
+    """One invocation sweeps, slot after slot, the pages each slot HOLDS:
+    ``pos[b] // page_size + 1`` of the ``table_pages`` entries of its table
+    row, in steps of up to ``_SWEEP_PAGES`` pages. The pools stay whole in
+    HBM; a step's live pages come to VMEM by one DMA a page, addressed
+    (layer, table[b, j]) off the scalar-prefetched operands, into one half
+    of a double buffer while the other half is computed on, and the step
+    after a slot's last is the next slot's first, so only the call's first
+    fetch is exposed. A table entry past a slot's last live page is never
+    looked at: no fetch (not of trash page 0 either), no compute.
+
+    A page is walked one key position at a time on the VPU: position s is
+    a native [nh, d] tile, its score column is a lane reduction and its
+    context contribution a broadcast multiply-add, folded into the online
+    softmax state (m, l, acc in VMEM scratch) once a page; the last live
+    page is masked by position. No dot_general: the per-head contraction
+    "hd,shd->hs" has its batch dim in the middle of the page and no free
+    lhs dim, which Mosaic's dot lowering refuses
     (TPU_DotDimensionNumbersAttr 'lhs_non_contracting_dims', jax 0.9.0).
 
     ``quant``: the pool holds int8/fp8 values and the per-PAGE dequant
     scales arrive as two more scalar-prefetch operands — scores scale
     after the q.k reduction, v contributions inside the ctx accumulation,
     so the fp K/V bytes never exist in HBM."""
-    # refs[0] is the layer index: only the BlockSpec index_map reads it
     if quant:
-        (_, table_ref, pos_ref, ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
+        (lay_ref, table_ref, pos_ref, ksc_ref, vsc_ref, q_ref, k_hbm, v_hbm,
+         o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref) = refs
     else:
-        (_, table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
+        (lay_ref, table_ref, pos_ref, q_ref, k_hbm, v_hbm,
+         o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref) = refs
+    G = _SWEEP_PAGES
+    lay = lay_ref[0]
 
-    @pl.when(j == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def live_pages(b):
+        return jnp.minimum(pos_ref[b] // page_size + 1, table_pages)
 
-    k_scale = scale
-    if quant:
-        phys = table_ref[b * nj + j]
-        k_scale = scale * ksc_ref[phys]
-    q = q_ref[0].astype(jnp.float32)                     # [nh, d]
-    last = pos_ref[b]
-    cols = []                                            # ps x [nh, 1]
-    for s in range(page_size):
-        c = jnp.sum(q * k_ref[0, s].astype(jnp.float32), axis=-1,
-                    keepdims=True) * k_scale
-        cols.append(jnp.where(j * page_size + s <= last, c, -jnp.inf))
+    def steps(b):
+        return (live_pages(b) + G - 1) // G
 
-    m_prev = m_ref[:, :1]                                # [nh, 1]
-    m_new = m_prev
-    for c in cols:
-        m_new = jnp.maximum(m_new, c)
-    # fully-masked pages keep m at -inf; guard the exp(-inf - -inf) NaNs
-    alpha = jnp.where(m_prev == -jnp.inf, 0.0, jnp.exp(m_prev - m_new))
-    l_new = alpha * l_ref[:, :1]
-    pv = jnp.zeros(acc_ref.shape, jnp.float32)           # [nh, d]
-    for s, c in enumerate(cols):
-        p = jnp.where(c == -jnp.inf, 0.0, jnp.exp(c - m_new))
-        l_new = l_new + p
-        pv = pv + p * v_ref[0, s].astype(jnp.float32)
-    if quant:
-        pv = pv * vsc_ref[phys]
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    acc_ref[:] = acc_ref[:] * alpha + pv
+    def phys_page(b, j, g):
+        return table_ref[b * table_pages + j * G + g]
 
-    @pl.when(j == nj - 1)
-    def _():
-        o_ref[0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+    def for_live_pages(b, j, fn):
+        """fn(g) for the pages g of slot b's step j that are live, in a
+        loop (the body is traced once, whatever ``_SWEEP_PAGES``); the
+        table is read for those alone (a dead entry's index may lie past
+        the row)."""
+        def body(g, carry):
+            fn(g)
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(live_pages(b) - j * G, G), body, 0)
+
+    def fetch(b, j, half, act):
+        def one(g):
+            phys = phys_page(b, j, g)
+            for hbm, buf, sem in ((k_hbm, k_buf, sems.at[0, half]),
+                                  (v_hbm, v_buf, sems.at[1, half])):
+                act(pltpu.make_async_copy(hbm.at[lay, phys],
+                                          buf.at[half, g], sem))
+        for_live_pages(b, j, one)
+
+    def walk(b, j, half, q, g):
+        k_scale = scale
+        if quant:
+            phys = phys_page(b, j, g)
+            k_scale = scale * ksc_ref[phys]
+        first = (j * G + g) * page_size
+        last = pos_ref[b]
+        cols = []                                            # ps x [nh, 1]
+        for s in range(page_size):
+            c = jnp.sum(q * k_buf[half, g, s].astype(jnp.float32), axis=-1,
+                        keepdims=True) * k_scale
+            cols.append(jnp.where(first + s <= last, c, -jnp.inf))
+
+        m_prev = m_ref[:, :1]                                # [nh, 1]
+        m_new = m_prev
+        for c in cols:
+            m_new = jnp.maximum(m_new, c)
+        # a walked page's first position is live, so m_new is finite and
+        # exp(-inf - m_new) is an exact 0: for a slot's first page (m_prev)
+        # and for the masked tail of its last (c)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_ref[:, :1]
+        pv = jnp.zeros(acc_ref.shape, jnp.float32)           # [nh, d]
+        for s, c in enumerate(cols):
+            p = jnp.exp(c - m_new)
+            l_new = l_new + p
+            pv = pv + p * v_buf[half, g, s].astype(jnp.float32)
+        if quant:
+            pv = pv * vsc_ref[phys]
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        acc_ref[:] = acc_ref[:] * alpha + pv
+
+    def step(i, at):
+        b, j = at
+        half = i % 2
+        slot_done = j + 1 == steps(b)
+        nxt = (jnp.where(slot_done, b + 1, b), jnp.where(slot_done, 0, j + 1))
+
+        @pl.when(i + 1 < total)
+        def _():
+            fetch(*nxt, 1 - half, lambda dma: dma.start())
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        fetch(b, j, half, lambda dma: dma.wait())
+        q = q_ref[b].astype(jnp.float32)                     # [nh, d]
+        for_live_pages(b, j, functools.partial(walk, b, j, half, q))
+
+        @pl.when(slot_done)
+        def _():
+            o_ref[b] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+        return nxt
+
+    total = jax.lax.fori_loop(0, q_ref.shape[0],
+                              lambda b, n: n + steps(b), 0)
+    fetch(0, 0, 0, lambda dma: dma.start())
+    jax.lax.fori_loop(0, total, step, (0, 0))
 
 
 def _paged_decode_call(q, kc, vc, layer, table, pos, scales, page_size,
                        interpret):
     """pallas_call shared by the fp and quantized-pool entry points. kc/vc
-    are the WHOLE pool [L, P, page_size, nh, d] and ``layer`` a traced
-    scalar: it rides as the first scalar-prefetch operand and the page
-    index_map addresses block (layer, phys page), so no layer of the pool
-    is sliced out (and copied) for the kernel. ``layer=None`` takes one
+    are the WHOLE pool [L, P, page_size, nh, d], left in HBM, and ``layer``
+    a traced scalar: it rides as the first scalar-prefetch operand and the
+    kernel's page fetches address (layer, phys page), so no layer of the
+    pool is sliced out (and copied) for the kernel. ``layer=None`` takes one
     layer's [P, page_size, nh, d] (a free leading axis, layer 0).
     ``scales`` is () or that layer's (ksc_l, vsc_l) [P] fp32, prefetched
     to SMEM after the flat table and pos."""
     if layer is None:
         kc, vc, layer = kc[None], vc[None], 0
     B, nh, d = q.shape
-    MP = table.shape[1]
-
-    def q_map(b, j, *prefetch):
-        return (b, 0, 0)
-
-    def page_map(b, j, lay, tab, *prefetch):
-        return (lay[0], tab[b * MP + j], 0, 0, 0)
-
-    page_spec = pl.BlockSpec((None, 1, page_size, nh, d), page_map)
+    whole = pl.BlockSpec((B, nh, d), lambda i, *prefetch: (0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    pages = (2, _SWEEP_PAGES, page_size, nh, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # layer, flat table, pos[, scales]
         num_scalar_prefetch=3 + len(scales),
-        grid=(B, MP),
-        in_specs=[pl.BlockSpec((1, nh, d), q_map), page_spec, page_spec],
-        out_specs=pl.BlockSpec((1, nh, d), q_map),
+        grid=(1,),
+        in_specs=[whole, in_hbm, in_hbm],
+        out_specs=whole,
         scratch_shapes=[
+            pltpu.VMEM(pages, kc.dtype),             # k pages, two halves
+            pltpu.VMEM(pages, vc.dtype),             # v pages
+            pltpu.SemaphoreType.DMA((2, 2)),         # (k | v, half)
             pltpu.VMEM((nh, 128), jnp.float32),      # m (lane-broadcast)
             pltpu.VMEM((nh, 128), jnp.float32),      # l
             pltpu.VMEM((nh, d), jnp.float32),        # acc
         ],
     )
     kernel = functools.partial(_decode_kernel, page_size=page_size,
-                               scale=1.0 / (d ** 0.5), quant=bool(scales))
+                               scale=1.0 / (d ** 0.5), quant=bool(scales),
+                               table_pages=table.shape[1])
     # Mosaic rejects x64-typed index math; the framework enables x64 globally
     # for dtype parity, so pin 32-bit types inside the kernel trace.
     with jax.enable_x64(False):

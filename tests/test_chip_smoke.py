@@ -94,7 +94,7 @@ def test_paged_and_quant_kernels_lower_for_tpu_at_the_1p3b_shapes():
     from paddle_tpu.serving.paged_attention import (
         paged_decode_attention, paged_decode_attention_q)
     sds = jax.ShapeDtypeStruct
-    B, nh, d, ps, MP = 8, 16, 128, 16, 128
+    B, nh, d, ps, MP = 16, 16, 128, 16, 128     # the 1.3B cell's decode
     P = B * MP + 1
     q, tab, pos = sds((B, nh, d), jnp.float32), sds((B, MP), jnp.int32), \
         sds((B,), jnp.int32)

@@ -15,6 +15,8 @@ plus the stop-condition matrix and the satellites: temperature validation, recyc
 reset, prefill padded-waste metric, and the Pallas kernel's interpret-mode
 parity with the jnp gather path.
 """
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -529,6 +531,81 @@ def test_paged_decode_kernel_reads_a_layer_of_the_whole_pool(layer, quant):
                                rtol=2e-5, atol=2e-5)
 
 
+# the sweep's geometry: 20 table entries a slot are two and a half of the
+# kernel's steps of eight pages, so a step that is part dead, a slot that
+# ends on a step's edge and one a page past it all occur
+_KB, _KMP, _KPS = 4, 20, 8
+_SWEEPS = {
+    "first_position": [0] * _KB,
+    "page_end": [_KPS - 1] * _KB,
+    "second_page": [_KPS] * _KB,
+    "full_table": [_KMP * _KPS - 1] * _KB,
+    "one_page_beside_full": [3, _KMP * _KPS - 1, 8 * _KPS - 1, 8 * _KPS],
+}
+
+
+@pytest.mark.parametrize("sweep,dead", [(name, "zero") for name in _SWEEPS] + [
+    ("one_page_beside_full", "stale"), ("one_page_beside_full", "nan")])
+@pytest.mark.parametrize("stacked", [True, False], ids=["layer", "one_layer"])
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+def test_paged_decode_kernel_sweeps_the_live_pages(quant, stacked, sweep,
+                                                   dead):
+    """The kernel against the gather read over tables as the pool keeps
+    them: a slot's live entries name pages of its own, the entries past its
+    last live page are 0 (``zero``) or left over (``stale``: other slots'
+    pages). ``nan`` poisons every pool page that no live position of any
+    slot maps to, trash page 0 included (the values of a float pool, the
+    scales of an int8 one): the kernel's output is finite and the clean
+    pool's, so a dead page is not fetched, not merely masked (a masked
+    NaN still poisons the context sum: 0 * NaN)."""
+    from paddle_tpu.serving.paged_attention import (
+        paged_attention_read, paged_decode_attention,
+        paged_decode_attention_q)
+    rng = np.random.default_rng(11)
+    L, layer, nh, d = 3, 2, 8, 128
+    B, MP, ps = _KB, _KMP, _KPS
+    P = 1 + B * MP
+    pos = np.asarray(_SWEEPS[sweep], np.int32)
+    owned = np.arange(1, P).reshape(B, MP)
+    live = np.arange(MP)[None, :] <= (pos // ps)[:, None]
+    table = np.where(live, owned, 0)
+    if dead == "stale":
+        table = np.where(live, owned, rng.integers(1, P, (B, MP)))
+    unmapped = np.setdiff1d(np.arange(P), owned[live])
+
+    q = jnp.asarray(rng.standard_normal((B, nh, d)), jnp.float32)
+    if quant:
+        kc, vc = (rng.integers(-127, 128, (L, P, ps, nh, d)).astype(np.int8)
+                  for _ in range(2))
+        scales = [rng.uniform(0.01, 0.1, P).astype(np.float32)
+                  for _ in range(2)]
+        fn = paged_decode_attention_q
+    else:
+        kc, vc = (rng.standard_normal((L, P, ps, nh, d)).astype(np.float32)
+                  for _ in range(2))
+        scales = []
+        fn = paged_decode_attention
+    # (on the host before a page is poisoned: a device array may alias
+    # the numpy buffer it was made from)
+    want = np.asarray(paged_attention_read(
+        q[:, None], jnp.asarray(kc), jnp.asarray(vc), layer,
+        jnp.asarray(table), pos[:, None], ps, False, jnp.float32,
+        *map(jnp.asarray, scales))[:, 0])
+    if dead == "nan":
+        for a in scales or (kc, vc):
+            a[(slice(None), unmapped) if a.ndim > 1 else unmapped] = np.nan
+    kc, vc = jnp.asarray(kc), jnp.asarray(vc)
+    args = (jnp.asarray(table), jnp.asarray(pos), *map(jnp.asarray, scales))
+    if stacked:
+        got = fn(q, kc, vc, *args, page_size=ps,
+                 layer=jnp.asarray(layer, jnp.int32), interpret=True)
+    else:
+        got = fn(q, kc[layer], vc[layer], *args, page_size=ps,
+                 interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
 def test_pool_pads_head_dim_to_lanes_and_nothing_sees_the_pad():
     """The device pool's last axis is head_dim padded up to 128 lanes (its
     row-major layout is then the device's default on a TPU); the pad
@@ -578,6 +655,49 @@ def test_sampled_steps_counts_the_dispatches_whose_tail_drew(speculate_k):
     assert 0 < c["sampled_steps"] <= 6 < c["paged_steps"]
     if not speculate_k:          # one token a dispatch without speculation
         assert c["sampled_steps"] == 6
+
+
+@pytest.mark.parametrize("read", ["gather", "kernel"])
+def test_decode_pages_counters_follow_the_read_the_step_was_built_with(
+        read, monkeypatch):
+    """``decode_pages_table`` counts the table entries of every [B, 1]
+    decode dispatch, ``decode_pages_swept`` those its attention read
+    visits: every entry under the gather read (the CPU's), the pages each
+    slot holds at its uploaded ``pos`` under the decode kernel (built here
+    by a patched ``kernel_ok`` and interpreted; head_dim 128 as the kernel
+    wants it)."""
+    from paddle_tpu.serving import paged_attention as PA, served_model
+    cfg = GPTConfig(vocab_size=97, hidden_size=256, num_layers=2,
+                    num_heads=2, max_seq_len=64, dropout=0.0, use_flash=False,
+                    compute_dtype="float32", remat=False)
+    if read == "kernel":
+        monkeypatch.setattr(served_model._GPTServed, "kernel_ok",
+                            lambda *a: True)
+        monkeypatch.setattr(PA, "paged_decode_attention", functools.partial(
+            PA.paged_decode_attention, interpret=True))
+    eng = serving.Engine(params=init_gpt_params(cfg, jax.random.key(1)),
+                         config=cfg, num_slots=3, max_seq_len=64,
+                         page_size=8, prefill_chunk=8)
+    B, MP, ps = 3, 64 // 8, 8
+    step, decodes = eng._paged_step, []
+
+    def spy(params, kc, vc, ids, start, *rest):
+        if ids.shape == (B, 1):
+            decodes.append(np.array(start))     # a copy: may alias _pos
+        return step(params, kc, vc, ids, start, *rest)
+    eng._paged_step = spy
+    rng = np.random.default_rng(4)
+    profiler.reset_serving_counters()
+    eng.run([serving.Request(rng.integers(0, cfg.vocab_size, n),
+                             max_new_tokens=m)
+             for n, m in ((3, 9), (13, 6), (21, 12), (5, 4))])
+    c = profiler.serving_counters()
+    assert len(decodes) == c["paged_steps"] - c["chunk_steps"] > 12
+    assert c["decode_pages_table"] == len(decodes) * B * MP
+    held = sum(int((pos // ps + 1).sum()) for pos in decodes)
+    assert c["decode_pages_swept"] == \
+        (held if read == "kernel" else c["decode_pages_table"])
+    assert 0 < held < c["decode_pages_table"] / 2
 
 
 # ---------------------------------------------------------------------------
