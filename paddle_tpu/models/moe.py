@@ -1,0 +1,103 @@
+"""What the expert models share (``models/xing4.py``, ``models/afmoe.py``):
+the RMS norm, the gated FFN, the final norm and untied head, and the
+sigmoid-routed expert layer beside a shared expert. One function each, so
+that a change to the expert layer is judged on every expert model the
+benchmark runs.
+
+The expert layer reads of a configuration object: ``n_routed_experts``,
+``num_experts_per_tok``, ``norm_topk_prob``, ``routed_scaling_factor``,
+``held`` (the range of routed experts this chip holds), ``rms_norm_eps`` and
+``compute_dtype``; of a layer's leaves: ``ffn_norm_g``, ``router_w``,
+``router_bias``, ``experts_{gate,up,down}_w`` and ``shared_{gate,up,down}_w``.
+A model whose published keys differ states them as properties."""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+F32 = jnp.float32
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def rms_norm(x, g, eps):
+    """RMS norm in float32, back in x's type; ``g`` None is no gain."""
+    xf = x.astype(F32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+    if g is not None:
+        xf = xf * g.astype(F32)
+    return xf.astype(x.dtype)
+
+
+def mm(x, w, out=None):
+    """x @ w in x's type; ``out`` float32 keeps the product's float32 sums
+    (what a sublayer hands back to the float32 stream)."""
+    return jnp.matmul(x, w.astype(x.dtype), preferred_element_type=out)
+
+
+def ffn(x, gate_w, up_w, down_w):
+    return mm(jax.nn.silu(mm(x, gate_w)) * mm(x, up_w), down_w, F32)
+
+
+def final_logits(params, config, h):
+    """The final norm (float32) and the untied head over h [..., H]."""
+    hn = rms_norm(h.astype(F32), params["normf_g"], config.rms_norm_eps)
+    return hn @ params["head_w"].astype(F32)
+
+
+def compute_of(config):
+    return jnp.dtype(config.compute_dtype or "float32")
+
+
+def moe_route(xn32, router_w, router_bias, config):
+    """Routing of tokens xn32 [N, H] (float32, as the published code routes):
+    chosen experts [N, k] and their weights [N, k]."""
+    c = config
+    s = jax.nn.sigmoid(jnp.matmul(xn32, router_w.astype(F32),
+                                  precision=HIGHEST))
+    _, idx = jax.lax.top_k(s + router_bias.astype(F32), c.num_experts_per_tok)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if c.norm_topk_prob:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return idx, w * c.routed_scaling_factor
+
+
+def moe_ffn(p, x, config, token_mask=None, held=None, shared=True):
+    """The expert layer's FFN on the sublayer input x [B, T, H] (float32,
+    not yet normed). Routes over every expert, computes the part of the result that
+    the ``held`` range of routed experts gives (default: the
+    configuration's), plus the shared expert where ``shared``. Returns the
+    result and int32 ``[assignments to held experts, held experts that got
+    a token, the fullest held expert's tokens]`` over the tokens that
+    ``token_mask`` [B, T] keeps."""
+    c = config
+    B, T, H = x.shape
+    lo, hi = held or c.held
+    compute = compute_of(c)
+    xn32 = rms_norm(x.astype(F32), p["ffn_norm_g"], c.rms_norm_eps)
+    xn = xn32.astype(compute).reshape(B * T, H)
+    with jax.named_scope("pt_moe_route"):
+        idx, w = moe_route(xn32.reshape(B * T, H), p["router_w"],
+                           p["router_bias"], c)
+        hot = jax.nn.one_hot(idx, c.n_routed_experts, dtype=F32)  # [N, k, E]
+        combine = jnp.einsum("nk,nke->ne", w, hot)[:, lo:hi]
+        load = jnp.sum(hot, axis=1)[:, lo:hi]                     # [N, E']
+        if token_mask is not None:
+            load = load * token_mask.reshape(B * T, 1)
+        per_expert = jnp.sum(load, axis=0)
+        stats = jnp.stack([jnp.sum(per_expert), jnp.sum(per_expert > 0),
+                           jnp.max(per_expert)]).astype(jnp.int32)
+    with jax.named_scope("pt_moe_experts"):
+        gate = jnp.einsum("nh,ehf->enf", xn,
+                          p["experts_gate_w"][lo:hi].astype(compute))
+        up = jnp.einsum("nh,ehf->enf", xn,
+                        p["experts_up_w"][lo:hi].astype(compute))
+        act = (jax.nn.silu(gate) * up).astype(F32) * combine.T[:, :, None]
+        y = jnp.einsum("enf,efh->nh", act.astype(compute),
+                       p["experts_down_w"][lo:hi].astype(compute),
+                       preferred_element_type=F32)
+        if shared:
+            y = y + ffn(xn, p["shared_gate_w"], p["shared_up_w"],
+                        p["shared_down_w"])
+    return y.reshape(B, T, H), stats
+
+

@@ -41,9 +41,8 @@ import numpy as np
 from ..serving import metrics
 from ..serving.paged_attention import latent_scatter, latent_window
 from ..serving.served_model import CacheGeometry, ServedModel
-
-F32 = jnp.float32
-HIGHEST = jax.lax.Precision.HIGHEST
+from .moe import F32, HIGHEST, compute_of, ffn, final_logits, mm, moe_ffn, \
+    rms_norm
 
 ROPE_SCALING = (("beta_fast", 32), ("beta_slow", 1), ("factor", 64),
                 ("mscale", 1), ("mscale_all_dim", 1),
@@ -208,15 +207,6 @@ def init_xing4_params(config, key, dtype=F32, mtp=False):
 # pieces
 
 
-def rms_norm(x, g, eps):
-    """RMS norm in float32, back in x's type; ``g`` None is no gain."""
-    xf = x.astype(F32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
-    if g is not None:
-        xf = xf * g.astype(F32)
-    return xf.astype(x.dtype)
-
-
 def yarn_inv_freq(config):
     """YaRN's blended inverse frequencies [rope/2] and the gain of cos and
     sin (``mscale`` over ``mscale_all_dim``), as numpy constants."""
@@ -308,76 +298,9 @@ def mhc_sublayer(p, X, config, which, fn):
     return out, extra
 
 
-def _mm(x, w, out=None):
-    """x @ w in x's type; ``out`` float32 keeps the product's float32 sums
-    (what a sublayer hands back to the float32 stream)."""
-    return jnp.matmul(x, w.astype(x.dtype), preferred_element_type=out)
-
-
-def ffn(x, gate_w, up_w, down_w):
-    return _mm(jax.nn.silu(_mm(x, gate_w)) * _mm(x, up_w), down_w, F32)
-
-
-def _compute(config):
-    return jnp.dtype(config.compute_dtype or "float32")
-
-
-def moe_route(xn32, router_w, router_bias, config):
-    """Routing of tokens xn32 [N, H] (float32, as the published code routes):
-    chosen experts [N, k] and their weights [N, k]."""
-    c = config
-    s = jax.nn.sigmoid(jnp.matmul(xn32, router_w.astype(F32),
-                                  precision=HIGHEST))
-    _, idx = jax.lax.top_k(s + router_bias.astype(F32), c.num_experts_per_tok)
-    w = jnp.take_along_axis(s, idx, axis=-1)
-    if c.norm_topk_prob:
-        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
-    return idx, w * c.routed_scaling_factor
-
-
-def moe_ffn(p, x, config, token_mask=None, held=None, shared=True):
-    """The expert layer's FFN on the sublayer input x [B, T, H] (float32,
-    not yet normed). Routes over every expert, computes the part of the result that
-    the ``held`` range of routed experts gives (default: the
-    configuration's), plus the shared expert where ``shared``. Returns the
-    result and int32 ``[assignments to held experts, held experts that got
-    a token, the fullest held expert's tokens]`` over the tokens that
-    ``token_mask`` [B, T] keeps."""
-    c = config
-    B, T, H = x.shape
-    lo, hi = held or c.held
-    compute = _compute(c)
-    xn32 = rms_norm(x.astype(F32), p["ffn_norm_g"], c.rms_norm_eps)
-    xn = xn32.astype(compute).reshape(B * T, H)
-    with jax.named_scope("pt_moe_route"):
-        idx, w = moe_route(xn32.reshape(B * T, H), p["router_w"],
-                           p["router_bias"], c)
-        hot = jax.nn.one_hot(idx, c.n_routed_experts, dtype=F32)  # [N, k, E]
-        combine = jnp.einsum("nk,nke->ne", w, hot)[:, lo:hi]
-        load = jnp.sum(hot, axis=1)[:, lo:hi]                     # [N, E']
-        if token_mask is not None:
-            load = load * token_mask.reshape(B * T, 1)
-        per_expert = jnp.sum(load, axis=0)
-        stats = jnp.stack([jnp.sum(per_expert), jnp.sum(per_expert > 0),
-                           jnp.max(per_expert)]).astype(jnp.int32)
-    with jax.named_scope("pt_moe_experts"):
-        gate = jnp.einsum("nh,ehf->enf", xn,
-                          p["experts_gate_w"][lo:hi].astype(compute))
-        up = jnp.einsum("nh,ehf->enf", xn,
-                        p["experts_up_w"][lo:hi].astype(compute))
-        act = (jax.nn.silu(gate) * up).astype(F32) * combine.T[:, :, None]
-        y = jnp.einsum("enf,efh->nh", act.astype(compute),
-                       p["experts_down_w"][lo:hi].astype(compute),
-                       preferred_element_type=F32)
-        if shared:
-            y = y + ffn(xn, p["shared_gate_w"], p["shared_up_w"],
-                        p["shared_down_w"])
-    return y.reshape(B, T, H), stats
-
-
 def dense_ffn(p, x, config):
     xn = rms_norm(x, p["ffn_norm_g"], config.rms_norm_eps)
-    return ffn(xn.astype(_compute(config)), p["gate_w"], p["up_w"],
+    return ffn(xn.astype(compute_of(config)), p["gate_w"], p["up_w"],
                p["down_w"])
 
 
@@ -385,8 +308,8 @@ def mla_q(p, xn, config, cos, sin):
     """q_nope [B, T, nh, nope] and rotated q_rope [B, T, nh, rope]."""
     c = config
     B, T, _ = xn.shape
-    cq = rms_norm(_mm(xn, p["wq_a"]), p["q_norm_g"], c.rms_norm_eps)
-    q = _mm(cq, p["wq_b"]).reshape(B, T, c.num_attention_heads, -1)
+    cq = rms_norm(mm(xn, p["wq_a"]), p["q_norm_g"], c.rms_norm_eps)
+    q = mm(cq, p["wq_b"]).reshape(B, T, c.num_attention_heads, -1)
     q_nope, q_rope = jnp.split(q, [c.qk_nope_head_dim], axis=-1)
     return q_nope, apply_rope(q_rope, cos[:, :, None], sin[:, :, None])
 
@@ -395,7 +318,7 @@ def mla_latent(p, xn, config, cos, sin):
     """What the cache holds of a token: ``[rms(c_kv) | rope(k_rope)]``
     [B, T, kv_lora_rank + rope]."""
     c = config
-    ckv, k_rope = jnp.split(_mm(xn, p["wkv_a"]), [c.kv_lora_rank], axis=-1)
+    ckv, k_rope = jnp.split(mm(xn, p["wkv_a"]), [c.kv_lora_rank], axis=-1)
     ckv = rms_norm(ckv, p["kv_norm_g"], c.rms_norm_eps)
     return jnp.concatenate([ckv, apply_rope(k_rope, cos, sin)], axis=-1)
 
@@ -452,10 +375,10 @@ def _layer(p, X, config, pos, attend, moe, token_mask=None):
     cos, sin = rope_cos_sin(c, pos)
 
     def attention(u):
-        un = rms_norm(u, p["attn_norm_g"], c.rms_norm_eps).astype(_compute(c))
+        un = rms_norm(u, p["attn_norm_g"], c.rms_norm_eps).astype(compute_of(c))
         q_nope, q_rope = mla_q(p, un, c, cos, sin)
         ctx, carry = attend(p, q_nope, q_rope, mla_latent(p, un, c, cos, sin))
-        return _mm(ctx, p["wo"], F32), carry
+        return mm(ctx, p["wo"], F32), carry
 
     X, carry = mhc_sublayer(p, X, c, "attn", attention)
     if moe:
@@ -472,12 +395,6 @@ def _embed(params, config, ids):
     from here to the head (the sublayers multiply in the compute type)."""
     x = params["wte"][ids].astype(F32)
     return jnp.repeat(x[:, :, None, :], config.hc_mult, axis=2)
-
-
-def _final_logits(params, config, h):
-    """The shared final norm (float32) and the untied head over h [..., H]."""
-    hn = rms_norm(h.astype(F32), params["normf_g"], config.rms_norm_eps)
-    return hn @ params["head_w"].astype(F32)
 
 
 def _stack_scan(params, X, layer_fn):
@@ -516,7 +433,7 @@ def forward(params, config, ids, return_hidden=False):
 
     X = _stack_scan(params, _embed(params, config, ids), layer_fn)
     h = jnp.sum(X, axis=2)
-    logits = _final_logits(params, config, h)
+    logits = final_logits(params, config, h)
     return (logits, h) if return_hidden else logits
 
 
@@ -531,13 +448,13 @@ def mtp_logits(params, config, hidden, ids):
     cat = jnp.concatenate([rms_norm(hidden[:, :-1], m["hnorm_g"],
                                     c.rms_norm_eps),
                            rms_norm(emb, m["enorm_g"], c.rms_norm_eps)], -1)
-    h = _mm(cat.astype(_compute(c)), m["eh_proj"], F32)
+    h = mm(cat.astype(compute_of(c)), m["eh_proj"], F32)
     X = jnp.repeat(h[:, :, None, :], c.hc_mult, axis=2)
     pos = jnp.broadcast_to(jnp.arange(T - 1)[None], (B, T - 1))
     mask = jnp.broadcast_to(jnp.tril(jnp.ones((T - 1, T - 1), bool))[None],
                             (B, T - 1, T - 1))
     X, _, _ = _layer(m["block"], X, c, pos, _plain_attend(c, mask), True)
-    return _final_logits(params, c, jnp.sum(X, axis=2))
+    return final_logits(params, c, jnp.sum(X, axis=2))
 
 
 # ---------------------------------------------------------------------------
@@ -584,7 +501,7 @@ def paged_forward(params, config, ids, pools, start, valid, table, page_size):
     idx = jnp.maximum(valid - 1, 0)
     last = jnp.take_along_axis(X, idx[:, None, None, None], axis=1)[:, 0]
     h = jnp.sum(last, axis=1)                                    # [B, H]
-    return _final_logits(params, c, h), (pool,), stats
+    return final_logits(params, c, h), (pool,), stats
 
 
 class _Served(ServedModel):
@@ -606,10 +523,9 @@ class _Served(ServedModel):
         return jax.tree_util.tree_map(jnp.asarray, tree)
 
     def geometry(self, config):
-        return CacheGeometry(names=("latent",),
-                             layers=config.num_hidden_layers,
-                             row=(config.latent_row,),
-                             dtype=config.compute_dtype or "float32")
+        return CacheGeometry.one_group(("latent",), config.num_hidden_layers,
+                                       (config.latent_row,),
+                                       config.compute_dtype or "float32")
 
     def forward(self, params, config, ids, pools, start, valid, table,
                 page_size, **_gpt_options):

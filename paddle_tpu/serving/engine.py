@@ -55,7 +55,7 @@ from .kv_transfer import KVTransfer, PagePayload
 from .paged_attention import (
     pad_lanes, paged_draft_forward, paged_kv_rewind, paged_verify_forward,
 )
-from .paged_kv import PagedKVPool, pages_for
+from .paged_kv import PagedKVPool, pages_for, ring_pages
 from .served_model import GPT, served_model
 from .request import (
     CANCELLED, ERROR, EXPIRED, FINISHED, LENGTH, QUEUED, RUNNING, SHED,
@@ -142,9 +142,10 @@ def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
 
     ``model`` is the served model's seam (serving/served_model.py): its
     forward takes and returns the pools, as many arrays as its cache
-    geometry names (GPT: kc, vc), which ride as the operands after
-    ``params`` and come back first; where the forward returns statistics
-    they are the step's last output."""
+    geometry names over all its groups (GPT: kc, vc), which ride as the
+    operands after ``params`` and come back first; ``table`` is the one
+    group's page table or a tuple of one a group; where the forward
+    returns statistics they are the step's last output."""
     config = model.view(cfg)
     n_pools = len(model.geometry(config).names)
     kvq = quant is not None and quant[1] != "bf16"
@@ -545,7 +546,12 @@ class Engine:
         while self._chunk_ladder[-1] * 2 <= self.prefill_chunk:
             self._chunk_ladder.append(self._chunk_ladder[-1] * 2)
         if prefix_cache is None:
-            prefix_cache = bool(flags.get("FLAGS_serving_prefix_cache", True))
+            # the flag's default is on; a model that cannot share prefixes
+            # yet resolves to off and refuses an explicit True
+            prefix_cache = bool(
+                flags.get("FLAGS_serving_prefix_cache", True)) \
+                and "prefix_cache" not in self._model.unsupported
+        self._refuse("prefix_cache", bool(prefix_cache))
         kv_dtype = self._quant.kv_dtype if self._quant is not None else "bf16"
         pool_kw = {}
         if kv_dtype != "bf16":
@@ -554,11 +560,25 @@ class Engine:
                            k_clip=self._quant.kv_k_clip,
                            v_clip=self._quant.kv_v_clip,
                            qmax=_squant.QMAX[kv_dtype])
-        self.pool = PagedKVPool(
-            B, self.max_seq_len, self.page_size,
-            num_pages=int(num_pages or
-                          flags.get("FLAGS_serving_num_pages", 0) or 0),
-            prefix_cache=prefix_cache, **pool_kw)
+        # an allocator and a page table a group of layers. The first
+        # group's is ``pool``: it takes ``num_pages``, the prefix cache and
+        # copy-on-write; a group with no window maps a request's whole
+        # lifetime, a window group a ring a slot (``ring_pages``),
+        # whatever the context
+        self._group_pools = []
+        for g in geo.groups:
+            first = not self._group_pools
+            slot_tokens = self.max_seq_len if g.window is None else min(
+                self.max_seq_len, self.page_size * ring_pages(
+                    g.window, self._chunk_ladder[-1], self.page_size))
+            self._group_pools.append(PagedKVPool(
+                B, slot_tokens, self.page_size,
+                num_pages=int(num_pages or
+                              flags.get("FLAGS_serving_num_pages", 0) or 0)
+                if first and g.window is None else 0,
+                prefix_cache=prefix_cache and first,
+                **(pool_kw if first else {})))
+        self.pool = self._group_pools[0]
         self._kv_quant = kv_dtype != "bf16"
         use_kernel = bool(flags.get("FLAGS_serving_paged_kernel", True)
                           ) and self._model.kernel_ok(
@@ -600,12 +620,17 @@ class Engine:
         # a row's last axis padded to whole lanes on the device;
         # snapshots and page payloads keep the model's own width
         # (_logical / pad_lanes)
-        shape = geo.pool_shape(self.pool.num_pages, self.page_size)
         if self._kv_quant:
             compute = _squant.STORE_DTYPES[kv_dtype]
-        # the pool arrays a layer keeps, in the geometry's order (GPT: K
-        # and V, also reachable as _kc / _vc)
-        self._pools = tuple(jnp.zeros(shape, compute) for _ in geo.names)
+        # the pool arrays a layer keeps, group after group in the
+        # geometry's order (GPT: K and V, also reachable as _kc / _vc),
+        # and the group each belongs to
+        self._pool_group = tuple(i for i, g in enumerate(geo.groups)
+                                 for _ in g.names)
+        self._pools = tuple(
+            jnp.zeros(geo.groups[i].pool_shape(
+                self._group_pools[i].num_pages, self.page_size), compute)
+            for i in self._pool_group)
         if self._quant is not None:
             metrics.set_quant_info(
                 self._quant.weight_dtype, self._quant.kv_dtype,
@@ -853,11 +878,12 @@ class Engine:
         # page reduces the fresh-page need by one. A request that can NEVER
         # fit must fail fast instead of deadlocking the FCFS queue head.
         worst = pages_for(plen + request.max_new_tokens, self.page_size)
-        if worst > self.pool.num_pages - 1:
-            metrics.bump("rejected")
-            raise ValueError(
-                f"request needs up to {worst} KV pages but the pool "
-                f"only has {self.pool.num_pages - 1}")
+        for pool in self._group_pools:
+            if min(worst, pool.slot_pages) > pool.num_pages - 1:
+                metrics.bump("rejected")
+                raise ValueError(
+                    f"request needs up to {min(worst, pool.slot_pages)} KV "
+                    f"pages but the pool only has {pool.num_pages - 1}")
         if request.top_k not in (None, self.top_k):
             metrics.bump("rejected")
             raise ValueError(
@@ -1073,7 +1099,9 @@ class Engine:
         active = np.array([r is not None for r in self._slots])
         metrics.observe_boundary(self.scheduler.qsize(), int(active.sum()),
                                  self.num_slots)
-        metrics.observe_pages(self.pool.pages_in_use, self.pool.num_pages - 1)
+        metrics.observe_pages(
+            sum(p.pages_in_use for p in self._group_pools),
+            sum(p.num_pages - 1 for p in self._group_pools))
         if active.any():
             self._iterate_paged()
 
@@ -1113,11 +1141,25 @@ class Engine:
                 req.trace.span("mp_comm", t0, t1, bytes=wire,
                                backend=self._mp_cfg.backend, mp=self.mp)
 
-    def _logical(self, pool):
-        """A host copy of a pool array (or of pages of it) at the model's
-        own row width, contiguous: what snapshots, page payloads and the
-        chaos hooks see. The device holds whole lanes."""
-        return self._geo.logical(pool)
+    def _logical(self, pool, group=0):
+        """A host copy of a pool array of ``group`` (or of pages of it) at
+        the model's own row width, contiguous: what snapshots, page
+        payloads and the chaos hooks see. The device holds whole lanes."""
+        return self._geo.groups[group].logical(pool)
+
+    def _table_arg(self, sl=slice(None)):
+        """The step's ``table`` operand for the slots ``sl``: the one
+        group's page table, or a tuple of one a group. Host-authoritative,
+        uploaded with every dispatch."""
+        tables = tuple(jnp.asarray(p.table[sl]) for p in self._group_pools)
+        return tables[0] if len(tables) == 1 else tables
+
+    def _copy_page(self, src, dst):
+        """One physical page of the first group copied onto another (the
+        CoW split): the other groups share no page."""
+        n = len(self._geo.groups[0].names)
+        self._pools = self._page_copy(self._pools[:n], jnp.int32(src),
+                                      jnp.int32(dst)) + self._pools[n:]
 
     def _take(self, out):
         """A fused step's outputs: the pools go back into the engine; the
@@ -1164,8 +1206,7 @@ class Engine:
         page before the dispatch that writes the range."""
         copied = 0
         for src, dst in self.pool.make_writable(b, start, end):
-            self._pools = self._page_copy(self._pools, jnp.int32(src),
-                                          jnp.int32(dst))
+            self._copy_page(src, dst)
             metrics.bump("cow_copies")
             copied += 1
         if copied:
@@ -1226,7 +1267,7 @@ class Engine:
             self.params, *self._pools,
             jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos),
             jnp.asarray(valid), jnp.asarray(emit),
-            jnp.asarray(self.pool.table), jnp.asarray(self._do_sample),
+            self._table_arg(), jnp.asarray(self._do_sample),
             jnp.asarray(self._temp), jnp.asarray(self._top_p),
             jnp.asarray(self._keys), *self._kv_scale_args(),
             *self._adapter_args())
@@ -1287,12 +1328,14 @@ class Engine:
             out = self._paged_step(
                 self.params, *self._pools, jnp.zeros((b, t), jnp.int32),
                 jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32),
-                jnp.zeros(b, bool), jnp.zeros_like(self.pool.table[:b]),
+                jnp.zeros(b, bool),
+                jax.tree_util.tree_map(jnp.zeros_like,
+                                       self._table_arg(slice(0, b))),
                 jnp.zeros(b, bool), jnp.ones(b, jnp.float32),
                 jnp.ones(b, jnp.float32), jnp.zeros((b, 2), jnp.uint32),
                 *self._kv_scale_args(), *self._adapter_args(slice(0, b)))
             self._take(out)
-        self._pools = self._page_copy(self._pools, jnp.int32(0), jnp.int32(0))
+        self._copy_page(0, 0)
         jax.block_until_ready(self._pools)
         return self
 
@@ -1446,7 +1489,7 @@ class Engine:
         out = self._paged_step(
             self.params, *self._pools, jnp.asarray(ids),
             jnp.asarray([off], np.int32), jnp.asarray([v], np.int32),
-            jnp.asarray([emit]), jnp.asarray(self.pool.table[b:b + 1]),
+            jnp.asarray([emit]), self._table_arg(slice(b, b + 1)),
             jnp.asarray(self._do_sample[b:b + 1]),
             jnp.asarray(self._temp[b:b + 1]),
             jnp.asarray(self._top_p[b:b + 1]),
@@ -1887,20 +1930,36 @@ class Engine:
         # request never CoWs against its own registration
         spare_needed = n_shared > 0 and n_shared - 1 >= chunk_start // ps
         need = (total - n_shared) + (1 if spare_needed else 0)
+        # every further group holds the request too, or none does: a
+        # window group's lifetime is capped at its ring, whatever the
+        # context (nothing is shared there, so nothing is looked up)
+        others = [(g, min(total, g.slot_pages))
+                  for g in self._group_pools[1:]]
         if probe:
             # capacity question only (preemption policy): answered without
             # allocating — pool.try_alloc would EVICT cache entries to
             # satisfy a transient probe, churning the very prefix pages
             # (possibly this request's own) the reservation depends on
-            ok = pool.can_alloc(need)
+            ok = pool.can_alloc(need) and all(g.can_alloc(n)
+                                              for g, n in others)
             pool.decref(shared)
             return ok
         got = pool.try_alloc(need)
-        if got is None:
-            pool.decref(shared)
+        got_others = []
+        if got is not None:
+            for g, n in others:
+                pages = g.try_alloc(n)
+                if pages is None:
+                    break
+                got_others.append(pages)
+        if got is None or len(got_others) < len(others):
+            # whichever group is short, the request waits with nothing held
+            pool.decref(shared + (got or []))
+            for (g, _), pages in zip(others, got_others):
+                g.decref(pages)
             return False
         spare = got.pop() if spare_needed else None
-        req._page_plan = (chunk_start, shared, got, spare)
+        req._page_plan = (chunk_start, shared, got, spare, got_others)
         # ledger per successful ADMISSION (fits may poll a waiting head
         # many times; that must not dilute the hit rate)
         if pool.prefix_cache_enabled:
@@ -1919,10 +1978,13 @@ class Engine:
         pages cover the rest of prompt + max_new_tokens. No forward pass
         happens here — the prompt prefills chunk-by-chunk inside the fused
         step, interleaved with every other slot's decode."""
-        chunk_start, shared, private, spare = req._page_plan
+        chunk_start, shared, private, spare, others = req._page_plan
         del req._page_plan
         self._observe_admission(req, b)
         self.pool.map_slot(b, list(shared) + list(private), spare)
+        for g, pages in zip(self._group_pools[1:], others):
+            g.map_slot(b, pages)
+        self._count_mapped_pages(req, b)
         req.state = RUNNING
         req.slot = b
         req.params_version = self.params_version
@@ -1957,6 +2019,23 @@ class Engine:
             self._outbound[req.request_id] = tr
             self._fresh_outbound.append(tr)
             self._stream_pages(b, tr)
+
+    def _count_mapped_pages(self, req, b):
+        """Where a group of layers attends to a window: the pages this
+        admission mapped in the groups with no window and in those with
+        one, and what the latter would have mapped with no cap, each times
+        its group's layers. From the host's tables; no sync."""
+        if all(g.window is None for g in self._geo.groups):
+            return
+        lifetime = pages_for(req.prompt_len + req.max_new_tokens,
+                             self.page_size)
+        for g, pool in zip(self._geo.groups, self._group_pools):
+            mapped = g.layers * int(np.count_nonzero(pool.table[b]))
+            if g.window is None:
+                metrics.bump("kv_pages_mapped_full", mapped)
+            else:
+                metrics.bump("kv_pages_mapped_window", mapped)
+                metrics.bump("kv_pages_unwindowed", g.layers * lifetime)
 
     def _quarantine(self, req, b):
         """Anomaly-guard resolution (``FLAGS_serving_anomaly_policy=
@@ -2020,7 +2099,8 @@ class Engine:
             metrics.observe_adapter_tokens(int(self._aid[b]),
                                            len(req.tokens))
         self._aid[b] = 0
-        self.pool.release_slot(b)
+        for pool in self._group_pools:
+            pool.release_slot(b)
 
     def _observe_admission(self, req, b):
         """Admission into slot b, one instant for all it feeds: the
@@ -2298,6 +2378,8 @@ class Engine:
                 "page_size": self.page_size,
                 "prefill_chunk": self.prefill_chunk,
                 "num_pages": self.pool.num_pages}
+        if len(self._group_pools) > 1:
+            meta["group_pages"] = [p.num_pages for p in self._group_pools]
         return meta
 
     @staticmethod
@@ -2327,7 +2409,8 @@ class Engine:
         unpopped results, and the serving metrics ledger. Safe for
         ``CheckpointManager``/``framework.io`` round trips; pair with
         ``load_state_dict`` for bitwise mid-decode resume."""
-        pools_np = [self._logical(jax.device_get(a)) for a in self._pools]
+        pools_np = [self._logical(jax.device_get(a), g)
+                    for a, g in zip(self._pools, self._pool_group)]
         if pools_np[0].dtype not in (np.int8, np.float32, np.float64,
                                      np.float16):
             # fp8/bf16 pools: numpy IO paths don't all speak ml_dtypes —
@@ -2359,6 +2442,11 @@ class Engine:
             "snapshot_wall": time.time(),
             "pool": self.pool.state_dict(),
         }
+        if len(self._group_pools) > 1:
+            # the further groups' tables and allocators (a window group's
+            # ring among them) ride beside the first's
+            state["group_pools"] = [p.state_dict()
+                                    for p in self._group_pools[1:]]
         if self.adapters is not None:
             # the resident adapter SET rides every snapshot: a restored
             # (or supervisor-respawned) engine serves the same many-model
@@ -2462,6 +2550,9 @@ class Engine:
         self._admit_t = [time.perf_counter()] * self.num_slots
         self._step_count = int(state["step_count"])
         self.pool.load_state_dict(state["pool"])
+        for pool, st in zip(self._group_pools[1:],
+                            state.get("group_pools", ())):
+            pool.load_state_dict(st)
         # in-flight transfer state is NOT part of a snapshot (the
         # KVTransfer objects live with the supervisor, which replays or
         # re-offers them): staged pages restored by the pool have no
@@ -2686,7 +2777,8 @@ class Engine:
         if self._kv_quant:
             # two fp32 scales per (layer, page), shared by page_size
             # tokens — rounded UP so the gauge never underreports to 0
-            per_tok += -(-2 * self._geo.layers * 4 // self.page_size)
+            per_tok += -(-2 * self._geo.groups[0].layers * 4
+                         // self.page_size)
         return per_tok
 
     def kv_shard_bytes(self):

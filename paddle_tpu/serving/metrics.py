@@ -145,6 +145,13 @@ def _zero():
         "moe_assignments_decode": 0, "moe_assignments_chunk": 0,
         "moe_touched_decode": 0, "moe_touched_chunk": 0,
         "moe_load_max": 0,
+        # a cache of several groups of layers, one of them a window group
+        # (models/afmoe.py), at each admission: pages mapped for the
+        # request in the groups with no window and in the window groups
+        # (a ring a slot), and what the latter would have mapped with no
+        # cap, each times its group's layers
+        "kv_pages_mapped_full": 0, "kv_pages_mapped_window": 0,
+        "kv_pages_unwindowed": 0,
         # occupancy: sum of active slots over decode steps / (steps * slots)
         "active_slot_steps": 0, "slot_steps": 0,
         # queue depth observed at step boundaries

@@ -343,17 +343,19 @@ def _quantize_kv(x, sc, dtype):
     return jnp.clip(scaled, float(info.min), float(info.max)).astype(dtype)
 
 
-def _write_slots(table, pos, writable, page_size):
+def _write_slots(table, pos, writable, page_size, ring=False):
     """(phys, off) [B, T] of the pool rows the window positions pos map
     to through the table; lanes that are not ``writable`` route to trash
-    page 0."""
-    li = jnp.minimum(pos // page_size, table.shape[1] - 1)
+    page 0. With ``ring`` the table row is a ring: position p lives in
+    logical page ``(p // page_size) mod`` the row's width."""
+    li = pos // page_size
+    li = li % table.shape[1] if ring else jnp.minimum(li, table.shape[1] - 1)
     phys = jnp.where(writable, jnp.take_along_axis(table, li, axis=1), 0)
     return phys, pos % page_size
 
 
 def paged_kv_scatter(kc, vc, l, k, v, table, pos, valid, page_size,
-                     ksc_l=None, vsc_l=None):
+                     ksc_l=None, vsc_l=None, ring=False):
     """Scatter one window's K/V [B, T, nh', d] into layer ``l`` (traced
     scalar) of the WHOLE paged pool kc/vc [L, P, page_size, nh',
     pool_head_dim(d)] through the slot->page table: logical page -> physical; lanes past
@@ -364,9 +366,11 @@ def paged_kv_scatter(kc, vc, l, k, v, table, pos, valid, page_size,
     single-chip, the local shard under mp (the table is
     head-independent). With a quantized pool the layer's per-page scales
     ksc_l/vsc_l [P] quantize the write in place (trash page 0 keeps scale
-    1.0; its garbage is never read unmasked)."""
+    1.0; its garbage is never read unmasked). ``nh'`` may also be a model's
+    KV heads, fewer than its query heads. ``ring``: the table row is a
+    window group's ring (``_write_slots``)."""
     phys, off = _write_slots(table, pos, jnp.arange(pos.shape[1])[None, :]
-                             < valid[:, None], page_size)
+                             < valid[:, None], page_size, ring)
     if ksc_l is not None:
         k = _quantize_kv(k, ksc_l[phys], kc.dtype)
         v = _quantize_kv(v, vsc_l[phys], vc.dtype)
@@ -375,8 +379,67 @@ def paged_kv_scatter(kc, vc, l, k, v, table, pos, valid, page_size,
     return kc, vc
 
 
+def ring_key_positions(table_pages, last_pos, page_size):
+    """The absolute position [B, table_pages * page_size] that each entry
+    of a slot's ring holds once the window ending at ``last_pos`` [B] is
+    written: ring page r holds the newest page congruent to r that is not
+    past the frontier page. An entry never written reads as a position
+    below 0 or past the window's end (its page's older lap, not yet
+    overwritten, is labelled with the new lap's positions), so the mask by
+    absolute position gives it an exact zero."""
+    front = (last_pos // page_size)[:, None]                    # [B, 1]
+    r = jnp.arange(table_pages)[None, :]
+    page = front - (front - r) % table_pages                    # [B, R]
+    return (page[:, :, None] * page_size
+            + jnp.arange(page_size)[None, None, :]).reshape(
+                last_pos.shape[0], -1)
+
+
+def window_mask(pos_q, pos_k, window=None):
+    """Whether the query at absolute position pos_q [B, T] sees the key at
+    pos_k [B | 1, S]: ``0 <= j <= i`` and, with ``window``, ``i - j <
+    window``. [B, T, S]."""
+    behind = pos_q[:, :, None] - pos_k[:, None, :]
+    mask = (behind >= 0) & (pos_k >= 0)[:, None, :]
+    return mask if window is None else mask & (behind < window)
+
+
+def grouped_attend(q, k, v, mask, out_dtype):
+    """Attention of q [B, T, nh, d] over k and v [B, S, nkv, d] under mask
+    [B, T, S], float32 inside: query head j reads KV head ``j // (nh /
+    nkv)`` (the heads of a group contiguous). Masked keys contribute exact
+    zeros."""
+    B, T, nh, d = q.shape
+    nkv = k.shape[2]
+    qg = q.reshape(B, T, nkv, nh // nkv, d)
+    scores = jnp.einsum("btkgd,bskd->bkgts", qg.astype(jnp.float32),
+                        k.astype(jnp.float32)) / (d ** 0.5)
+    scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    ctx = jnp.einsum("bkgts,bskd->btkgd", probs, v.astype(jnp.float32))
+    return ctx.reshape(B, T, nh, d).astype(out_dtype)
+
+
+def _grouped_read(q, kc, vc, l, table, pos, page_size, out_dtype, window):
+    """The gather read for a pool of fewer KV heads than query heads and,
+    with ``window`` (positions), of a ring: the table row is gathered whole
+    (the ring's pages, never the context's), and a key counts by its
+    ABSOLUTE position (``window_mask``)."""
+    B, _, _, d = q.shape
+    nkv = kc.shape[3]
+    S = table.shape[1] * page_size
+    kv_k = kc[l, table][..., :d].reshape(B, S, nkv, d)
+    kv_v = vc[l, table][..., :d].reshape(B, S, nkv, d)
+    if window is None:
+        key_pos = jnp.arange(S)[None, :]
+    else:
+        key_pos = ring_key_positions(table.shape[1], pos[:, -1], page_size)
+    return grouped_attend(q, kv_k, kv_v, window_mask(pos, key_pos, window),
+                          out_dtype)
+
+
 def paged_attention_read(q, kc, vc, l, table, pos, page_size, use_kernel,
-                         out_dtype, ksc_l=None, vsc_l=None):
+                         out_dtype, ksc_l=None, vsc_l=None, window=None):
     """Paged attention read: q [B, T, nh', d] against layer ``l`` (traced
     scalar) of the WHOLE pool kc/vc [L, P, page_size, nh',
     pool_head_dim(d)] through the table; returns ctx [B, T, nh', d] in ``out_dtype``. The layer is
@@ -392,9 +455,16 @@ def paged_attention_read(q, kc, vc, l, table, pos, page_size, use_kernel,
     scale, so the multiply factors out of the contraction and both read
     branches below compute bit-identical scores; V dequantizes after its
     gather. The per-dtype exactness contract (mp == single-chip,
-    order/restore invariance) rides on this branch-consistency."""
+    order/restore invariance) rides on this branch-consistency.
+
+    A pool of fewer KV heads than q has, or a ``window`` (the table row is
+    then a ring), takes ``_grouped_read``: no kernel, no quantized pool."""
     B, T, nh, d = q.shape
     MP = table.shape[1]
+    if kc.shape[3] != nh or window is not None:
+        assert not use_kernel and ksc_l is None
+        return _grouped_read(q, kc, vc, l, table, pos, page_size, out_dtype,
+                             window)
 
     if use_kernel and T == 1:
         if ksc_l is not None:

@@ -61,6 +61,14 @@ def pages_for(tokens, page_size):
     return -(-int(tokens) // int(page_size))
 
 
+def ring_pages(window, chunk, page_size):
+    """Pages of a window group's ring: a dispatch that writes positions
+    [s, s + chunk) reads back to s - window + 1, and the page its last
+    write lands in must not be the ring slot of the first page it reads;
+    s need not be page-aligned. (window 2048, chunk 512, page 16: 161.)"""
+    return (int(window) + int(chunk) - 2) // int(page_size) + 2
+
+
 class PagedKVPool:
     """Host-side page bookkeeping: allocator + slot page table + prefix
     cache. Device KV arrays live in the engine; this class only decides

@@ -2,22 +2,34 @@
 
 The engine asks a model for two things and nothing else:
 
-* its **cache geometry** (``CacheGeometry``): how many pool arrays a layer
-  keeps, a token's row in each, the storage type. The engine allocates
-  ``[layers, pages, page_size, *row]`` arrays from it, copies, snapshots and
-  counts their pages and bytes, and never looks inside a row;
+* its **cache geometry** (``CacheGeometry``): one or more GROUPS of layers
+  (``CacheGroup``), each with the pool arrays a layer of it keeps, a token's
+  row in each and, where the group's layers attend to a window, the window.
+  For every group the engine allocates ``[layers, pages, page_size, *row]``
+  arrays and keeps a page table and an allocator of its own
+  (``PagedKVPool``); it copies, snapshots and counts their pages and bytes
+  and never looks inside a row. A group with no window is mapped for a
+  request's whole lifetime. A window group's slot is a RING of
+  ``ring_pages(window, widest chunk, page_size)`` pages whatever the
+  context: position p lives in logical page ``(p // page_size) mod`` that
+  many. A request is admitted when every group can hold it;
 * its **paged forward**: ``forward(params, config, ids, pools, start, valid,
   table, page_size, ...) -> (logits [B, V], pools, stats)``, the fused
-  chunk/decode step over those pools (the pools the layer scan's carry).
-  ``stats`` is None or one small array that leaves the device with the
-  tokens and goes to ``record(stats, kind, config)`` on the host.
+  chunk/decode step over those pools (the pools the layer scans' carry).
+  ``pools`` is every group's arrays in the geometry's order, flat; ``table``
+  is the one group's table ``[B, pages]`` or, with several groups, a tuple
+  of them in the groups' order. ``stats`` is None or one small array that
+  leaves the device with the tokens and goes to ``record(stats, kind,
+  config)`` on the host.
 
 A configuration object names its model through a ``served_model`` attribute;
 one without it is the GPT family, whose seam is here. Scheduler, admission,
-page table, prefix cache, copy-on-write, chunk ladder, sampling and the phase
-clock are the engine's and shared by every model; what a model does not
-support yet (``unsupported``: spec, quant, adapters, mp, kv_transfer)
-the engine refuses by name at construction."""
+page tables, chunk ladder, sampling and the phase clock are the engine's and
+shared by every model; what a model does not support yet (``unsupported``:
+spec, quant, adapters, mp, kv_transfer, prefix_cache) the engine refuses by
+name at construction. Prefix sharing and copy-on-write work on the first
+group alone, so a model with a window group lists ``prefix_cache``: a ring
+page holds different positions over a request's life."""
 from __future__ import annotations
 
 import dataclasses
@@ -31,14 +43,16 @@ from .paged_attention import paged_forward, paged_kernel_supported, \
 
 
 @dataclasses.dataclass(frozen=True)
-class CacheGeometry:
-    """A model's paged cache: ``names`` one per pool array a layer keeps,
-    ``row`` a token's row in each as the model writes it (GPT: ``(nh, d)``
-    twice; a latent cache: ``(576,)`` once), ``dtype`` its storage type."""
+class CacheGroup:
+    """The layers of a model that share one page table: ``names`` one per
+    pool array a layer keeps, ``row`` a token's row in each as the model
+    writes it (GPT: ``(nh, d)`` twice; grouped heads: ``(kv heads, d)``; a
+    latent cache: ``(576,)`` once), ``window`` None or the positions a
+    layer of the group attends to (its slot is then a ring)."""
     names: tuple
     layers: int
     row: tuple
-    dtype: str
+    window: int = None
 
     def pool_shape(self, num_pages, page_size):
         """On the device a row's last axis is whole lanes (``pool_head_dim``:
@@ -51,6 +65,23 @@ class CacheGeometry:
         own row width, contiguous: what snapshots, page payloads and the
         chaos hooks see."""
         return np.ascontiguousarray(np.asarray(pool)[..., :self.row[-1]])
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheGeometry:
+    """A model's paged cache: its groups of layers (most models have one)
+    and the storage type of every pool array."""
+    groups: tuple
+    dtype: str
+
+    @classmethod
+    def one_group(cls, names, layers, row, dtype):
+        return cls((CacheGroup(tuple(names), layers, tuple(row)),), dtype)
+
+    @property
+    def names(self):
+        """Every pool array's name, in the order the step takes them."""
+        return tuple(n for g in self.groups for n in g.names)
 
 
 class ServedModel:
@@ -101,9 +132,9 @@ class _GPTServed(ServedModel):
 
     def geometry(self, config):
         nh = config.num_heads
-        return CacheGeometry(("kc", "vc"), config.num_layers,
-                             (nh, config.hidden_size // nh),
-                             str(jnp.dtype(config.compute_dtype or "float32")))
+        return CacheGeometry.one_group(
+            ("kc", "vc"), config.num_layers, (nh, config.hidden_size // nh),
+            str(jnp.dtype(config.compute_dtype or "float32")))
 
     def kernel_ok(self, config, mp, page_size):
         nh = config.num_heads

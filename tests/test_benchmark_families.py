@@ -3,7 +3,8 @@
 names a family with its four files and their functions, every workload's
 cell resolves, an unknown or half family is refused in a sentence) and the
 rehearsal of the xing4 family (``benchmark/tests/test_rehearsal_xing4.py``: a
-whole serving run at a toy width to ``correct`` on the CPU) run here too,
+whole serving run at a toy width to ``correct`` on the CPU) and of the afmoe
+family (``test_rehearsal_afmoe.py``, with its planted faults) run here too,
 imported and not copied, so that a cell or a family that no longer loads
 fails the tests the driver runs. So do the readers of the xing4 cell's own
 per-layer metrics on their hand-made context
@@ -47,5 +48,8 @@ def _keep_the_workers_arrays(monkeypatch):
 globals().update(_cases("test_families"))
 globals().update(_cases("test_rehearsal_xing4"))
 globals().update(_cases("test_xing4_readers"))
+globals().update({k + "_afmoe" if k in globals() else k: v for k, v in
+                  _cases("test_rehearsal_afmoe").items()})
+globals().update(_cases("test_afmoe_readers"))
 globals().update(_cases("test_program_span_readers"))
 globals().update(_cases("test_greedy_tail_share"))

@@ -27,7 +27,7 @@ if ROOT not in sys.path:
 
 from benchmark.harness import loader, reference as R  # noqa: E402
 from paddle_tpu import profiler, serving  # noqa: E402
-from paddle_tpu.models import xing4 as X  # noqa: E402
+from paddle_tpu.models import moe as MOE, xing4 as X  # noqa: E402
 from paddle_tpu.serving import engine as E  # noqa: E402
 from paddle_tpu.serving.paged_attention import pool_head_dim  # noqa: E402
 
@@ -102,7 +102,7 @@ def test_chunks_down_the_ladder_then_decode_match_the_full_forward(
     engine's rungs (32, then the tail in a padded 8), then seven tokens one
     at a time: positions 37..43 cross the page boundary at 40. Every logit
     row the step returns equals the reference's row of the full forward."""
-    geo = PC.served_model.geometry(PC)
+    (geo,) = PC.served_model.geometry(PC).groups
     pools = (jnp.zeros(geo.pool_shape(12, PAGE), jnp.float32),)
     table = jnp.asarray([[3, 5, 1, 7, 9, 2, 0, 0]], jnp.int32)
     step = jax.jit(lambda p, i, pl, s, v: X.paged_forward(
@@ -269,7 +269,7 @@ def test_counters_count_what_a_hand_made_routing_says(weights):
     moe["router_w"] = jnp.zeros_like(moe["router_w"])
     moe["router_bias"] = jnp.zeros_like(moe["router_bias"])
     tree["moe"] = moe
-    geo = PC.served_model.geometry(PC)
+    (geo,) = PC.served_model.geometry(PC).groups
     pools = (jnp.zeros(geo.pool_shape(6, PAGE), jnp.float32),)
     table = jnp.asarray([[1], [2], [3], [4]], jnp.int32)
     toks = np.array([[5], [5], [6], [0]], np.int32)
@@ -280,14 +280,14 @@ def test_counters_count_what_a_hand_made_routing_says(weights):
         idx = jnp.stack([tok % 8, (tok + 1) % 8], axis=-1)
         return idx, jnp.ones((n, 2), jnp.float32)
 
-    orig = X.moe_route
-    X.moe_route = routed
+    orig = MOE.moe_route
+    MOE.moe_route = routed
     try:
         _, _, stats = X.paged_forward(
             tree, PC, toks, pools, jnp.zeros(4, jnp.int32),
             jnp.asarray([1, 1, 1, 0]), table, PAGE)
     finally:
-        X.moe_route = orig
+        MOE.moe_route = orig
     assert [int(s) for s in stats] == [2 * 6, 2 * 3, 3]
 
     # and the engine's ledger takes them, by kind of dispatch
@@ -313,8 +313,9 @@ def test_geometry_warm_up_and_snapshot(weights):
     eng = _engine(weights, num_slots=3).warm_up()    # 3 slots: fresh shapes
     warm = profiler.serving_counters()["paged_traces"]
     assert warm == 3 + 1                              # rungs 8, 16, 32; [3,1]
-    geo = eng._geo
+    (geo,) = eng._geo.groups                          # one group, no window
     assert geo.names == ("latent",) and geo.row == (PC.latent_row,)
+    assert geo.window is None and eng._group_pools == [eng.pool]
     assert len(eng._pools) == 1
     assert eng._pools[0].shape == (3, eng.pool.num_pages, PAGE, 128)
     assert eng.kv_bytes_per_token() == 3 * 128 * 4
