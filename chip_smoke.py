@@ -432,8 +432,8 @@ def leg_kernels(cfg, *, batch, seq, num_slots, page_size, max_seq_len,
         flash_attention_bshd)
     from paddle_tpu.ops.pallas_kernels.quant_gemm import quant_gemm_kernel
     from paddle_tpu.serving.paged_attention import (
-        paged_attention_read, paged_decode_attention,
-        paged_decode_attention_q)
+        pad_lanes, paged_attention_read, paged_decode_attention,
+        paged_decode_attention_q, pool_head_dim)
 
     leg = Leg()
     mosaic = not interpret
@@ -466,20 +466,17 @@ def leg_kernels(cfg, *, batch, seq, num_slots, page_size, max_seq_len,
     # paged decode, full-precision and int8 pool, every slot at a different
     # depth of its page list (first page, page boundary, last position);
     # the engine's call: the last layer of a stacked pool, addressed in
-    # place by the kernel's index_map and by the gather's start indices
+    # place by the kernel's index_map and by the gather's start indices.
+    # Then twice the heads of 5/8 the head_dim in the same hidden size
+    # (GPT-3 2.7B's 32 heads of 80 beside 1.3B's 16 of 128): the pool holds
+    # it in 128 lanes, the pad zeros, and the kernel widens the query
     MP = max_seq_len // page_size
     P = num_slots * MP + 1
-    q = jnp.asarray(rng.standard_normal((num_slots, nh, d)), jnp.float32)
     table = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(
         num_slots, MP), jnp.int32)
     pos = jnp.asarray(np.linspace(0, max_seq_len - 1, num_slots).round(),
                       jnp.int32).at[1].set(page_size - 1).at[2].set(page_size)
     layer = jnp.asarray(1, jnp.int32)
-    shape = (2, P, page_size, nh, d)
-    kc, vc = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-              for _ in range(2))
-    kq, vq = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
-              for _ in range(2))
     ksc, vsc = (jnp.asarray(rng.uniform(0.005, 0.02, (P,)), jnp.float32)
                 for _ in range(2))
 
@@ -488,18 +485,29 @@ def leg_kernels(cfg, *, batch, seq, num_slots, page_size, max_seq_len,
                                     pos[:, None], page_size, False,
                                     jnp.float32, *scales)[:, 0]
 
-    args = (q, kc, vc, table, pos)
-    _kernel_case(leg, "paged_decode",
-                 lambda *a: paged_decode_attention(
-                     *a, page_size=page_size, layer=layer,
-                     interpret=interpret),
-                 args, jax.jit(gather_read)(*args), mosaic)
-    args = (q, kq, vq, table, pos, ksc, vsc)
-    _kernel_case(leg, "paged_decode_q",
-                 lambda *a: paged_decode_attention_q(
-                     *a, page_size=page_size, layer=layer,
-                     interpret=interpret),
-                 args, jax.jit(gather_read)(*args), mosaic)
+    for tag, heads, dim in (("", nh, d), (f"_d{d * 5 // 8}", 2 * nh,
+                                          d * 5 // 8)):
+        q = jnp.asarray(rng.standard_normal((num_slots, heads, dim)),
+                        jnp.float32)
+        shape = (2, P, page_size, heads, dim)
+        like = jnp.zeros((1, pool_head_dim(dim)))
+        kc, vc = (pad_lanes(jnp.asarray(rng.standard_normal(shape),
+                                        jnp.bfloat16), like)
+                  for _ in range(2))
+        kq, vq = (pad_lanes(jnp.asarray(rng.integers(-127, 128, shape),
+                                        jnp.int8), like) for _ in range(2))
+        args = (q, kc, vc, table, pos)
+        _kernel_case(leg, "paged_decode" + tag,
+                     lambda *a: paged_decode_attention(
+                         *a, page_size=page_size, layer=layer,
+                         interpret=interpret),
+                     args, jax.jit(gather_read)(*args), mosaic)
+        args = (q, kq, vq, table, pos, ksc, vsc)
+        _kernel_case(leg, "paged_decode_q" + tag,
+                     lambda *a: paged_decode_attention_q(
+                         *a, page_size=page_size, layer=layer,
+                         interpret=interpret),
+                     args, jax.jit(gather_read)(*args), mosaic)
 
     # weight-only int8 GEMM at the qkv and ffn-up widths, one decode batch
     for F in (3 * H, cfg.ffn_mult * H):

@@ -21,7 +21,10 @@ Two implementations of the decode-attention read:
   (``paged_kernel_supported``), mirroring the flash-attention routing.
 
 The pool's last axis is ``pool_head_dim(d)``: head_dim padded up to the
-TPU's 128 lanes, the pad never read. The pool is never sliced, copied or
+TPU's 128 lanes, the pad zeros. The gather reads take ``[..., :d]``; the
+kernel fetches whole rows, pad and all, and multiplies the pad by the zero
+lanes of a query widened to the pool's lanes (GPT-3 2.7B: head_dim 80 in 128
+lanes). The pool is never sliced, copied or
 re-stacked by a step: it is the layer
 scan's CARRY, each layer writes and reads it at ``[l, ...]`` (one scatter of
 the window's rows; the kernel's index_map or the page gather's start
@@ -86,8 +89,9 @@ def pool_head_dim(d):
     the step converts the whole pool on the way in and on the way out of
     every dispatch. (Pinning row-major with ``jax.experimental.layout``
     does not survive the persistent compile cache: PERF.md, PR 26.) The
-    pad lanes hold zeros (``pad_lanes``) and are read by nothing: every
-    read below takes ``[..., :d]``."""
+    pad lanes hold zeros (``pad_lanes``). The gather reads below take
+    ``[..., :d]``; the decode kernel fetches the whole row and meets the pad
+    with the zero lanes of its widened query (``_paged_decode_call``)."""
     return -(-d // 128) * 128
 
 
@@ -99,19 +103,26 @@ def pad_lanes(x, like):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
-def paged_kernel_supported(nh, d, page_size, why=""):
+def paged_kernel_supported(nh, d, page_size, why="", itemsize=2):
     """Routing predicate for the Pallas paged-decode kernel (same pattern
     as ops.pallas_kernels.flash_supported): TPU backend + Mosaic-friendly
-    shapes, logged fallback otherwise."""
+    shapes, logged fallback otherwise. Any head_dim is taken: a pool row is
+    ``pool_head_dim(d)`` lanes, whole tiles whatever ``d``. What is checked
+    of the rows is that the kernel's double buffer of them (``itemsize``
+    bytes a value: the pool's storage dtype) fits its share of VMEM."""
     reasons = []
     if jax.default_backend() != "tpu":
         reasons.append("backend is not TPU")
-    if d % 128 != 0:
-        reasons.append(f"head_dim {d} not a multiple of 128")
     if nh % 8 != 0:
         reasons.append(f"num_heads {nh} not a multiple of 8")
     if page_size % 8 != 0:
         reasons.append(f"page_size {page_size} not a multiple of 8")
+    # K and V, two halves each, of a step's pages [page, nh, lanes]
+    buffers = (2 * 2 * _SWEEP_PAGES * page_size * nh * pool_head_dim(d)
+               * itemsize)
+    if buffers > _SWEEP_VMEM_BYTES:
+        reasons.append(f"the page buffers ({buffers >> 20} MiB) pass "
+                       f"{_SWEEP_VMEM_BYTES >> 20} MiB of VMEM")
     if reasons:
         logger.info("paged decode kernel fallback to jnp gather%s: %s",
                     f" ({why})" if why else "", "; ".join(reasons))
@@ -129,6 +140,12 @@ def paged_kernel_supported(nh, d, page_size, why=""):
 # (4, 8 and 16 read within 5% on a v5e: the walk, not the fetch, is the
 # bound; PERF.md section 6, PR 31)
 _SWEEP_PAGES = 8
+# what the K and V double buffers may take of the VMEM a Mosaic kernel is
+# given (the query and output blocks [slots, nh, lanes] and the softmax
+# state share the rest). Compiled for a described v5e at 16 slots: 12 MiB
+# (96 heads x 128 lanes, bf16) are taken, 16 MiB (128 heads) refused; GPT-3
+# 2.7B's 32 heads take 4 MiB
+_SWEEP_VMEM_BYTES = 12 << 20
 
 
 def _decode_kernel(*refs, page_size, scale, quant, table_pages):
@@ -142,8 +159,15 @@ def _decode_kernel(*refs, page_size, scale, quant, table_pages):
     fetch is exposed. A table entry past a slot's last live page is never
     looked at: no fetch (not of trash page 0 either), no compute.
 
+    Two widths: the pool's ``lanes`` (its last axis, whole 128-lane tiles)
+    and the model's head_dim ``d <= lanes``. The kernel sees only lanes:
+    q, the page buffers, the accumulator and the output block are ``lanes``
+    wide, the query's lanes past ``d`` are zeros (so a score is the d-lane
+    dot product whatever the pool's pad holds) and ``scale`` is the
+    caller's ``1/sqrt(d)``.
+
     A page is walked one key position at a time on the VPU: position s is
-    a native [nh, d] tile, its score column is a lane reduction and its
+    a native [nh, lanes] tile, its score column is a lane reduction and its
     context contribution a broadcast multiply-add, folded into the online
     softmax state (m, l, acc in VMEM scratch) once a page; the last live
     page is masked by position. No dot_general: the per-head contraction
@@ -214,7 +238,7 @@ def _decode_kernel(*refs, page_size, scale, quant, table_pages):
         # and for the masked tail of its last (c)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_ref[:, :1]
-        pv = jnp.zeros(acc_ref.shape, jnp.float32)           # [nh, d]
+        pv = jnp.zeros(acc_ref.shape, jnp.float32)           # [nh, lanes]
         for s, c in enumerate(cols):
             p = jnp.exp(c - m_new)
             l_new = l_new + p
@@ -242,7 +266,7 @@ def _decode_kernel(*refs, page_size, scale, quant, table_pages):
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
         fetch(b, j, half, lambda dma: dma.wait())
-        q = q_ref[b].astype(jnp.float32)                     # [nh, d]
+        q = q_ref[b].astype(jnp.float32)                     # [nh, lanes]
         for_live_pages(b, j, functools.partial(walk, b, j, half, q))
 
         @pl.when(slot_done)
@@ -259,19 +283,29 @@ def _decode_kernel(*refs, page_size, scale, quant, table_pages):
 def _paged_decode_call(q, kc, vc, layer, table, pos, scales, page_size,
                        interpret):
     """pallas_call shared by the fp and quantized-pool entry points. kc/vc
-    are the WHOLE pool [L, P, page_size, nh, d], left in HBM, and ``layer``
-    a traced scalar: it rides as the first scalar-prefetch operand and the
-    kernel's page fetches address (layer, phys page), so no layer of the
-    pool is sliced out (and copied) for the kernel. ``layer=None`` takes one
-    layer's [P, page_size, nh, d] (a free leading axis, layer 0).
+    are the WHOLE pool [L, P, page_size, nh, lanes], left in HBM, and
+    ``layer`` a traced scalar: it rides as the first scalar-prefetch operand
+    and the kernel's page fetches address (layer, phys page), so no layer of
+    the pool is sliced out (and copied) for the kernel. ``layer=None`` takes
+    one layer's [P, page_size, nh, lanes] (a free leading axis, layer 0).
     ``scales`` is () or that layer's (ksc_l, vsc_l) [P] fp32, prefetched
-    to SMEM after the flat table and pos."""
+    to SMEM after the flat table and pos.
+
+    Two widths, both read off the operands: the model's head_dim ``d`` is
+    q's last axis, the pool's ``lanes`` is kc's (``pool_head_dim(d)``).
+    Where they differ q is widened with zeros to the lanes, every block and
+    scratch buffer is ``lanes`` wide, the scores keep ``1/sqrt(d)`` and the
+    output's pad lanes are cut; where they are one (d a multiple of 128)
+    neither the pad nor the cut is traced."""
     if layer is None:
         kc, vc, layer = kc[None], vc[None], 0
     B, nh, d = q.shape
-    whole = pl.BlockSpec((B, nh, d), lambda i, *prefetch: (0, 0, 0))
+    lanes = kc.shape[-1]
+    if lanes != d:
+        q = pad_lanes(q, kc)
+    whole = pl.BlockSpec((B, nh, lanes), lambda i, *prefetch: (0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    pages = (2, _SWEEP_PAGES, page_size, nh, d)
+    pages = (2, _SWEEP_PAGES, page_size, nh, lanes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # layer, flat table, pos[, scales]
         num_scalar_prefetch=3 + len(scales),
@@ -284,7 +318,7 @@ def _paged_decode_call(q, kc, vc, layer, table, pos, scales, page_size,
             pltpu.SemaphoreType.DMA((2, 2)),         # (k | v, half)
             pltpu.VMEM((nh, 128), jnp.float32),      # m (lane-broadcast)
             pltpu.VMEM((nh, 128), jnp.float32),      # l
-            pltpu.VMEM((nh, d), jnp.float32),        # acc
+            pltpu.VMEM((nh, lanes), jnp.float32),    # acc
         ],
     )
     kernel = functools.partial(_decode_kernel, page_size=page_size,
@@ -293,15 +327,16 @@ def _paged_decode_call(q, kc, vc, layer, table, pos, scales, page_size,
     # Mosaic rejects x64-typed index math; the framework enables x64 globally
     # for dtype parity, so pin 32-bit types inside the kernel trace.
     with jax.enable_x64(False):
-        return pl.pallas_call(
+        ctx = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, nh, d), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((B, nh, lanes), jnp.float32),
             interpret=interpret,
         )(jnp.asarray(layer, jnp.int32).reshape(1),
           table.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
           *(sc.astype(jnp.float32) for sc in scales),
           q.astype(jnp.float32), kc, vc)
+    return ctx if lanes == d else ctx[..., :d]
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
@@ -320,9 +355,10 @@ def paged_decode_attention(q, kc, vc, table, pos, *, page_size, layer=None,
                            interpret=False):
     """One-token paged attention: q [B, nh, d] (fp32), table [B, MP],
     pos [B] -> ctx [B, nh, d] fp32. kc/vc are the whole pool
-    [L, P, page_size, nh, d] read at the traced scalar ``layer``, or with
-    ``layer=None`` one layer's [P, page_size, nh, d]. Unmapped table
-    entries are 0 (trash page) and masked by pos."""
+    [L, P, page_size, nh, pool_head_dim(d)] read at the traced scalar
+    ``layer``, or with ``layer=None`` one layer's [P, page_size, nh,
+    pool_head_dim(d)]. Unmapped table entries are 0 (trash page) and
+    masked by pos."""
     return _paged_decode_call(q, kc, vc, layer, table, pos, (), page_size,
                               interpret)
 

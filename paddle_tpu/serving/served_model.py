@@ -138,8 +138,11 @@ class _GPTServed(ServedModel):
 
     def kernel_ok(self, config, mp, page_size):
         nh = config.num_heads
-        return paged_kernel_supported(nh // mp, config.hidden_size // nh,
-                                      page_size, why="serving engine")
+        # (a quantized pool's rows are narrower than the compute dtype's)
+        return paged_kernel_supported(
+            nh // mp, config.hidden_size // nh, page_size,
+            why="serving engine", itemsize=jnp.dtype(
+                config.compute_dtype or "float32").itemsize)
 
     def forward(self, params, config, ids, pools, start, valid, table,
                 page_size, use_kernel=False, kv_scales=None, wq_kernel=False,

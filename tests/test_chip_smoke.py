@@ -73,8 +73,8 @@ def test_kernels_leg():
                                  interpret=True)
     assert leg.ok, leg.line("kernels")
     assert {"flash_fwd_bwd_d32", "flash_fwd_bwd_d16", "paged_decode",
-            "paged_decode_q", "quant_gemm_F384", "quant_gemm_F512"} \
-        <= set(leg.info)
+            "paged_decode_q", "paged_decode_d20", "paged_decode_q_d20",
+            "quant_gemm_F384", "quant_gemm_F512"} <= set(leg.info)
 
 
 # ---------------------------------------------------------------------------
@@ -94,23 +94,26 @@ def test_paged_and_quant_kernels_lower_for_tpu_at_the_1p3b_shapes():
     from paddle_tpu.serving.paged_attention import (
         paged_decode_attention, paged_decode_attention_q)
     sds = jax.ShapeDtypeStruct
-    B, nh, d, ps, MP = 16, 16, 128, 16, 128     # the 1.3B cell's decode
+    B, ps, MP = 16, 16, 128                     # the GPT cells' decode
     P = B * MP + 1
-    q, tab, pos = sds((B, nh, d), jnp.float32), sds((B, MP), jnp.int32), \
-        sds((B,), jnp.int32)
-    for dt, scales in ((jnp.bfloat16, ()),
-                       (jnp.int8, (sds((P,), jnp.float32),) * 2)):
-        fn = paged_decode_attention_q if scales else paged_decode_attention
-        # one layer's pool, and the engine's call: a traced layer of the
-        # whole stacked pool
-        pool = sds((P, ps, nh, d), dt)
-        assert _lowers_for_tpu(
-            lambda *a: fn(*a, page_size=ps), q, pool, pool, tab, pos,
-            *scales) == 1
-        pool = sds((24, P, ps, nh, d), dt)
-        assert _lowers_for_tpu(
-            lambda l, *a: fn(*a, page_size=ps, layer=l),
-            sds((), jnp.int32), q, pool, pool, tab, pos, *scales) == 1
+    tab, pos = sds((B, MP), jnp.int32), sds((B,), jnp.int32)
+    # 1.3B's heads, and 2.7B's: 32 of 80 in the pool's 128 lanes
+    for nh, d in ((16, 128), (32, 80)):
+        q = sds((B, nh, d), jnp.float32)
+        for dt, scales in ((jnp.bfloat16, ()),
+                           (jnp.int8, (sds((P,), jnp.float32),) * 2)):
+            fn = paged_decode_attention_q if scales else \
+                paged_decode_attention
+            # one layer's pool, and the engine's call: a traced layer of
+            # the whole stacked pool
+            pool = sds((P, ps, nh, 128), dt)
+            assert _lowers_for_tpu(
+                lambda *a: fn(*a, page_size=ps), q, pool, pool, tab, pos,
+                *scales) == 1
+            pool = sds((24, P, ps, nh, 128), dt)
+            assert _lowers_for_tpu(
+                lambda l, *a: fn(*a, page_size=ps, layer=l),
+                sds((), jnp.int32), q, pool, pool, tab, pos, *scales) == 1
     for F in (6144, 8192):
         assert _lowers_for_tpu(
             quant_gemm_kernel, sds((B, 2048), jnp.bfloat16),

@@ -493,26 +493,31 @@ def test_paged_decode_kernel_matches_gather_reference():
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
-@pytest.mark.parametrize("layer", [0, 2])
-def test_paged_decode_kernel_reads_a_layer_of_the_whole_pool(layer, quant):
+@pytest.mark.parametrize("layer,d", [
+    pytest.param(0, 128, id="0"), pytest.param(2, 128, id="2"),
+    pytest.param(2, 80, id="2-d80")])
+def test_paged_decode_kernel_reads_a_layer_of_the_whole_pool(layer, d, quant):
     """The engine's call hands the kernel the stacked pool [L, P, ...] and
     a traced layer (the index_map addresses block (layer, page)): bitwise
     the per-layer call on kc[layer], which is the old [P, ...] entry, and
-    the gather read's numbers."""
+    the gather read's numbers. ``d`` 80: a head_dim-80 query against a pool
+    of 128 lanes (GPT-3 2.7B's), whose pad lanes hold anything finite here:
+    the query's zero lanes meet K's, the output's cut V's."""
     from paddle_tpu.serving.paged_attention import (
         paged_attention_read, paged_decode_attention,
-        paged_decode_attention_q)
+        paged_decode_attention_q, pool_head_dim)
     rng = np.random.default_rng(3)
-    L, B, nh, d, ps, MP, P = 3, 3, 8, 128, 8, 4, 11
+    L, B, nh, ps, MP, P = 3, 3, 8, 8, 4, 11
+    lanes = pool_head_dim(d)
     q = jnp.asarray(rng.standard_normal((B, nh, d)), jnp.float32)
     if quant:
-        kc, vc = (jnp.asarray(rng.integers(-127, 128, (L, P, ps, nh, d)),
+        kc, vc = (jnp.asarray(rng.integers(-127, 128, (L, P, ps, nh, lanes)),
                               jnp.int8) for _ in range(2))
         scales = tuple(jnp.asarray(rng.uniform(0.01, 0.1, P), jnp.float32)
                        for _ in range(2))
         fn = paged_decode_attention_q
     else:
-        kc, vc = (jnp.asarray(rng.standard_normal((L, P, ps, nh, d)),
+        kc, vc = (jnp.asarray(rng.standard_normal((L, P, ps, nh, lanes)),
                               jnp.float32) for _ in range(2))
         scales = ()
         fn = paged_decode_attention
@@ -523,6 +528,7 @@ def test_paged_decode_kernel_reads_a_layer_of_the_whole_pool(layer, quant):
                layer=jnp.asarray(layer, jnp.int32), interpret=True)
     one = fn(q, kc[layer], vc[layer], table, pos, *scales, page_size=ps,
              interpret=True)
+    assert whole.shape == (B, nh, d)
     assert (np.asarray(whole) == np.asarray(one)).all()
     want = paged_attention_read(q[:, None], kc, vc, layer, table,
                                 pos[:, None], ps, False, jnp.float32,
@@ -544,12 +550,20 @@ _SWEEPS = {
 }
 
 
-@pytest.mark.parametrize("sweep,dead", [(name, "zero") for name in _SWEEPS] + [
-    ("one_page_beside_full", "stale"), ("one_page_beside_full", "nan")])
+@pytest.mark.parametrize("sweep,dead,d", [
+    pytest.param(name, "zero", 128, id=f"{name}-zero") for name in _SWEEPS] + [
+    pytest.param("one_page_beside_full", "stale", 128,
+                 id="one_page_beside_full-stale"),
+    pytest.param("one_page_beside_full", "nan", 128,
+                 id="one_page_beside_full-nan"),
+    pytest.param("one_page_beside_full", "zero", 80,
+                 id="one_page_beside_full-zero-d80"),
+    pytest.param("one_page_beside_full", "nan", 80,
+                 id="one_page_beside_full-nan-d80")])
 @pytest.mark.parametrize("stacked", [True, False], ids=["layer", "one_layer"])
 @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
 def test_paged_decode_kernel_sweeps_the_live_pages(quant, stacked, sweep,
-                                                   dead):
+                                                   dead, d):
     """The kernel against the gather read over tables as the pool keeps
     them: a slot's live entries name pages of its own, the entries past its
     last live page are 0 (``zero``) or left over (``stale``: other slots'
@@ -557,12 +571,14 @@ def test_paged_decode_kernel_sweeps_the_live_pages(quant, stacked, sweep,
     slot maps to, trash page 0 included (the values of a float pool, the
     scales of an int8 one): the kernel's output is finite and the clean
     pool's, so a dead page is not fetched, not merely masked (a masked
-    NaN still poisons the context sum: 0 * NaN)."""
+    NaN still poisons the context sum: 0 * NaN). ``d`` 80: the query is 80
+    wide and the pool 128 lanes, its pad zeros as ``pad_lanes`` writes it."""
     from paddle_tpu.serving.paged_attention import (
         paged_attention_read, paged_decode_attention,
-        paged_decode_attention_q)
+        paged_decode_attention_q, pool_head_dim)
     rng = np.random.default_rng(11)
-    L, layer, nh, d = 3, 2, 8, 128
+    L, layer, nh = 3, 2, 8
+    lanes = pool_head_dim(d)
     B, MP, ps = _KB, _KMP, _KPS
     P = 1 + B * MP
     pos = np.asarray(_SWEEPS[sweep], np.int32)
@@ -575,16 +591,18 @@ def test_paged_decode_kernel_sweeps_the_live_pages(quant, stacked, sweep,
 
     q = jnp.asarray(rng.standard_normal((B, nh, d)), jnp.float32)
     if quant:
-        kc, vc = (rng.integers(-127, 128, (L, P, ps, nh, d)).astype(np.int8)
-                  for _ in range(2))
+        kc, vc = (rng.integers(-127, 128, (L, P, ps, nh, lanes)).astype(
+            np.int8) for _ in range(2))
         scales = [rng.uniform(0.01, 0.1, P).astype(np.float32)
                   for _ in range(2)]
         fn = paged_decode_attention_q
     else:
-        kc, vc = (rng.standard_normal((L, P, ps, nh, d)).astype(np.float32)
-                  for _ in range(2))
+        kc, vc = (rng.standard_normal((L, P, ps, nh, lanes)).astype(
+            np.float32) for _ in range(2))
         scales = []
         fn = paged_decode_attention
+    kc[..., d:] = 0
+    vc[..., d:] = 0
     # (on the host before a page is poisoned: a device array may alias
     # the numpy buffer it was made from)
     want = np.asarray(paged_attention_read(
@@ -657,17 +675,21 @@ def test_sampled_steps_counts_the_dispatches_whose_tail_drew(speculate_k):
         assert c["sampled_steps"] == 6
 
 
-@pytest.mark.parametrize("read", ["gather", "kernel"])
+@pytest.mark.parametrize("read,hidden", [
+    pytest.param("gather", 256, id="gather"),
+    pytest.param("kernel", 256, id="kernel"),
+    pytest.param("kernel", 160, id="kernel-d80")])
 def test_decode_pages_counters_follow_the_read_the_step_was_built_with(
-        read, monkeypatch):
+        read, hidden, monkeypatch):
     """``decode_pages_table`` counts the table entries of every [B, 1]
     decode dispatch, ``decode_pages_swept`` those its attention read
     visits: every entry under the gather read (the CPU's), the pages each
     slot holds at its uploaded ``pos`` under the decode kernel (built here
-    by a patched ``kernel_ok`` and interpreted; head_dim 128 as the kernel
-    wants it)."""
+    by a patched ``kernel_ok`` and interpreted; two heads of 128, or of 80
+    in the pool's 128 lanes), and serves the single-request reference's
+    tokens."""
     from paddle_tpu.serving import paged_attention as PA, served_model
-    cfg = GPTConfig(vocab_size=97, hidden_size=256, num_layers=2,
+    cfg = GPTConfig(vocab_size=97, hidden_size=hidden, num_layers=2,
                     num_heads=2, max_seq_len=64, dropout=0.0, use_flash=False,
                     compute_dtype="float32", remat=False)
     if read == "kernel":
@@ -688,9 +710,15 @@ def test_decode_pages_counters_follow_the_read_the_step_was_built_with(
     eng._paged_step = spy
     rng = np.random.default_rng(4)
     profiler.reset_serving_counters()
-    eng.run([serving.Request(rng.integers(0, cfg.vocab_size, n),
-                             max_new_tokens=m)
-             for n, m in ((3, 9), (13, 6), (21, 12), (5, 4))])
+    reqs = [serving.Request(rng.integers(0, cfg.vocab_size, n),
+                            max_new_tokens=m)
+            for n, m in ((3, 9), (13, 6), (21, 12), (5, 4))]
+    served = eng.run(reqs)
+    for r in reqs:
+        want = np.asarray(generate_from_params(
+            eng.params, np.asarray(r.prompt)[None], cfg,
+            max_new_tokens=r.max_new_tokens)._data)[0, len(r.prompt):]
+        assert served[r.request_id].tokens == want.tolist()
     c = profiler.serving_counters()
     assert len(decodes) == c["paged_steps"] - c["chunk_steps"] > 12
     assert c["decode_pages_table"] == len(decodes) * B * MP
@@ -714,15 +742,18 @@ def _subjaxprs(obj):
             yield from _subjaxprs(o)
 
 
-def _scans(jaxpr):
-    """Every scan equation of a jaxpr, nested ones (pjit, shard_map, scan,
-    cond, custom calls) included."""
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested ones (pjit, shard_map, scan, cond,
+    custom calls) included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn
+        yield eqn
         for v in eqn.params.values():
             for sub in _subjaxprs(v):
-                yield from _scans(sub)
+                yield from _eqns(sub)
+
+
+def _scans(jaxpr):
+    return (eqn for eqn in _eqns(jaxpr) if eqn.primitive.name == "scan")
 
 
 def _assert_pool_is_carried(closed, pool_shape):
@@ -750,21 +781,27 @@ def _assert_pool_is_carried(closed, pool_shape):
         [tuple(pool_shape)] * 2
 
 
-def _step_jaxprs(variant, quant=None):
+def _step_jaxprs(variant, quant=None, cfg=None):
     """The engine, and the jaxprs of the step it builds at its steady-state
     shapes [B, 1] and [1, chunk] ([B, k+1] for verify). Platform
     independent: the kernel variant traces the step the engine builds on a
-    TPU."""
+    TPU. ``cfg``: another model than the module's ``CFG`` (head_dim 16)."""
     from paddle_tpu.serving import engine as E
     kw = {"verify": {"speculate_k": 3}, "mp": {"mp": 2}}.get(variant, {})
     # num_slots=9 is unique in the suite: these traces warm no executable
     # that a trace-count gate of another test counts
-    eng = _engine(num_slots=9, quant=quant, **kw)
+    kw.update(num_slots=9, quant=quant)
+    if cfg is None:
+        cfg, eng = CFG, _engine(**kw)
+    else:
+        eng = serving.Engine(
+            params=init_gpt_params(cfg, jax.random.key(1)), config=cfg,
+            max_seq_len=cfg.max_seq_len, page_size=8, prefill_chunk=8, **kw)
     B, C, MP = 9, eng.prefill_chunk, eng.pool.table.shape[1]
     step = eng._paged_step
     if variant == "kernel":
         step = E._make_paged_step(
-            E._cfg_key(CFG), eng.top_k, eng.page_size, True, (),
+            E._cfg_key(cfg), eng.top_k, eng.page_size, True, (),
             quant=None if quant is None else eng._quant.key())
 
     def operands(b, t):
@@ -811,8 +848,64 @@ def test_sampling_tail_lies_in_cond_branches(variant, primitives):
         assert not {("sort", False), ("random_bits", False)} & found
 
 
-def test_paged_kernel_routing_predicate():
+def test_paged_kernel_routing_predicate(monkeypatch):
     from paddle_tpu.serving.paged_attention import paged_kernel_supported
     # off-TPU backends always fall back to the jnp gather path
     assert not paged_kernel_supported(8, 128, 16)   # cpu backend here
-    assert not paged_kernel_supported(8, 64, 16)    # head_dim
+    assert not paged_kernel_supported(8, 64, 16)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # any head_dim: the pool holds it in whole 128-lane tiles
+    for d in (128, 80, 64, 96, 256):
+        assert paged_kernel_supported(8, d, 16)
+    assert paged_kernel_supported(32, 80, 16)       # GPT-3 2.7B
+    assert paged_kernel_supported(16, 128, 16)      # GPT-3 1.3B
+    assert not paged_kernel_supported(4, 128, 16)   # heads: sublane tiles
+    assert not paged_kernel_supported(8, 128, 4)    # page rows
+    # the K and V double buffers of a step's pages have to fit VMEM: 128
+    # heads of 128 lanes are 16 MiB in bf16 (Mosaic refuses them for a
+    # described v5e, and takes 96 heads' 12 MiB), 8 MiB in an int8 pool
+    assert paged_kernel_supported(96, 128, 16)
+    assert not paged_kernel_supported(128, 128, 16)
+    assert paged_kernel_supported(128, 128, 16, itemsize=1)
+    assert paged_kernel_supported(32, 80, 16, itemsize=4)
+    assert not paged_kernel_supported(64, 128, 16, itemsize=4)
+
+
+@pytest.mark.parametrize("hidden", [256, 160], ids=["d128", "d80"])
+def test_kernel_step_pads_the_query_only_where_head_dim_is_not_the_lanes(
+        hidden):
+    """The [B, 1] step built with the kernel, two heads. head_dim 128 (the
+    pool's lanes): around the kernel call no pad of the query and no slice
+    of the context is traced (the 1.3B cell's executable is untouched by
+    the other width). head_dim 80: one pad [B, nh, 80] -> [B, nh, 128] and
+    one slice back, a layer. Neither gathers the pages into a window
+    [B, MP, page, nh, lanes]: that is the other read."""
+    cfg = GPTConfig(vocab_size=97, hidden_size=hidden, num_layers=2,
+                    num_heads=2, max_seq_len=64, dropout=0.0, use_flash=False,
+                    compute_dtype="float32", remat=False)
+    eng, (decode, chunk) = _step_jaxprs("kernel", cfg=cfg)
+    B, nh, d, lanes = 9, 2, hidden // 2, eng._kc.shape[-1]
+    MP = eng.pool.table.shape[1]
+    window = (B, MP, eng.page_size, nh, lanes)
+
+    def count(closed, name, shape_in, shape_out):
+        return sum(1 for eqn in _eqns(closed.jaxpr)
+                   if eqn.primitive.name == name
+                   and eqn.invars[0].aval.shape == shape_in
+                   and eqn.outvars[0].aval.shape == shape_out)
+
+    def names(closed):
+        return [eqn.primitive.name for eqn in _eqns(closed.jaxpr)]
+
+    assert names(decode).count("pallas_call") == 1      # in the layer scan
+    assert "pallas_call" not in names(chunk)
+    widened = 0 if d == lanes else 1
+    assert count(decode, "pad", (B, nh, d), (B, nh, lanes)) == widened
+    assert count(decode, "slice", (B, nh, lanes), (B, nh, d)) == widened
+    assert not [eqn for eqn in _eqns(decode.jaxpr)
+                if eqn.primitive.name == "gather"
+                and eqn.outvars[0].aval.shape == window]
+    # (the chunk step keeps the gather read)
+    assert [eqn for eqn in _eqns(chunk.jaxpr)
+            if eqn.primitive.name == "gather"
+            and eqn.outvars[0].aval.shape == (1,) + window[1:]]

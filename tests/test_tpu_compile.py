@@ -71,16 +71,19 @@ def _compile_step(chip, cfg, num_pages, batch, window, use_kernel):
         sds((b, 2), "uint32")).compile()
 
 
-@pytest.mark.parametrize("name,hidden,heads,num_pages,batch,window,kernel", [
-    ("1.3B-decode", 2048, 16, 2049, SLOTS, 1, True),
-    ("1.3B-chunk", 2048, 16, 2049, 1, 16, True),
-    ("2.7B-decode", 2560, 32, 769, SLOTS, 1, False),
-    ("2.7B-chunk", 2560, 32, 769, 1, 16, False),
+@pytest.mark.parametrize("name,hidden,heads,num_pages,batch,window", [
+    ("1.3B-decode", 2048, 16, 2049, SLOTS, 1),
+    ("1.3B-chunk", 2048, 16, 2049, 1, 16),
+    # head_dim 80 in a pool of 128 lanes: the kernel takes it too (PR 33)
+    ("2.7B-decode", 2560, 32, 769, SLOTS, 1),
+    ("2.7B-chunk", 2560, 32, 769, 1, 16),
 ])
 def test_paged_step_updates_the_pool_in_place_on_the_chip(
-        chip, name, hidden, heads, num_pages, batch, window, kernel):
+        chip, name, hidden, heads, num_pages, batch, window):
+    """The step as the engine builds it on a TPU, with the kernel: a Mosaic
+    call in the [16, 1] step, none in the chunk step."""
     pool, compiled = _compile_step(chip, _gpt(hidden, heads), num_pages,
-                                   batch, window, kernel)
+                                   batch, window, True)
     text = compiled.as_text()
     dims = ",".join(str(n) for n in pool.shape)
     whole = re.findall(
@@ -94,10 +97,24 @@ def test_paged_step_updates_the_pool_in_place_on_the_chip(
     assert compiled.memory_analysis().alias_size_in_bytes >= 2 * 2 * pool.size
     calls = re.findall(r"(%[\w.\-]+) = \S+ custom-call\([^\n]*"
                        r'custom_call_target="tpu_custom_call"', text)
-    if kernel and window == 1:
+    if window == 1:
         assert [c for c in calls if c.startswith("%paged_decode_attention")]
     else:
         assert not calls
+
+
+def test_decode_kernel_takes_the_gather_reads_windows_off_the_2_7b_step(
+        chip):
+    """The [16, 1] step at 2.7B's attention geometry (32 heads of 80 in 128
+    lanes): built with the kernel its temporaries are below the gather
+    read's, whose float32 windows [16, 2048, 32, 80] of K and V are gone."""
+    temp = {}
+    for kernel in (True, False):
+        _, compiled = _compile_step(chip, _gpt(2560, 32), 769, SLOTS, 1,
+                                    kernel)
+        temp[kernel] = compiled.memory_analysis().temp_size_in_bytes
+    window = SLOTS * MAX_SEQ * 32 * 80 * 4
+    assert temp[True] < temp[False] - window, temp
 
 
 def test_decode_step_keeps_the_sampling_tail_in_a_conditional_on_the_chip(
