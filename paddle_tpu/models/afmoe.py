@@ -53,7 +53,8 @@ from ..serving import metrics
 from ..serving.paged_attention import grouped_attend, paged_attention_read, \
     paged_kv_scatter, window_mask
 from ..serving.served_model import CacheGeometry, CacheGroup, ServedModel
-from .moe import F32, compute_of, ffn, final_logits, mm, moe_ffn, rms_norm
+from .moe import F32, compute_of, ffn, final_logits, layer_plan, mm, \
+    moe_ffn, rms_norm, rotate, run_layers
 
 logger = logging.getLogger("paddle_tpu.afmoe")
 
@@ -142,6 +143,10 @@ class AfmoeConfig:
         return self.route_scale
 
     @property
+    def route_norm_eps(self):
+        return 1e-20
+
+    @property
     def held(self):
         return self.experts_held or (0, self.num_experts)
 
@@ -217,19 +222,6 @@ def init_afmoe_params(config, key, dtype=F32):
 # pieces
 
 
-def rotate(x, pos, theta):
-    """x [B, T, heads, d] rotated at integer positions pos [B, T]: the pair
-    (i, i + d/2) turns by position x theta^(-2i/d)."""
-    half = x.shape[-1] // 2
-    inv = 1.0 / theta ** (jnp.arange(half, dtype=F32) / half)
-    ang = pos.astype(F32)[..., None, None] * inv               # [B, T, 1, d/2]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    xf = x.astype(F32)
-    a, b = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
 def attention(p, h, config, pos, window, attend):
     """The attention sublayer on the stream h [B, T, H] (float32), before
     its residual add. ``window`` is the layer's kind; ``attend(q, k, v)`` ->
@@ -269,62 +261,6 @@ def layer(p, h, config, pos, kind, attend, token_mask=None):
         y, stats = ffn(xn.astype(compute_of(c)), p["gate_w"], p["up_w"],
                        p["down_w"]), None
     return h + rms_norm(y, p["ffn_post_norm_g"], c.rms_norm_eps), carry, stats
-
-
-def layer_plan(kinds):
-    """[(pattern, repeats)] covering the layers' ``kinds`` in order: at each
-    point the pattern (a run of one kind, or a period of several kinds seen
-    at least twice) that covers the most layers."""
-    plan, i = [], 0
-    while i < len(kinds):
-        best = (1, 1)
-        for period in range(1, (len(kinds) - i) // 2 + 1):
-            pattern, n = kinds[i:i + period], 1
-            while kinds[i + n * period:i + (n + 1) * period] == pattern:
-                n += 1
-            if n > 1 and period * n > best[0] * best[1]:
-                best = (period, n)
-        plan.append((tuple(kinds[i:i + best[0]]), best[1]))
-        i += best[0] * best[1]
-    return plan
-
-
-def run_layers(params, config, carry, layer_fn):
-    """``layer_fn(carry, leaves, kind, moe_index, group_index) -> carry``
-    over every layer in order: one scan a segment of ``layer_plan``, the
-    layer's leaves indexed out of its kind's stack by the repeat.
-    ``moe_index`` counts the layers of the same MLP kind before it (its
-    place in ``params["dense"]`` or ``params["moe"]``), ``group_index``
-    those of the same attention kind (its place in its cache group)."""
-    seen = {}                       # key -> layers met so far
-
-    for pattern, repeats in layer_plan(config.kinds()):
-        base = dict(seen)
-        per = {}
-        for moe, window in pattern:
-            for key in (("mlp", moe), ("attn", window)):
-                per[key] = per.get(key, 0) + 1
-
-        def body(carry, rep, pattern=pattern, base=base, per=per):
-            at = {}
-            for kind in pattern:
-                moe, window = kind
-                idx = []
-                for key in (("mlp", moe), ("attn", window)):
-                    idx.append(base.get(key, 0) + rep * per[key]
-                               + at.get(key, 0))
-                    at[key] = at.get(key, 0) + 1
-                stack = params["moe" if moe else "dense"]
-                leaves = jax.tree_util.tree_map(lambda a, i=idx[0]: a[i],
-                                                stack)
-                carry = layer_fn(carry, leaves, kind, idx[0], idx[1])
-            return carry, None
-
-        carry, _ = jax.lax.scan(body, carry,
-                                jnp.arange(repeats, dtype=jnp.int32))
-        for key, n in per.items():
-            seen[key] = seen.get(key, 0) + n * repeats
-    return carry
 
 
 def _embed(params, config, ids):

@@ -1,14 +1,18 @@
-"""What the expert models share (``models/xing4.py``, ``models/afmoe.py``):
-the RMS norm, the gated FFN, the final norm and untied head, and the
-sigmoid-routed expert layer beside a shared expert. One function each, so
-that a change to the expert layer is judged on every expert model the
-benchmark runs.
+"""What the expert models share (``models/xing4.py``, ``models/afmoe.py``,
+``models/lfm2.py``): the RMS norm, the gated FFN, the final norm and head,
+the rotation of a head's pairs, the sigmoid-routed expert layer beside a
+shared expert (or none), and the walk over a model's layers as one scan a
+segment of repeated kinds. One function each, so that a change to the expert
+layer is judged on every expert model the benchmark runs.
 
 The expert layer reads of a configuration object: ``n_routed_experts``,
-``num_experts_per_tok``, ``norm_topk_prob``, ``routed_scaling_factor``,
-``held`` (the range of routed experts this chip holds), ``rms_norm_eps`` and
-``compute_dtype``; of a layer's leaves: ``ffn_norm_g``, ``router_w``,
-``router_bias``, ``experts_{gate,up,down}_w`` and ``shared_{gate,up,down}_w``.
+``num_experts_per_tok``, ``norm_topk_prob``, ``route_norm_eps`` (what the
+normalisation adds to the chosen scores' sum, as the model's published code
+has it), ``routed_scaling_factor``, ``held`` (the range of routed experts
+this chip holds), ``rms_norm_eps`` and ``compute_dtype``; of a layer's
+leaves: ``ffn_norm_g``, ``router_w``, ``router_bias``,
+``experts_{gate,up,down}_w`` and, where the layer has a shared expert,
+``shared_{gate,up,down}_w``.
 A model whose published keys differ states them as properties."""
 from __future__ import annotations
 
@@ -48,6 +52,19 @@ def compute_of(config):
     return jnp.dtype(config.compute_dtype or "float32")
 
 
+def rotate(x, pos, theta):
+    """x [B, T, heads, d] rotated at integer positions pos [B, T]: the pair
+    (i, i + d/2) turns by position x theta^(-2i/d)."""
+    half = x.shape[-1] // 2
+    inv = 1.0 / theta ** (jnp.arange(half, dtype=F32) / half)
+    ang = pos.astype(F32)[..., None, None] * inv               # [B, T, 1, d/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(F32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
 def moe_route(xn32, router_w, router_bias, config):
     """Routing of tokens xn32 [N, H] (float32, as the published code routes):
     chosen experts [N, k] and their weights [N, k]."""
@@ -57,7 +74,7 @@ def moe_route(xn32, router_w, router_bias, config):
     _, idx = jax.lax.top_k(s + router_bias.astype(F32), c.num_experts_per_tok)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if c.norm_topk_prob:
-        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, -1, keepdims=True) + c.route_norm_eps)
     return idx, w * c.routed_scaling_factor
 
 
@@ -101,3 +118,59 @@ def moe_ffn(p, x, config, token_mask=None, held=None, shared=True):
     return y.reshape(B, T, H), stats
 
 
+def layer_plan(kinds):
+    """[(pattern, repeats)] covering the layers' ``kinds`` in order: at each
+    point the pattern (a run of one kind, or a period of several kinds seen
+    at least twice) that covers the most layers."""
+    plan, i = [], 0
+    while i < len(kinds):
+        best = (1, 1)
+        for period in range(1, (len(kinds) - i) // 2 + 1):
+            pattern, n = kinds[i:i + period], 1
+            while kinds[i + n * period:i + (n + 1) * period] == pattern:
+                n += 1
+            if n > 1 and period * n > best[0] * best[1]:
+                best = (period, n)
+        plan.append((tuple(kinds[i:i + best[0]]), best[1]))
+        i += best[0] * best[1]
+    return plan
+
+
+def run_layers(params, config, carry, layer_fn):
+    """``layer_fn(carry, leaves, kind, moe_index, group_index) -> carry``
+    over every layer in order: one scan a segment of ``layer_plan``, the
+    layer's leaves indexed out of its kind's stack by the repeat.
+    A kind is (is an expert layer, which of the model's two operators: a
+    window against a full attention, a convolution against an attention).
+    ``moe_index`` counts the layers of the same MLP kind before it (its
+    place in ``params["dense"]`` or ``params["moe"]``), ``group_index``
+    those of the same operator (its place in its cache group)."""
+    seen = {}                       # key -> layers met so far
+
+    for pattern, repeats in layer_plan(config.kinds()):
+        base = dict(seen)
+        per = {}
+        for moe, window in pattern:
+            for key in (("mlp", moe), ("attn", window)):
+                per[key] = per.get(key, 0) + 1
+
+        def body(carry, rep, pattern=pattern, base=base, per=per):
+            at = {}
+            for kind in pattern:
+                moe, window = kind
+                idx = []
+                for key in (("mlp", moe), ("attn", window)):
+                    idx.append(base.get(key, 0) + rep * per[key]
+                               + at.get(key, 0))
+                    at[key] = at.get(key, 0) + 1
+                stack = params["moe" if moe else "dense"]
+                leaves = jax.tree_util.tree_map(lambda a, i=idx[0]: a[i],
+                                                stack)
+                carry = layer_fn(carry, leaves, kind, idx[0], idx[1])
+            return carry, None
+
+        carry, _ = jax.lax.scan(body, carry,
+                                jnp.arange(repeats, dtype=jnp.int32))
+        for key, n in per.items():
+            seen[key] = seen.get(key, 0) + n * repeats
+    return carry

@@ -117,6 +117,10 @@ class Xing4Config:
         return self.experts_held or (0, self.n_routed_experts)
 
     @property
+    def route_norm_eps(self):
+        return 1e-20
+
+    @property
     def latent_row(self):
         return self.kv_lora_rank + self.qk_rope_head_dim
 

@@ -560,13 +560,18 @@ class Engine:
                            k_clip=self._quant.kv_k_clip,
                            v_clip=self._quant.kv_v_clip,
                            qmax=_squant.QMAX[kv_dtype])
-        # an allocator and a page table a group of layers. The first
+        # an allocator and a page table a PAGED group of layers. The first
         # group's is ``pool``: it takes ``num_pages``, the prefix cache and
         # copy-on-write; a group with no window maps a request's whole
         # lifetime, a window group a ring a slot (``ring_pages``),
-        # whatever the context
+        # whatever the context. A state group has neither: its arrays are
+        # a row a slot, found by the slot's number (_table_arg)
+        if not geo.groups[0].paged:
+            raise ValueError(
+                f"the {self._model.name} model's first cache group must be "
+                f"a paged one (serving/served_model.py)")
         self._group_pools = []
-        for g in geo.groups:
+        for g in geo.paged:
             first = not self._group_pools
             slot_tokens = self.max_seq_len if g.window is None else min(
                 self.max_seq_len, self.page_size * ring_pages(
@@ -627,10 +632,15 @@ class Engine:
         # and the group each belongs to
         self._pool_group = tuple(i for i, g in enumerate(geo.groups)
                                  for _ in g.names)
-        self._pools = tuple(
-            jnp.zeros(geo.groups[i].pool_shape(
-                self._group_pools[i].num_pages, self.page_size), compute)
-            for i in self._pool_group)
+        # every group beside its allocator (a state group has none)
+        allocators = iter(self._group_pools)
+        self._groups = [(g, next(allocators) if g.paged else None)
+                        for g in geo.groups]
+        shapes = [g.pool_shape(pool.num_pages, self.page_size) if g.paged
+                  else g.state_shape(B) for g, pool in self._groups]
+        self._pools = tuple(jnp.zeros(shapes[i], compute)
+                            for i in self._pool_group)
+        self._slot_ids = np.arange(B, dtype=np.int32)
         if self._quant is not None:
             metrics.set_quant_info(
                 self._quant.weight_dtype, self._quant.kv_dtype,
@@ -1149,9 +1159,12 @@ class Engine:
 
     def _table_arg(self, sl=slice(None)):
         """The step's ``table`` operand for the slots ``sl``: the one
-        group's page table, or a tuple of one a group. Host-authoritative,
-        uploaded with every dispatch."""
-        tables = tuple(jnp.asarray(p.table[sl]) for p in self._group_pools)
+        group's page table, or a tuple of one a group (a state group's is
+        the slots' numbers). Host-authoritative, uploaded with every
+        dispatch."""
+        tables = tuple(jnp.asarray(
+            (pool.table if g.paged else self._slot_ids)[sl])
+            for g, pool in self._groups)
         return tables[0] if len(tables) == 1 else tables
 
     def _copy_page(self, src, dst):
@@ -1985,6 +1998,7 @@ class Engine:
         for g, pages in zip(self._group_pools[1:], others):
             g.map_slot(b, pages)
         self._count_mapped_pages(req, b)
+        self._count_bound_cache(req, b)
         req.state = RUNNING
         req.slot = b
         req.params_version = self.params_version
@@ -2029,13 +2043,38 @@ class Engine:
             return
         lifetime = pages_for(req.prompt_len + req.max_new_tokens,
                              self.page_size)
-        for g, pool in zip(self._geo.groups, self._group_pools):
+        for g, pool in zip(self._geo.paged, self._group_pools):
             mapped = g.layers * int(np.count_nonzero(pool.table[b]))
             if g.window is None:
                 metrics.bump("kv_pages_mapped_full", mapped)
             else:
                 metrics.bump("kv_pages_mapped_window", mapped)
                 metrics.bump("kv_pages_unwindowed", g.layers * lifetime)
+
+    def _count_bound_cache(self, req, b):
+        """Where a group of layers keeps a state and no pages: the bytes
+        this admission binds (the pages mapped, times their group's layers
+        and a page's bytes at the model's own row width, and the state
+        groups' rows of one slot) and what the same lifetime would have
+        mapped had the state layers kept rows a token as the first group's
+        do. From the host's tables; no sync."""
+        states = [g for g in self._geo.groups if not g.paged]
+        if not states:
+            return
+        size = self._pools[0].dtype.itemsize
+        page_bytes = [g.row_bytes(size) * self.page_size
+                      for g in self._geo.paged]
+        mapped = sum(
+            g.layers * int(np.count_nonzero(pool.table[b])) * nbytes
+            for g, pool, nbytes in zip(self._geo.paged, self._group_pools,
+                                       page_bytes))
+        lifetime = pages_for(req.prompt_len + req.max_new_tokens,
+                             self.page_size)
+        metrics.bump("state_slots_bound")
+        metrics.bump("cache_bytes_bound", mapped + sum(
+            g.layers * g.row_bytes(size) for g in states))
+        metrics.bump("cache_bytes_all_paged", mapped + sum(
+            g.layers for g in states) * lifetime * page_bytes[0])
 
     def _quarantine(self, req, b):
         """Anomaly-guard resolution (``FLAGS_serving_anomaly_policy=
@@ -2773,7 +2812,9 @@ class Engine:
         # a token's row in every pool array, as many lanes as the device
         # holds, over the chips that share the head axis
         per_tok = sum(a.shape[0] * int(np.prod(a.shape[3:]))
-                      * int(a.dtype.itemsize) for a in self._pools) // self.mp
+                      * int(a.dtype.itemsize)
+                      for a, g in zip(self._pools, self._pool_group)
+                      if self._geo.groups[g].paged) // self.mp
         if self._kv_quant:
             # two fp32 scales per (layer, page), shared by page_size
             # tokens — rounded UP so the gauge never underreports to 0

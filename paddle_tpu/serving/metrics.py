@@ -152,6 +152,13 @@ def _zero():
         # cap, each times its group's layers
         "kv_pages_mapped_full": 0, "kv_pages_mapped_window": 0,
         "kv_pages_unwindowed": 0,
+        # admission ledger of a model with a state group: admissions (each
+        # binds one slot's state), the bytes they bound (pages mapped times
+        # their group's layers times a page's bytes, plus the state rows of
+        # the slot) and what the same lifetimes would have mapped had every
+        # state layer kept rows a token as the first paged group's do
+        "state_slots_bound": 0, "cache_bytes_bound": 0,
+        "cache_bytes_all_paged": 0,
         # occupancy: sum of active slots over decode steps / (steps * slots)
         "active_slot_steps": 0, "slot_steps": 0,
         # queue depth observed at step boundaries

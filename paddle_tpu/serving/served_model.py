@@ -12,13 +12,23 @@ The engine asks a model for two things and nothing else:
   request's whole lifetime. A window group's slot is a RING of
   ``ring_pages(window, widest chunk, page_size)`` pages whatever the
   context: position p lives in logical page ``(p // page_size) mod`` that
-  many. A request is admitted when every group can hold it;
+  many. A request is admitted when every paged group can hold it. A
+  STATE group (``paged=False``) is not paged at all: its layers keep
+  ``[layers, slots, *row]`` whatever the context (the rows before a short
+  convolution, a recurrent state), so it has no table, no allocator and
+  nothing to reserve; the step finds a slot's state by the slot's number,
+  which rides where a paged group's table does. The STEP owns its
+  lifetime: it starts a slot's state from zero where the slot's ``start``
+  is 0 and leaves it alone where ``valid`` is 0, so slot reuse,
+  pre-emption with recompute and idle slots need nothing of the host. The
+  first group is a paged one;
 * its **paged forward**: ``forward(params, config, ids, pools, start, valid,
   table, page_size, ...) -> (logits [B, V], pools, stats)``, the fused
   chunk/decode step over those pools (the pools the layer scans' carry).
   ``pools`` is every group's arrays in the geometry's order, flat; ``table``
   is the one group's table ``[B, pages]`` or, with several groups, a tuple
-  of them in the groups' order. ``stats`` is None or one small array that
+  of them in the groups' order (a state group's entry is the slots'
+  numbers ``[B]``). ``stats`` is None or one small array that
   leaves the device with the tokens and goes to ``record(stats, kind,
   config)`` on the host.
 
@@ -29,7 +39,8 @@ shared by every model; what a model does not support yet (``unsupported``:
 spec, quant, adapters, mp, kv_transfer, prefix_cache) the engine refuses by
 name at construction. Prefix sharing and copy-on-write work on the first
 group alone, so a model with a window group lists ``prefix_cache``: a ring
-page holds different positions over a request's life."""
+page holds different positions over a request's life. So does a model with
+a state group: a shared page does not bring the state at its end."""
 from __future__ import annotations
 
 import dataclasses
@@ -48,17 +59,31 @@ class CacheGroup:
     pool array a layer keeps, ``row`` a token's row in each as the model
     writes it (GPT: ``(nh, d)`` twice; grouped heads: ``(kv heads, d)``; a
     latent cache: ``(576,)`` once), ``window`` None or the positions a
-    layer of the group attends to (its slot is then a ring)."""
+    layer of the group attends to (its slot is then a ring). ``paged``
+    False is a state group: ``row`` is then what a SLOT keeps a layer
+    whatever its context (``(rows, hidden)``), the arrays are ``[layers,
+    slots, *row]`` and no table maps them."""
     names: tuple
     layers: int
     row: tuple
     window: int = None
+    paged: bool = True
 
     def pool_shape(self, num_pages, page_size):
         """On the device a row's last axis is whole lanes (``pool_head_dim``:
         row-major is then the TPU's default layout, PERF.md PR 26)."""
         return (self.layers, num_pages, page_size) + self.row[:-1] + \
             (pool_head_dim(self.row[-1]),)
+
+    def state_shape(self, num_slots):
+        """A state group's arrays: a row a slot a layer, whole lanes too."""
+        return (self.layers, num_slots) + self.row[:-1] + \
+            (pool_head_dim(self.row[-1]),)
+
+    def row_bytes(self, itemsize):
+        """Bytes of one row in every array of the group at the model's own
+        width: a token a layer (paged), a slot a layer (state)."""
+        return len(self.names) * int(np.prod(self.row)) * itemsize
 
     def logical(self, pool):
         """A host copy of a pool array (or of pages of it) at the model's
@@ -82,6 +107,11 @@ class CacheGeometry:
     def names(self):
         """Every pool array's name, in the order the step takes them."""
         return tuple(n for g in self.groups for n in g.names)
+
+    @property
+    def paged(self):
+        """The groups that a page table maps, in order."""
+        return tuple(g for g in self.groups if g.paged)
 
 
 class ServedModel:
