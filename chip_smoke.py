@@ -245,11 +245,12 @@ class _LoweringRecorder:
         self.fn = fn
         self.lowered = {}
 
-    def __call__(self, *args):
-        shape = tuple(args[3].shape)        # ids [B, T]
+    def __call__(self, *args, layout):
+        shape = (layout.B, layout.T)        # ids [B, T], inside the buffer
         if shape not in self.lowered:
-            self.lowered[shape] = self.fn.lower(*args).as_text()
-        return self.fn(*args)
+            self.lowered[shape] = self.fn.lower(*args,
+                                                layout=layout).as_text()
+        return self.fn(*args, layout=layout)
 
 
 def _serve(cfg, params, prompts, max_new, **engine_kw):

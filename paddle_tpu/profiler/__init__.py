@@ -445,6 +445,12 @@ def serving_counters():
     each ended by its outputs reaching the host; ``admit_queue_wait_s`` /
     ``admit_queue_waits`` is submit to admission of admitted requests and
     ``prefill_span_s`` / ``first_tokens`` admission to the first token.
+    ``paged_uploads`` / ``paged_fetches`` count the host-to-device arrays
+    the engine's dispatches sent and the device-to-host arrays they
+    fetched, where they are sent and fetched: one of each a paged step
+    (``paged_steps``), the slot operands riding one buffer and the small
+    outputs one (serving/operands.py); two more uploads a step with a
+    quantised pool's scale tables.
     The same phases are ``jax.profiler.TraceAnnotation`` spans
     (``pt.serve.step`` around ``pt.serve.admit | feed | wait | emit``, a
     dispatch's feed and wait with ``kind=chunk|decode|draft|verify``;

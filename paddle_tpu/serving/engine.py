@@ -52,6 +52,7 @@ from . import metrics
 from . import quant as _squant
 from .adapters import AdapterRegistry, AdapterSpec, UnknownAdapterError
 from .kv_transfer import KVTransfer, PagePayload
+from .operands import StepLayout, pack_out, split_out
 from .paged_attention import (
     pad_lanes, paged_draft_forward, paged_kv_rewind, paged_verify_forward,
 )
@@ -145,7 +146,15 @@ def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
     geometry names over all its groups (GPT: kc, vc), which ride as the
     operands after ``params`` and come back first; ``table`` is the one
     group's page table or a tuple of one a group; where the forward
-    returns statistics they are the step's last output."""
+    returns statistics they are the step's last output.
+
+    What is jitted is ``packed_fn``: ``(params, *pools, packed[, kv
+    scales][, adapter slabs], layout=) -> (*pools, out)``. The per-slot
+    operands arrive as ONE int32 vector, laid out by the static ``layout``
+    (serving/operands.py) and unpacked bit for bit at the top of the
+    trace; the small outputs leave as one. ``fn``, the step over named
+    operands, is what it was; ``step.named`` is ``fn`` jitted alone, for
+    whoever reads the step's jaxpr or lowers it by its operands' names."""
     config = model.view(cfg)
     n_pools = len(model.geometry(config).names)
     kvq = quant is not None and quant[1] != "bf16"
@@ -176,7 +185,21 @@ def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
             return (*pools, nxt, new_keys, ok, *tail)
         return (*pools, nxt, new_keys, *tail)
 
-    return jax.jit(fn, donate_argnums=donate)
+    def packed_fn(params, *operands, layout):
+        packed, *rest = operands[n_pools:]
+        *slot, adapter_ids = layout.unpack(packed)
+        if adapters is not None:        # the ids ride before the slabs
+            rest.insert(len(rest) - 1, adapter_ids)
+        out = fn(params, *operands[:n_pools], *slot, *rest)
+        nxt, new_keys, *tail = out[n_pools:]
+        ok = tail.pop(0) if anomaly else None
+        return (*out[:n_pools],
+                pack_out(nxt, new_keys, ok, tail[0] if tail else None))
+
+    step = jax.jit(packed_fn, donate_argnums=donate,
+                   static_argnames=("layout",))
+    step.named = jax.jit(fn)
+    return step
 
 
 @lru_cache(maxsize=None)
@@ -641,6 +664,7 @@ class Engine:
         self._pools = tuple(jnp.zeros(shapes[i], compute)
                             for i in self._pool_group)
         self._slot_ids = np.arange(B, dtype=np.int32)
+        self._operand_bufs = {}           # (b, t) -> _operands' triple
         if self._quant is not None:
             metrics.set_quant_info(
                 self._quant.weight_dtype, self._quant.kv_dtype,
@@ -1158,14 +1182,86 @@ class Engine:
         return self._geo.groups[group].logical(pool)
 
     def _table_arg(self, sl=slice(None)):
-        """The step's ``table`` operand for the slots ``sl``: the one
-        group's page table, or a tuple of one a group (a state group's is
-        the slots' numbers). Host-authoritative, uploaded with every
-        dispatch."""
-        tables = tuple(jnp.asarray(
-            (pool.table if g.paged else self._slot_ids)[sl])
-            for g, pool in self._groups)
-        return tables[0] if len(tables) == 1 else tables
+        """The step's table fields for the slots ``sl``, by the layout's
+        names: a group's page table (a state group's is the slots'
+        numbers). Host arrays: they ride the dispatch's one buffer."""
+        return {f"table{i}": (pool.table if g.paged else self._slot_ids)[sl]
+                for i, (g, pool) in enumerate(self._groups)}
+
+    def _slot_fields(self, sl=slice(None)):
+        """The fields of a dispatch over the slots ``sl`` that are the
+        host's per-slot state as it stands: the tables, the sampling
+        parameters, the keys, and the adapter ids where adapters are on."""
+        fields = dict(self._table_arg(sl), do_sample=self._do_sample[sl],
+                      temperature=self._temp[sl], top_p=self._top_p[sl],
+                      key_data=self._keys[sl])
+        if self.adapters is not None:
+            fields["adapter_ids"] = self._aid[sl]
+        return fields
+
+    def _operands(self, b, t):
+        """The layout of a ``[b, t]`` dispatch, the host buffer the engine
+        keeps for it and the buffer's views, one a field. A buffer is
+        filled again only after the dispatch that read it has its outputs
+        on the host (every dispatch ends with that fetch; warm_up sends
+        each buffer once), so an upload that aliases it is safe."""
+        got = self._operand_bufs.get((b, t))
+        if got is None:
+            layout = StepLayout(
+                b, t, tuple(pool.table.shape[1] if g.paged else 0
+                            for g, pool in self._groups),
+                self.adapters is not None)
+            buf = np.zeros(layout.size, np.int32)
+            got = self._operand_bufs[b, t] = (layout, buf, layout.views(buf))
+        return got
+
+    def _step_args(self, b, t, named=False, **fields):
+        """What the fused step takes for a ``[b, t]`` dispatch over the
+        first ``b`` slots, as ``(args, kwargs)``. ``fields`` are the slot
+        operands by name (serving/operands.py); left out, the dispatch is
+        idle, as warm_up sends it: no live lane (valid=0 sends every write
+        to the trash page, emit=False parks the keys). ``named=True``
+        gives the operands of ``step.named`` instead, one an array, for a
+        reader of the step's jaxpr."""
+        layout, buf, views = self._operands(b, t)
+        if not fields:
+            fields = dict.fromkeys(layout.fields, 0)
+            fields.update(temperature=1.0, top_p=1.0)
+        layout.pack(views, **fields)
+        rest = self._kv_scale_args()
+        if self.adapters is not None:
+            rest += (self.adapters.device_slabs(),)
+        if not named:
+            return ((self.params, *self._pools, self._upload(buf), *rest),
+                    {"layout": layout})
+        *slot, aid = layout.unpack(jnp.asarray(buf))
+        if aid is not None:
+            rest = (*rest[:-1], aid, rest[-1])
+        return (self.params, *self._pools, *slot, *rest), {}
+
+    def _dispatch(self, b, t, **fields):
+        """One dispatch of the fused step: the slot operands packed into
+        the shape's buffer and sent as one array. The pools go back into
+        the engine; returns the step's small outputs, one array still on
+        the device (``_take`` fetches and splits it)."""
+        args, kw = self._step_args(b, t, **fields)
+        out = self._paged_step(*args, **kw)
+        self._pools = tuple(out[:-1])
+        return out[-1]
+
+    @staticmethod
+    def _upload(a):
+        """A host array sent with a paged dispatch, counted where it is
+        sent (``paged_uploads``)."""
+        metrics.bump("paged_uploads")
+        return jnp.asarray(a)
+
+    @staticmethod
+    def _fetch(a):
+        """An output of a paged dispatch brought to the host, counted
+        where it is fetched (``paged_fetches``): the host waits here."""
+        metrics.bump("paged_fetches")
+        return np.asarray(a)
 
     def _copy_page(self, src, dst):
         """One physical page of the first group copied onto another (the
@@ -1174,44 +1270,28 @@ class Engine:
         self._pools = self._page_copy(self._pools[:n], jnp.int32(src),
                                       jnp.int32(dst)) + self._pools[n:]
 
-    def _take(self, out):
-        """A fused step's outputs: the pools go back into the engine; the
-        rest come back as (next tokens, keys, per-slot verdict or None, the
-        model's statistics or None), still on the device."""
-        n = len(self._pools)
-        self._pools = tuple(out[:n])
-        nxt, keys, *rest = out[n:]
-        ok = rest.pop(0) if self._anomaly else None
-        return nxt, keys, ok, (rest[0] if rest else None)
+    def _take(self, out, b):
+        """The small outputs of a ``[b, t]`` dispatch, fetched as the one
+        array they are and split on the host: (next tokens, keys, per-slot
+        verdict or None, the model's statistics or None)."""
+        return split_out(self._fetch(out), b, self._anomaly)
 
     def _record_stats(self, stats, kind):
         """Hands a dispatch's statistics (``kind`` chunk | decode) to the
-        model, once the dispatch's other outputs are on the host."""
+        model, once the dispatch's outputs are on the host."""
         if stats is not None:
-            self._model.record(np.asarray(stats), kind, self.config)
+            self._model.record(stats, kind, self.config)
 
     def _kv_scale_args(self):
         """Per-page dequant scale operands of a quantized pool: host-
         authoritative like the page table, uploaded with every dispatch
-        ([L, P] fp32 — tiny). Empty for a full-precision pool, so the
-        unquantized dispatch signature is untouched."""
+        ([L, P] fp32 each, beside the slot operands' one buffer). Empty for
+        a full-precision pool, so the unquantized dispatch signature is
+        untouched."""
         if not self._kv_quant:
             return ()
-        return (jnp.asarray(self.pool.k_scale),
-                jnp.asarray(self.pool.v_scale))
-
-    def _adapter_args(self, sl=None):
-        """Traced adapter operands of the fused step (AFTER the kv
-        scales): the per-slot adapter row ids (host-authoritative,
-        re-uploaded every dispatch exactly like the page table) and the
-        stacked delta slabs (device-resident; re-placed only by
-        load/evict/swap). Empty when adapters are off, so the
-        adapter-less dispatch signature is untouched. ``sl`` slices the
-        id row for the [1, chunk] prefill dispatch."""
-        if self.adapters is None:
-            return ()
-        aid = self._aid if sl is None else self._aid[sl]
-        return (jnp.asarray(aid), self.adapters.device_slabs())
+        return (self._upload(self.pool.k_scale),
+                self._upload(self.pool.v_scale))
 
     def _cow(self, b, start, end):
         """Copy-on-write guard: a slot may only WRITE pages it exclusively
@@ -1276,19 +1356,11 @@ class Engine:
         for b in decoding:
             self._cow(b, int(self._pos[b]), int(self._pos[b]) + 1)
         self._decode_dispatches += 1     # per-role gate: prefill workers
-        out = self._paged_step(          # must never reach this dispatch
-            self.params, *self._pools,
-            jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos),
-            jnp.asarray(valid), jnp.asarray(emit),
-            self._table_arg(), jnp.asarray(self._do_sample),
-            jnp.asarray(self._temp), jnp.asarray(self._top_p),
-            jnp.asarray(self._keys), *self._kv_scale_args(),
-            *self._adapter_args())
+        out = self._dispatch(            # must never reach this dispatch
+            B, 1, ids=self._tok[:, None], start=self._pos, valid=valid,
+            emit=emit, **self._slot_fields())
         clk.wait()
-        nxt, keys, ok, stats = self._take(out)
-        if ok is not None:
-            ok = np.asarray(ok)
-        nxt = np.asarray(nxt)
+        nxt, keys, ok, stats = self._take(out, B)
         self._record_stats(stats, "decode")
         now = clk.emit()
         self._keys = np.array(keys)
@@ -1338,16 +1410,7 @@ class Engine:
                              "speculative dispatch)")
         for b, t in [(1, c) for c in self._chunk_ladder] \
                 + [(self.num_slots, 1)]:
-            out = self._paged_step(
-                self.params, *self._pools, jnp.zeros((b, t), jnp.int32),
-                jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32),
-                jnp.zeros(b, bool),
-                jax.tree_util.tree_map(jnp.zeros_like,
-                                       self._table_arg(slice(0, b))),
-                jnp.zeros(b, bool), jnp.ones(b, jnp.float32),
-                jnp.ones(b, jnp.float32), jnp.zeros((b, 2), jnp.uint32),
-                *self._kv_scale_args(), *self._adapter_args(slice(0, b)))
-            self._take(out)
+            self._dispatch(b, t)
         self._copy_page(0, 0)
         jax.block_until_ready(self._pools)
         return self
@@ -1411,10 +1474,10 @@ class Engine:
             clk.feed("draft", "decode_time_s")
             props = self._spec_draft(
                 self._draft_params, self._kc, self._vc,
-                jnp.asarray(self._tok), jnp.asarray(self._pos),
-                jnp.asarray(self.pool.table), *self._kv_scale_args())
+                self._upload(self._tok), self._upload(self._pos),
+                self._upload(self.pool.table), *self._kv_scale_args())
             clk.wait()
-            ids[:, 1:] = np.asarray(props)
+            ids[:, 1:] = self._fetch(props)
             metrics.bump("draft_dispatches")
         clk.feed("verify", "decode_time_s")
         for b in decoding:
@@ -1422,23 +1485,23 @@ class Engine:
                       int(self._pos[b]) + int(valid[b]))
         self._decode_dispatches += 1     # per-role gate: prefill workers
         out = self._spec_verify(         # must never reach this dispatch
-            self.params, self._kc, self._vc, jnp.asarray(ids),
-            jnp.asarray(self._pos), jnp.asarray(valid), jnp.asarray(emit),
-            jnp.asarray(self.pool.table), jnp.asarray(nprop),
-            jnp.asarray(self._do_sample), jnp.asarray(self._temp),
-            jnp.asarray(self._top_p), jnp.asarray(self._keys),
+            self.params, self._kc, self._vc, self._upload(ids),
+            self._upload(self._pos), self._upload(valid), self._upload(emit),
+            self._upload(self.pool.table), self._upload(nprop),
+            self._upload(self._do_sample), self._upload(self._temp),
+            self._upload(self._top_p), self._upload(self._keys),
             *self._kv_scale_args())
         clk.wait()
         if self._anomaly:
             self._kc, self._vc, toks, n_emit, keys, ok = out
-            ok = np.asarray(ok)
+            ok = self._fetch(ok)
         else:
             self._kc, self._vc, toks, n_emit, keys = out
             ok = None
-        toks = np.asarray(toks)
-        n_emit = np.asarray(n_emit)
+        toks = self._fetch(toks)
+        n_emit = self._fetch(n_emit)
         now = clk.emit()
-        self._keys = np.array(keys)
+        self._keys = np.array(self._fetch(keys))
         self._count_paged_step(self._do_sample & emit)
         metrics.bump("verify_dispatches")
         for b in decoding:
@@ -1499,23 +1562,14 @@ class Engine:
         ids = np.zeros((1, C), np.int32)
         ids[0, :v] = req.prompt[off:off + v]
         self._cow(b, off, off + v)
-        out = self._paged_step(
-            self.params, *self._pools, jnp.asarray(ids),
-            jnp.asarray([off], np.int32), jnp.asarray([v], np.int32),
-            jnp.asarray([emit]), self._table_arg(slice(b, b + 1)),
-            jnp.asarray(self._do_sample[b:b + 1]),
-            jnp.asarray(self._temp[b:b + 1]),
-            jnp.asarray(self._top_p[b:b + 1]),
-            jnp.asarray(self._keys[b:b + 1]), *self._kv_scale_args(),
-            *self._adapter_args(slice(b, b + 1)))
+        out = self._dispatch(1, C, ids=ids, start=off, valid=v, emit=emit,
+                             **self._slot_fields(slice(b, b + 1)))
         clk.wait()
-        # the anomaly verdict is only consulted on the emitting (final)
-        # chunk — fetched there, not per chunk (no extra host sync on the
-        # interleaved bulk-prefill path)
-        nxt, keys, ok_dev, stats = self._take(out)
-        # the chunk step ends when its outputs are on the host: the fetch
-        # of the slot's key row is where the host waits for the device
-        keys = np.asarray(keys)
+        # the chunk step ends when its outputs are on the host: their one
+        # fetch is where the host waits for the device. The anomaly
+        # verdict comes with them and is only consulted on the emitting
+        # (final) chunk
+        nxt, keys, ok_dev, stats = self._take(out, 1)
         self._record_stats(stats, "chunk")
         t1 = clk.emit()
         self._keys[b] = keys[0]
@@ -1531,7 +1585,7 @@ class Engine:
             self._pos[b] = plen               # next decode writes here
             # only the final chunk is padded: waste < chunk per request
             metrics.observe_prefill_waste(C - v)
-            ok = True if ok_dev is None else bool(np.asarray(ok_dev)[0])
+            ok = True if ok_dev is None else bool(ok_dev[0])
             if not ok:
                 # poisoned already at first-token time (bad weights or a
                 # corrupted prompt page): quarantine before anything is
@@ -1544,8 +1598,7 @@ class Engine:
                 # prompt — the assigned decode worker emits token #1
                 self._finish_handoff(b)
                 return
-            tok = int(np.asarray(nxt)[0])
-            self._emit_token(req, b, tok, first=True)
+            self._emit_token(req, b, int(nxt[0]), first=True)
         else:
             self._chunk_off[b] = off + v
             if self.role == "prefill":

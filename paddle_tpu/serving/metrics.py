@@ -33,6 +33,14 @@ def _zero():
         # only ones whose sampling tail (cuts, sorts, Gumbel draw over
         # [slots, vocab]) ran; in the others the step took the argmax
         "sampled_steps": 0,
+        # host-to-device arrays the engine's dispatches sent and device-to-
+        # host arrays they fetched, counted where they are sent and
+        # fetched (Engine._upload / _fetch). A decode or chunk dispatch
+        # sends its slot operands as one buffer and fetches its small
+        # outputs as one (serving/operands.py): one of each a paged step,
+        # two more uploads with a quantised pool's scale tables. A
+        # speculative engine's draft and verify still send theirs one by one
+        "paged_uploads": 0, "paged_fetches": 0,
         # page-table entries of the [B, 1] decode dispatches, and those the
         # read the step was built with visits: the live pages of each slot
         # under the decode kernel, the whole table under the gather read

@@ -17,6 +17,7 @@ they differ by float32 summation order alone: 2e-5 absolute holds twenty
 times that, and a window layer that reads its whole context, a rotated full
 layer, a dropped gate or a ring one page short moves logits by 1e-2 and
 more."""
+import functools
 import json
 import os
 import sys
@@ -409,13 +410,11 @@ def test_decode_kernel_is_refused_with_its_reason(caplog):
 # the step the engine builds
 
 
-def _step_args(eng, b, t):
-    z = lambda *sh, dt=np.int32: jnp.zeros(sh, dt)
-    tables = jax.tree_util.tree_map(jnp.zeros_like,
-                                    eng._table_arg(slice(0, b)))
-    return (eng.params, *eng._pools, z(b, t), z(b), z(b), z(b, dt=bool),
-            tables, z(b, dt=bool), jnp.ones(b, np.float32),
-            jnp.ones(b, np.float32), z(b, 2, dt=np.uint32))
+def _step_jaxpr(eng, b, t):
+    """The jaxpr of the step the engine dispatches at [b, t], on the idle
+    operands warm_up sends (Engine._step_args)."""
+    args, kw = eng._step_args(b, t)
+    return jax.make_jaxpr(functools.partial(eng._paged_step, **kw))(*args)
 
 
 def test_both_groups_pools_are_the_layer_scans_carry(weights):
@@ -434,7 +433,7 @@ def test_both_groups_pools_are_the_layer_scans_carry(weights):
                 yield from scans(sub)
 
     for b, t in ((7, 1), (1, CHUNK)):
-        closed = jax.make_jaxpr(eng._paged_step)(*_step_args(eng, b, t))
+        closed = _step_jaxpr(eng, b, t)
         found = list(scans(closed.jaxpr))
         assert len(found) == 4                          # layer_plan's segments
         for eqn in found:
@@ -450,8 +449,8 @@ def test_both_groups_pools_are_the_layer_scans_carry(weights):
 def test_scopes_are_in_the_lowered_steps_op_names(weights):
     eng = _engine(weights, num_slots=7)
     for b, t in ((7, 1), (1, CHUNK)):
-        text = eng._paged_step.lower(*_step_args(eng, b, t)).as_text(
-            debug_info=True)
+        args, kw = eng._step_args(b, t)
+        text = eng._paged_step.lower(*args, **kw).as_text(debug_info=True)
         for scope in ("pt_attn_window", "pt_attn_full", "pt_attn_gate",
                       "pt_moe_route", "pt_moe_experts"):
             assert scope in text, scope
@@ -461,7 +460,7 @@ def test_window_layers_gather_the_ring_never_the_contexts_table(weights):
     """In the step's jaxpr every gather out of a window pool takes RING
     pages a slot, every gather out of a full pool the table's 20."""
     eng = _engine(weights, num_slots=7)
-    closed = jax.make_jaxpr(eng._paged_step)(*_step_args(eng, 7, 1))
+    closed = _step_jaxpr(eng, 7, 1)
     window_pool, full_pool = eng._pools[2].shape, eng._pools[0].shape
     seen = {"window": set(), "full": set()}
 
@@ -482,7 +481,8 @@ def test_window_layers_gather_the_ring_never_the_contexts_table(weights):
 
 def test_other_models_steps_take_one_table_and_one_group():
     """GPT and xing4 through the grouped geometry: one group, no window,
-    one allocator, the ``table`` operand the bare array it was (the bitwise
+    one allocator, one table field in the dispatch's buffer, which the step
+    unpacks to the bare ``table`` array it was (the bitwise
     and jaxpr gates of test_paged_serving.py and test_xing4_serving.py hold
     the executables themselves)."""
     from paddle_tpu.models.gpt import GPTConfig
@@ -506,7 +506,8 @@ def test_other_models_steps_take_one_table_and_one_group():
         (group,) = eng._geo.groups
         assert group.names == names and group.window is None
         assert eng._group_pools == [eng.pool]
-        assert eng._table_arg().shape == eng.pool.table.shape
+        (table,) = eng._table_arg().values()
+        assert table.shape == eng.pool.table.shape
         assert eng.pool.prefix_cache_enabled
         assert "group_pages" not in eng._snapshot_meta()
     profiler.reset_serving_counters()

@@ -12,7 +12,8 @@ per-layer metrics on their hand-made context
 (``benchmark/tests/test_xing4_readers.py``; ``test_afmoe_readers.py`` and
 ``test_lfm2_readers.py`` for those cells'), and those of the program's spans
 and counters (``benchmark/tests/test_program_span_readers.py``: the engine's
-phase clock; ``benchmark/tests/test_greedy_tail_share.py``: ``sampled_steps``),
+phase clock; ``benchmark/tests/test_greedy_tail_share.py``: ``sampled_steps``;
+``benchmark/tests/test_uploads_per_dispatch.py``: ``paged_uploads``),
 which break when the program renames what they read."""
 import importlib.util
 import os
@@ -59,3 +60,4 @@ globals().update({k + "_lfm2" if k in globals() else k: v for k, v in
                   _cases("test_lfm2_readers").items()})
 globals().update(_cases("test_program_span_readers"))
 globals().update(_cases("test_greedy_tail_share"))
+globals().update(_cases("test_uploads_per_dispatch"))

@@ -18,6 +18,7 @@ row, scans against a walk), so they differ by float32 summation order alone:
 2e-5 absolute holds fifty times that, and a state that is not zeroed, is
 taken from a padded row, or moves under an idle slot shifts logits by 1e-2
 and more."""
+import functools
 import hashlib
 import json
 import os
@@ -439,13 +440,13 @@ def test_decode_kernel_is_refused_with_its_reason(caplog):
 # the step the engine builds
 
 
-def _step_args(eng, b, t):
-    z = lambda *sh, dt=np.int32: jnp.zeros(sh, dt)
-    tables = jax.tree_util.tree_map(jnp.zeros_like,
-                                    eng._table_arg(slice(0, b)))
-    return (eng.params, *eng._pools, z(b, t), z(b), z(b), z(b, dt=bool),
-            tables, z(b, dt=bool), jnp.ones(b, np.float32),
-            jnp.ones(b, np.float32), z(b, 2, dt=np.uint32))
+def _step_jaxpr(eng, b, t, named=False):
+    """The jaxpr of the step the engine dispatches at [b, t], on the idle
+    operands warm_up sends (Engine._step_args); ``named``: of the step over
+    named operands inside it (``_make_paged_step``'s ``fn``)."""
+    args, kw = eng._step_args(b, t, named=named)
+    step = eng._paged_step.named if named else eng._paged_step
+    return jax.make_jaxpr(functools.partial(step, **kw))(*args)
 
 
 def _scans(jaxpr):
@@ -465,7 +466,7 @@ def test_pools_and_state_are_the_layer_scans_carry(weights):
     eng = _engine(weights, num_slots=7)
     shapes = [a.shape for a in eng._pools]
     for b, t in ((7, 1), (1, CHUNK)):
-        closed = jax.make_jaxpr(eng._paged_step)(*_step_args(eng, b, t))
+        closed = _step_jaxpr(eng, b, t)
         found = list(_scans(closed.jaxpr))
         assert len(found) == 2                          # layer_plan's segments
         for eqn in found:
@@ -482,8 +483,8 @@ def test_pools_and_state_are_the_layer_scans_carry(weights):
 def test_scopes_are_in_the_lowered_steps_op_names(weights):
     eng = _engine(weights, num_slots=7)
     for b, t in ((7, 1), (1, CHUNK)):
-        text = eng._paged_step.lower(*_step_args(eng, b, t)).as_text(
-            debug_info=True)
+        args, kw = eng._step_args(b, t)
+        text = eng._paged_step.lower(*args, **kw).as_text(debug_info=True)
         for scope in ("pt_conv_in", "pt_conv_mix", "pt_conv_out",
                       "pt_attn_gqa", "pt_moe_route", "pt_moe_experts"):
             assert scope in text, scope
@@ -546,8 +547,8 @@ def _toy_engine(model):
 @pytest.mark.parametrize("model", sorted(PARENT_STEPS))
 def test_other_models_steps_are_the_parents_jaxpr_for_jaxpr(model):
     eng = _toy_engine(model)
-    text = "\n".join(str(jax.make_jaxpr(eng._paged_step)(
-        *_step_args(eng, b, t))) for b, t in ((2, 1), (1, 16)))
+    text = "\n".join(str(_step_jaxpr(eng, b, t, named=True))
+                     for b, t in ((2, 1), (1, 16)))
     assert "0x" not in text                 # nothing of this process in it
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEPS[model]
     (group,) = eng._geo.paged[:1]

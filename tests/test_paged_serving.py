@@ -703,10 +703,11 @@ def test_decode_pages_counters_follow_the_read_the_step_was_built_with(
     B, MP, ps = 3, 64 // 8, 8
     step, decodes = eng._paged_step, []
 
-    def spy(params, kc, vc, ids, start, *rest):
-        if ids.shape == (B, 1):
-            decodes.append(np.array(start))     # a copy: may alias _pos
-        return step(params, kc, vc, ids, start, *rest)
+    def spy(params, kc, vc, packed, *rest, layout):
+        if (layout.B, layout.T) == (B, 1):      # a copy: the buffer is
+            start = layout.fields["start"]      # the engine's to refill
+            decodes.append(np.array(packed)[start.offset:start.offset + B])
+        return step(params, kc, vc, packed, *rest, layout=layout)
     eng._paged_step = spy
     rng = np.random.default_rng(4)
     profiler.reset_serving_counters()
@@ -818,9 +819,12 @@ def _step_jaxprs(variant, quant=None, cfg=None):
             *operands(B, 4), jnp.zeros(B, np.int32), *sampling(B),
             *eng._kv_scale_args())]
     else:
-        traced = [jax.make_jaxpr(step)(*operands(b, t), *sampling(b),
-                                       *eng._kv_scale_args())
-                  for b, t in ((B, 1), (1, C))]
+        # the step on the idle operands warm_up sends (Engine._step_args)
+        traced = []
+        for b, t in ((B, 1), (1, C)):
+            args, kw = eng._step_args(b, t)
+            traced.append(jax.make_jaxpr(
+                functools.partial(step, **kw))(*args))
     return eng, traced
 
 

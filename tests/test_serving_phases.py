@@ -121,7 +121,7 @@ class _SlowFetch:
 
 def test_prefill_time_ends_after_the_fetch():
     """The dispatch returns its futures at once and the host waits at the
-    fetch of the keys: ``prefill_time_s``, ``wait_s`` and the
+    one fetch of its outputs: ``prefill_time_s``, ``wait_s`` and the
     ``prefill_chunk`` span hold that wait. (``prefill_time_s`` read the
     enqueue alone before: near 0 here.)"""
     _warm()
@@ -129,11 +129,11 @@ def test_prefill_time_ends_after_the_fetch():
     eng = _engine(trace=True)
     real = eng._paged_step
 
-    def stand_in(*args):
-        out = list(real(*args))
-        if args[3].shape[0] == 1:           # ids [1, C]: a chunk step
+    def stand_in(*args, layout):
+        out = list(real(*args, layout=layout))
+        if layout.B == 1:                   # ids [1, C]: a chunk step
             jax.block_until_ready(out)
-            out[3] = _SlowFetch(out[3], delay)
+            out[-1] = _SlowFetch(out[-1], delay)    # its one small output
         return tuple(out)
 
     eng._paged_step = stand_in

@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.models.gpt import GPTConfig
 from paddle_tpu.models.gpt_hybrid import init_gpt_params
 from paddle_tpu.serving import engine as E
+from paddle_tpu.serving.operands import StepLayout
 from paddle_tpu.serving.paged_attention import pool_head_dim
 
 PAGE, SLOTS, MAX_SEQ = 16, 16, 2048
@@ -62,13 +63,9 @@ def _compile_step(chip, cfg, num_pages, batch, window, use_kernel):
                 pool_head_dim(cfg.hidden_size // cfg.num_heads)), "bfloat16")
     step = E._make_paged_step(E._cfg_key(cfg), None, PAGE, use_kernel,
                               (1, 2))
-    b = batch
-    return pool, step.lower(
-        params, pool, pool, sds((b, window), "int32"), sds((b,), "int32"),
-        sds((b,), "int32"), sds((b,), "bool"),
-        sds((b, MAX_SEQ // PAGE), "int32"), sds((b,), "bool"),
-        sds((b,), "float32"), sds((b,), "float32"),
-        sds((b, 2), "uint32")).compile()
+    layout = StepLayout(batch, window, (MAX_SEQ // PAGE,))
+    return pool, step.lower(params, pool, pool, sds((layout.size,), "int32"),
+                            layout=layout).compile()
 
 
 @pytest.mark.parametrize("name,hidden,heads,num_pages,batch,window", [

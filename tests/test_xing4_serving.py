@@ -12,6 +12,7 @@ over experts, absorbed against expanded attention, a scan against a walk), so
 they differ by float32 summation order alone: 2e-5 absolute holds a hundred
 times that, and a wrong rotary pairing, a dropped shared expert or a missing
 mHC map moves logits by 3e-3 and more."""
+import functools
 import json
 import os
 import sys
@@ -335,15 +336,15 @@ def test_geometry_warm_up_and_snapshot(weights):
 
 
 def _step_jaxprs(eng):
-    """The jaxprs of the step the engine builds, at its two steady-state
-    shapes [B, 1] and [1, chunk]."""
-    B, MP = eng.num_slots, eng.pool.table.shape[1]
-    z = lambda *sh, dt=np.int32: jnp.zeros(sh, dt)
-    return [jax.make_jaxpr(eng._paged_step)(
-        eng.params, *eng._pools, z(b, t), z(b), z(b), z(b, dt=bool),
-        z(b, MP), z(b, dt=bool), jnp.ones(b, np.float32),
-        jnp.ones(b, np.float32), z(b, 2, dt=np.uint32))
-        for b, t in ((B, 1), (1, CHUNK))]
+    """The jaxprs of the step the engine dispatches, on the idle operands
+    warm_up sends (Engine._step_args), at its two steady-state shapes
+    [B, 1] and [1, chunk]."""
+    out = []
+    for b, t in ((eng.num_slots, 1), (1, CHUNK)):
+        args, kw = eng._step_args(b, t)
+        out.append(jax.make_jaxpr(
+            functools.partial(eng._paged_step, **kw))(*args))
+    return out
 
 
 def test_latent_pool_is_the_layer_scans_carry(weights):
