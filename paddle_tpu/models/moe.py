@@ -44,8 +44,9 @@ def ffn(x, gate_w, up_w, down_w):
 
 def final_logits(params, config, h):
     """The final norm (float32) and the untied head over h [..., H]."""
-    hn = rms_norm(h.astype(F32), params["normf_g"], config.rms_norm_eps)
-    return hn @ params["head_w"].astype(F32)
+    with jax.named_scope("pt_head"):
+        hn = rms_norm(h.astype(F32), params["normf_g"], config.rms_norm_eps)
+        return hn @ params["head_w"].astype(F32)
 
 
 def compute_of(config):
@@ -169,8 +170,12 @@ def run_layers(params, config, carry, layer_fn):
                 carry = layer_fn(carry, leaves, kind, idx[0], idx[1])
             return carry, None
 
-        carry, _ = jax.lax.scan(body, carry,
-                                jnp.arange(repeats, dtype=jnp.int32))
+        # what the walk itself costs on a device trace (a layer's leaves
+        # indexed out of their stacks, the loop's carry) is pt_layers'; a
+        # stage inside a layer keeps its own, innermost, scope
+        with jax.named_scope("pt_layers"):
+            carry, _ = jax.lax.scan(body, carry,
+                                    jnp.arange(repeats, dtype=jnp.int32))
         for key, n in per.items():
             seen[key] = seen.get(key, 0) + n * repeats
     return carry

@@ -406,11 +406,13 @@ def _stack_scan(params, X, layer_fn):
     kind, the absolute layer index beside each layer's leaves."""
     n_dense = params["dense"]["wq_a"].shape[0]
     n_moe = params["moe"]["wq_a"].shape[0]
-    X, _ = jax.lax.scan(lambda cr, xs: layer_fn(cr, xs, False), X,
-                        (params["dense"], jnp.arange(n_dense, dtype=jnp.int32)))
-    X, _ = jax.lax.scan(lambda cr, xs: layer_fn(cr, xs, True), X,
-                        (params["moe"],
-                         n_dense + jnp.arange(n_moe, dtype=jnp.int32)))
+    with jax.named_scope("pt_layers"):      # the walk's own: moe.run_layers
+        X, _ = jax.lax.scan(
+            lambda cr, xs: layer_fn(cr, xs, False), X,
+            (params["dense"], jnp.arange(n_dense, dtype=jnp.int32)))
+        X, _ = jax.lax.scan(
+            lambda cr, xs: layer_fn(cr, xs, True), X,
+            (params["moe"], n_dense + jnp.arange(n_moe, dtype=jnp.int32)))
     return X
 
 

@@ -15,6 +15,8 @@ from enum import Enum
 
 import jax
 
+from .xplane import device_time, device_time_summary  # noqa: F401
+
 
 class ProfilerTarget(Enum):
     CPU = 0
@@ -440,7 +442,8 @@ def serving_counters():
     The phase clock of ``Engine.step`` (always on): ``step_s`` over
     ``boundaries`` is the mean boundary, and ``admit_s`` / ``feed_s`` /
     ``wait_s`` / ``emit_s`` its disjoint phases (with a small remainder
-    they sum to ``step_s``). ``decode_time_s`` / ``prefill_time_s`` are
+    they sum to ``step_s``); ``launch_s`` is the part of ``feed_s`` inside
+    the jitted calls themselves. ``decode_time_s`` / ``prefill_time_s`` are
     feed + wait of the decode-side and of the chunk / prefill dispatches,
     each ended by its outputs reaching the host; ``admit_queue_wait_s`` /
     ``admit_queue_waits`` is submit to admission of admitted requests and
@@ -453,8 +456,11 @@ def serving_counters():
     quantised pool's scale tables.
     The same phases are ``jax.profiler.TraceAnnotation`` spans
     (``pt.serve.step`` around ``pt.serve.admit | feed | wait | emit``, a
-    dispatch's feed and wait with ``kind=chunk|decode|draft|verify``;
-    the trainers' dispatch is ``pt.train.step``): see them in a
+    dispatch's feed and wait with ``kind=chunk|decode|draft|verify`` and
+    ``exe=`` the name its executable runs under, ``pt.serve.launch`` with
+    both nested in the feed around the jitted call; ``device_time`` reads
+    a profile by those names; the trainers' dispatch is
+    ``pt.train.step``): see them in a
     ``jax.profiler.start_trace`` session, on the device trace's clock, or
     on the ``boundaries`` thread of ``Engine.export_trace()`` with
     ``FLAGS_serving_trace`` on. (Thin view over the registry's "serving"

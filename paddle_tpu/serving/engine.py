@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import threading
 import time
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import numpy as np
 import jax
@@ -152,9 +152,12 @@ def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
     scales][, adapter slabs], layout=) -> (*pools, out)``. The per-slot
     operands arrive as ONE int32 vector, laid out by the static ``layout``
     (serving/operands.py) and unpacked bit for bit at the top of the
-    trace; the small outputs leave as one. ``fn``, the step over named
-    operands, is what it was; ``step.named`` is ``fn`` jitted alone, for
-    whoever reads the step's jaxpr or lowers it by its operands' names."""
+    trace; the small outputs leave as one. It is jitted once a layout
+    under the dispatch shape's name (``_ShapedStep``), so a device trace
+    reads ``jit_pt_paged_b16_t1`` where it read ``jit_packed_fn``. ``fn``,
+    the step over named operands, is what it was; ``step.named`` is ``fn``
+    jitted alone, for whoever reads the step's jaxpr or lowers it by its
+    operands' names."""
     config = model.view(cfg)
     n_pools = len(model.geometry(config).names)
     kvq = quant is not None and quant[1] != "bf16"
@@ -174,12 +177,13 @@ def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
             use_kernel=use_kernel, kv_scales=scales, wq_kernel=qkernel,
             adapters=ad, mp_key=mp_key)
         tail = () if stats is None else (stats,)
-        keys = jax.random.wrap_key_data(key_data)           # [B] keys
-        pair = jax.vmap(jax.random.split)(keys)             # [B, 2] keys
-        nxt = _next_token(logits, pair[:, 1], do_sample & emit,
-                          temperature, top_k, top_p)
-        new_keys = jnp.where(emit[:, None], jax.random.key_data(pair[:, 0]),
-                             key_data)
+        with jax.named_scope("pt_tail"):
+            keys = jax.random.wrap_key_data(key_data)       # [B] keys
+            pair = jax.vmap(jax.random.split)(keys)         # [B, 2] keys
+            nxt = _next_token(logits, pair[:, 1], do_sample & emit,
+                              temperature, top_k, top_p)
+            new_keys = jnp.where(emit[:, None],
+                                 jax.random.key_data(pair[:, 0]), key_data)
         if anomaly:
             ok = jnp.all(jnp.isfinite(logits), axis=-1)     # [B] per-slot
             return (*pools, nxt, new_keys, ok, *tail)
@@ -193,13 +197,50 @@ def _make_paged_step(cfg, top_k, page_size, use_kernel, donate,
         out = fn(params, *operands[:n_pools], *slot, *rest)
         nxt, new_keys, *tail = out[n_pools:]
         ok = tail.pop(0) if anomaly else None
-        return (*out[:n_pools],
-                pack_out(nxt, new_keys, ok, tail[0] if tail else None))
+        with jax.named_scope("pt_tail"):
+            out_vec = pack_out(nxt, new_keys, ok, tail[0] if tail else None)
+        return (*out[:n_pools], out_vec)
 
-    step = jax.jit(packed_fn, donate_argnums=donate,
-                   static_argnames=("layout",))
-    step.named = jax.jit(fn)
-    return step
+    return _ShapedStep(packed_fn, fn, donate)
+
+
+def _jit_as(name, fn, donate=()):
+    """``fn`` jitted under ``name``: its runs read ``jit_<name>(...)`` on a
+    device trace's ``XLA Modules`` line, and the program's ``pt.serve.*``
+    spans carry ``exe=<name>``."""
+    def run(*args):
+        return fn(*args)
+    run.__name__ = run.__qualname__ = name
+    return jax.jit(run, donate_argnums=donate)
+
+
+class _ShapedStep:
+    """The paged step of one builder key, called as ``step(*operands,
+    layout=)`` as the one jit with a static ``layout`` was: one jitted
+    wrapper a ``StepLayout``, named by its dispatch shape
+    (``StepLayout.exe``), kept here so that every engine of the key shares
+    it (one trace a shape, as before). ``named`` is the step over named
+    operands, jitted alone."""
+
+    def __init__(self, packed_fn, fn, donate):
+        self._packed_fn, self._donate = packed_fn, donate
+        self._exes = {}
+        self.named = jax.jit(fn)
+
+    def exe(self, layout):
+        """The jitted wrapper of ``layout``."""
+        got = self._exes.get(layout)
+        if got is None:
+            got = self._exes[layout] = _jit_as(
+                layout.exe, partial(self._packed_fn, layout=layout),
+                self._donate)
+        return got
+
+    def __call__(self, *operands, layout):
+        return self.exe(layout)(*operands)
+
+    def lower(self, *operands, layout):
+        return self.exe(layout).lower(*operands)
 
 
 @lru_cache(maxsize=None)
@@ -211,7 +252,7 @@ def _make_page_copy(donate):
         metrics.bump("copy_traces")  # body runs only when traced
         return tuple(a.at[:, dst].set(a[:, src]) for a in pools)
 
-    return jax.jit(fn, donate_argnums=donate)
+    return _jit_as("pt_page_copy", fn, donate)
 
 
 @lru_cache(maxsize=None)
@@ -244,7 +285,7 @@ def _make_page_write(donate):
 
 
 @lru_cache(maxsize=None)
-def _make_spec_draft(cfg, page_size, k, quant=None):
+def _make_spec_draft(cfg, page_size, k, quant=None, name="pt_draft"):
     """Build the speculative DRAFT executable: greedily roll the draft
     params ``k`` tokens ahead of every slot, reading the shared paged
     pool (strictly below each slot's write position) and carrying the
@@ -256,7 +297,8 @@ def _make_spec_draft(cfg, page_size, k, quant=None):
     (config, page_size, k, quant) — both draft sources share this one
     wrapper; their distinct param TREES (int8 scale leaves vs sliced
     shallow blocks) key distinct traces under it, exactly like the
-    quantized vs bf16 fused step."""
+    quantized vs bf16 fused step. ``name`` is what the executable runs
+    under (``_jit_as``): the engine gives its dispatch shape's."""
     config = _cfg_view(cfg)
     kvq = quant is not None and quant[1] != "bf16"
 
@@ -267,12 +309,12 @@ def _make_spec_draft(cfg, page_size, k, quant=None):
                                    table, page_size, k, kv_scales=scales)
 
     # NO donation: kc/vc must survive — the verify dispatch reads them next
-    return jax.jit(fn)
+    return _jit_as(name, fn)
 
 
 @lru_cache(maxsize=None)
 def _make_spec_verify(cfg, top_k, page_size, donate, anomaly=False,
-                      quant=None, qkernel=False):
+                      quant=None, qkernel=False, name="pt_verify"):
     """Build the fused speculative VERIFY executable: score ALL slots'
     [B, k+1] windows (lane 0 = the last emitted token, lanes 1..k = the
     draft's proposals) with the SERVED weights, run the accept scan
@@ -285,7 +327,8 @@ def _make_spec_verify(cfg, top_k, page_size, donate, anomaly=False,
 
     ``anomaly=True`` mirrors the fused step's guard: a slot is flagged
     only if a NON-finite logit occurs on a lane it actually emitted
-    from — rejected lanes' logits are dead values."""
+    from — rejected lanes' logits are dead values. ``name``: as the
+    draft's."""
     config = _cfg_view(cfg)
     kvq = quant is not None and quant[1] != "bf16"
 
@@ -313,7 +356,7 @@ def _make_spec_verify(cfg, top_k, page_size, donate, anomaly=False,
             return kc, vc, toks, n_emit, new_keys, ok
         return kc, vc, toks, n_emit, new_keys
 
-    return jax.jit(fn, donate_argnums=donate)
+    return _jit_as(name, fn, donate)
 
 
 class Engine:
@@ -638,12 +681,16 @@ class Engine:
             # one draft + one verify builder, memoized per config like
             # every other serving executable: a second spec engine
             # over warm shapes adds zero traces
+            k = self._spec.k
+            self._verify_exe = f"pt_verify_b{B}_t{k + 1}"
+            self._draft_exe = f"pt_draft_b{B}_t{k}"
             self._spec_verify = _make_spec_verify(
                 cfg, self.top_k, self.page_size,
                 (1, 2) if donate_ok else (), anomaly=self._anomaly,
-                quant=quant_key, qkernel=qkernel)
+                quant=quant_key, qkernel=qkernel, name=self._verify_exe)
             self._spec_draft = _make_spec_draft(
-                cfg, self.page_size, self._spec.k, quant=quant_key)
+                cfg, self.page_size, k, quant=quant_key,
+                name=self._draft_exe)
             self._build_draft_params()
         # a row's last axis padded to whole lanes on the device;
         # snapshots and page payloads keep the model's own width
@@ -1215,6 +1262,11 @@ class Engine:
             got = self._operand_bufs[b, t] = (layout, buf, layout.views(buf))
         return got
 
+    def _exe_name(self, b, t):
+        """What the executable of a ``[b, t]`` dispatch runs under: the
+        ``exe=`` of its feed, launch and wait spans."""
+        return self._operands(b, t)[0].exe
+
     def _step_args(self, b, t, named=False, **fields):
         """What the fused step takes for a ``[b, t]`` dispatch over the
         first ``b`` slots, as ``(args, kwargs)``. ``fields`` are the slot
@@ -1245,7 +1297,8 @@ class Engine:
         the engine; returns the step's small outputs, one array still on
         the device (``_take`` fetches and splits it)."""
         args, kw = self._step_args(b, t, **fields)
-        out = self._paged_step(*args, **kw)
+        with self._clock.launch():
+            out = self._paged_step(*args, **kw)
         self._pools = tuple(out[:-1])
         return out[-1]
 
@@ -1346,7 +1399,7 @@ class Engine:
         if self._spec is not None:
             self._iterate_spec(decoding, t_boundary)
             return
-        t0 = clk.feed("decode", "decode_time_s")
+        t0 = clk.feed("decode", "decode_time_s", self._exe_name(B, 1))
         # mid-prefill slots ride along inert: valid=0 routes their writes
         # to the trash page, emit=False parks their PRNG keys
         valid = np.zeros(B, np.int32)
@@ -1471,26 +1524,30 @@ class Engine:
         ids[:, 0] = self._tok                 # lane 0: last emitted token
         clk = self._clock
         if int(nprop.max()) > 0:
-            clk.feed("draft", "decode_time_s")
-            props = self._spec_draft(
-                self._draft_params, self._kc, self._vc,
-                self._upload(self._tok), self._upload(self._pos),
-                self._upload(self.pool.table), *self._kv_scale_args())
+            clk.feed("draft", "decode_time_s", self._draft_exe)
+            args = (self._draft_params, self._kc, self._vc,
+                    self._upload(self._tok), self._upload(self._pos),
+                    self._upload(self.pool.table), *self._kv_scale_args())
+            with clk.launch():
+                props = self._spec_draft(*args)
             clk.wait()
             ids[:, 1:] = self._fetch(props)
             metrics.bump("draft_dispatches")
-        clk.feed("verify", "decode_time_s")
+        clk.feed("verify", "decode_time_s", self._verify_exe)
         for b in decoding:
             self._cow(b, int(self._pos[b]),
                       int(self._pos[b]) + int(valid[b]))
-        self._decode_dispatches += 1     # per-role gate: prefill workers
-        out = self._spec_verify(         # must never reach this dispatch
+        # per-role gate: prefill workers must never reach this dispatch
+        self._decode_dispatches += 1
+        args = (
             self.params, self._kc, self._vc, self._upload(ids),
             self._upload(self._pos), self._upload(valid), self._upload(emit),
             self._upload(self.pool.table), self._upload(nprop),
             self._upload(self._do_sample), self._upload(self._temp),
             self._upload(self._top_p), self._upload(self._keys),
             *self._kv_scale_args())
+        with clk.launch():
+            out = self._spec_verify(*args)
         clk.wait()
         if self._anomaly:
             self._kc, self._vc, toks, n_emit, keys, ok = out
@@ -1539,7 +1596,6 @@ class Engine:
         """Advance slot b's prefill by one chunk ([1, rung] dispatch of
         the fused step); the final chunk emits the request's first token."""
         clk = self._clock
-        t0 = clk.feed("chunk", "prefill_time_s")
         req = self._slots[b]
         plen = req.prompt_len
         off = int(self._chunk_off[b])
@@ -1550,6 +1606,8 @@ class Engine:
         target = min(-(-remaining // self.page_size) * self.page_size,
                      self._chunk_ladder[-1])
         C = max(c for c in self._chunk_ladder if c <= target)
+        # the feed opens once the rung names its executable
+        t0 = clk.feed("chunk", "prefill_time_s", self._exe_name(1, C))
         v = min(C, remaining)
         last = off + v >= plen                # final chunk emits token #1
         # a PREFILL worker never emits: its final chunk dispatches with

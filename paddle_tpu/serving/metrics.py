@@ -135,9 +135,10 @@ def _zero():
         "decode_time_s": 0.0, "prefill_time_s": 0.0,
         # phase clock of Engine.step (PhaseClock below): seconds of every
         # boundary (count: "boundaries") and of its disjoint phases, which
-        # with a remainder (ledger bumps, snapshots) sum to step_s
+        # with a remainder (ledger bumps, snapshots) sum to step_s;
+        # launch_s is the part of feed_s inside the jitted calls themselves
         "step_s": 0.0, "admit_s": 0.0, "feed_s": 0.0, "wait_s": 0.0,
-        "emit_s": 0.0,
+        "emit_s": 0.0, "launch_s": 0.0,
         # request-level: submit -> admission of every admitted request, and
         # admission -> first token of every fresh first token (a requeued
         # or replayed request's first token counts once, as in observe_ttft)
@@ -278,40 +279,66 @@ def add_time(name, dt):
         _C[name] += dt
 
 
+class _Launch:
+    """``PhaseClock.launch()``: one object a clock, entered once a
+    dispatch."""
+    __slots__ = ("_clock", "_ann", "_t")
+
+    def __init__(self, clock):
+        self._clock = clock
+
+    def __enter__(self):
+        self._ann = TraceAnnotation("pt.serve.launch", **self._clock._tags)
+        self._ann.__enter__()
+        self._t = time.perf_counter()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t
+        self._ann.__exit__(*exc)
+        if self._clock._name == "feed":     # warm_up dispatches in no step
+            self._clock.add("launch_s", dt)
+
+
 class PhaseClock:
     """The one timing idiom of ``Engine.step``: a mark-based clock that
     takes one ``perf_counter()`` per phase edge, keeps the boundary's sums
     in a local dict and adds them to the ledger with ONE lock acquisition
     in ``finish()``. Every phase is also a ``jax.profiler.TraceAnnotation``
     (``pt.serve.step`` around ``pt.serve.admit|feed|wait|emit``; a
-    dispatch's feed and wait carry ``kind=``), so inside a profiler session
-    the phases lie on the device trace's clock; outside one an annotation
-    is a no-op check. With ``keep_spans`` the same instants are kept as
-    spans for the engine track of the exported trace.
+    dispatch's feed and wait carry ``kind=`` and ``exe=``, the name its
+    executable runs under on the device's ``XLA Modules`` line less the
+    ``jit_``), so inside a profiler session the phases lie on the device
+    trace's clock; outside one an annotation is a no-op check. With
+    ``keep_spans`` the same instants are kept as spans for the engine track
+    of the exported trace.
 
     The phases of a boundary are disjoint: opening one closes the one
-    before it at the same instant. ``feed(kind, time_to)`` opens a
+    before it at the same instant. ``feed(kind, time_to, exe)`` opens a
     dispatch; its feed and its ``wait()`` also add to ``time_to``
-    (``decode_time_s`` or ``prefill_time_s``)."""
+    (``decode_time_s`` or ``prefill_time_s``). ``launch()`` is no phase: a
+    ``pt.serve.launch`` span NESTED in the open feed, around the jitted
+    call alone, whose seconds go to ``launch_s`` with the boundary's one
+    flush (two ``perf_counter()`` a dispatch, no lock)."""
 
     def __init__(self, keep_spans=False):
         self.sums = {}
         self.spans = [] if keep_spans else None
         self._step_ann = self._ann = None
-        self._name = self._kind = self._time_to = None
+        self._name = self._time_to = None
+        self._tags = {}                 # kind= and exe= of the open dispatch
         self._t_step = self._t = 0.0
+        self._launch = _Launch(self)
 
     def start(self):
         """Top of ``Engine.step``: opens the step and its first phase."""
         self._step_ann = TraceAnnotation("pt.serve.step")
         self._step_ann.__enter__()
         self._t_step = self._t = time.perf_counter()
-        self._open("admit", None, None)
+        self._open("admit", {}, None)
 
-    def _open(self, name, kind, time_to):
-        self._name, self._kind, self._time_to = name, kind, time_to
-        self._ann = TraceAnnotation("pt.serve." + name) if kind is None \
-            else TraceAnnotation("pt.serve." + name, kind=kind)
+    def _open(self, name, tags, time_to):
+        self._name, self._tags, self._time_to = name, tags, time_to
+        self._ann = TraceAnnotation("pt.serve." + name, **tags)
         self._ann.__enter__()
 
     def pause(self):
@@ -327,29 +354,32 @@ class PhaseClock:
             if self._time_to is not None:
                 sums[self._time_to] = sums.get(self._time_to, 0.0) + dt
             if self.spans is not None:
-                ev = {"name": "pt.serve." + name, "t0": self._t, "t1": now}
-                if self._kind is not None:
-                    ev["kind"] = self._kind
-                self.spans.append(ev)
+                self.spans.append({"name": "pt.serve." + name,
+                                   "t0": self._t, "t1": now, **self._tags})
             self._name = None
         self._t = now
         return now
 
-    def _switch(self, name, kind=None, time_to=None):
+    def _switch(self, name, tags=None, time_to=None):
         now = self.pause()
-        self._open(name, kind, time_to)
+        self._open(name, tags or {}, time_to)
         return now
 
     def admit(self):
         return self._switch("admit")
 
-    def feed(self, kind, time_to):
-        return self._switch("feed", kind, time_to)
+    def feed(self, kind, time_to, exe):
+        return self._switch("feed", {"kind": kind, "exe": exe}, time_to)
+
+    def launch(self):
+        """``with clock.launch():`` around the jitted call of the open
+        feed, and nothing else."""
+        return self._launch
 
     def wait(self):
         """From the jitted call's return to its outputs on the host: the
         same dispatch as the feed before it."""
-        return self._switch("wait", self._kind, self._time_to)
+        return self._switch("wait", self._tags, self._time_to)
 
     def emit(self):
         return self._switch("emit")

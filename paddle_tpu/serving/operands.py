@@ -90,6 +90,13 @@ class StepLayout:
         return sum(f.size for f in self.fields.values())
 
     @property
+    def exe(self):
+        """The name the step is jitted under for this dispatch shape: an
+        executable run reads ``jit_pt_paged_b16_t1(...)`` on a device
+        trace, and the ``pt.serve.*`` spans of its dispatch carry it."""
+        return f"pt_paged_b{self.B}_t{self.T}"
+
+    @property
     def tables(self):
         """The names of the table fields, group after group."""
         return tuple(f"table{g}" for g in range(len(self.table_widths)))

@@ -596,6 +596,19 @@ def _adapted_proj(h, p, name, wq_kernel, aid, ad_l):
     return compose_delta(base, lora_delta(h, A_l, B_l, aid), aid)
 
 
+def _qkv(p, h, nh, eps, wq_kernel):
+    """The first norm and the fused q, k, v projection of one block over
+    h [B, T, H]: three [B, T, nh, d]. Under ``pt_attn_qkv`` on a device
+    trace, like every stage of the paged step under a ``pt_*`` scope of
+    its own (``profiler.device_time`` sums device seconds by them)."""
+    B, T, H = h.shape
+    with jax.named_scope("pt_attn_qkv"):
+        h1 = ln_fp32(h, p["ln1_g"], p["ln1_b"], eps)
+        qkv = _proj(h1, p, "qkv_w", wq_kernel) + p["qkv_b"].astype(h.dtype)
+        q, k, v = jnp.split(qkv.reshape(B, T, 3, nh, H // nh), 3, axis=2)
+        return q[:, :, 0], k[:, :, 0], v[:, :, 0]
+
+
 def _layer_paged(p, h, kc, vc, l, table, pos, valid, nh, eps, page_size,
                  use_kernel, ksc_l=None, vsc_l=None, wq_kernel=False,
                  aid=None, ad_l=None):
@@ -613,27 +626,27 @@ def _layer_paged(p, h, kc, vc, l, table, pos, valid, nh, eps, page_size,
     delta into the out/up/down projection epilogues (qkv itself stays
     un-adapted)."""
     B, T, H = h.shape
-    d = H // nh
 
-    h1 = ln_fp32(h, p["ln1_g"], p["ln1_b"], eps)
-    qkv = _proj(h1, p, "qkv_w", wq_kernel) + p["qkv_b"].astype(h.dtype)
-    q, k, v = jnp.split(qkv.reshape(B, T, 3, nh, d), 3, axis=2)
-    q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]
+    q, k, v = _qkv(p, h, nh, eps, wq_kernel)
+    with jax.named_scope("pt_kv_write"):
+        kc, vc = paged_kv_scatter(kc, vc, l, k, v, table, pos, valid,
+                                  page_size, ksc_l, vsc_l)
+    with jax.named_scope("pt_attn_read"):
+        ctx = paged_attention_read(q, kc, vc, l, table, pos, page_size,
+                                   use_kernel, h.dtype, ksc_l, vsc_l)
 
-    kc, vc = paged_kv_scatter(kc, vc, l, k, v, table, pos, valid,
-                              page_size, ksc_l, vsc_l)
-    ctx = paged_attention_read(q, kc, vc, l, table, pos, page_size,
-                               use_kernel, h.dtype, ksc_l, vsc_l)
-
-    attn = _adapted_proj(ctx.reshape(B, T, H), p, "out_w", wq_kernel,
-                         aid, ad_l) + p["out_b"].astype(h.dtype)
-    h = h + attn
-    h2 = ln_fp32(h, p["ln2_g"], p["ln2_b"], eps)
-    up = _adapted_proj(h2, p, "up_w", wq_kernel, aid, ad_l) + \
-        p["up_b"].astype(h.dtype)
-    up = jax.nn.gelu(up, approximate=True)
-    return h + _adapted_proj(up, p, "down_w", wq_kernel, aid, ad_l) + \
-        p["down_b"].astype(h.dtype), kc, vc
+    with jax.named_scope("pt_attn_out"):
+        attn = _adapted_proj(ctx.reshape(B, T, H), p, "out_w", wq_kernel,
+                             aid, ad_l) + p["out_b"].astype(h.dtype)
+        h = h + attn
+    with jax.named_scope("pt_ffn"):
+        h2 = ln_fp32(h, p["ln2_g"], p["ln2_b"], eps)
+        up = _adapted_proj(h2, p, "up_w", wq_kernel, aid, ad_l) + \
+            p["up_b"].astype(h.dtype)
+        up = jax.nn.gelu(up, approximate=True)
+        h = h + _adapted_proj(up, p, "down_w", wq_kernel, aid, ad_l) + \
+            p["down_b"].astype(h.dtype)
+    return h, kc, vc
 
 
 def paged_forward(params, config, ids, kc, vc, start, valid, table,
@@ -655,8 +668,9 @@ def paged_forward(params, config, ids, kc, vc, start, valid, table,
     compute = jnp.dtype(config.compute_dtype or "float32")
     B, T = ids.shape
     pos = start[:, None] + jnp.arange(T)[None, :]               # [B, T]
-    x = params["wte"].astype(compute)[ids] + \
-        jnp.take(params["wpe"].astype(compute), pos, axis=0)
+    with jax.named_scope("pt_embed"):
+        x = params["wte"].astype(compute)[ids] + \
+            jnp.take(params["wpe"].astype(compute), pos, axis=0)
     nh = config.num_heads
     ksc, vsc = kv_scales if kv_scales is not None else (None, None)
     aid, slabs = adapters if adapters is not None else (None, None)
@@ -668,20 +682,20 @@ def paged_forward(params, config, ids, kc, vc, start, valid, table,
                             config.layer_norm_epsilon, page_size, use_kernel,
                             ksc_l, vsc_l, wq_kernel, aid, ad_l), None
 
-    # a None (no quantized pool, no adapters) is an empty pytree to scan
-    (x, kc, vc), _ = jax.lax.scan(
-        layer_fn, (x, kc, vc),
-        (params["blocks"], layer_ids(params), ksc, vsc, slabs))
-    idx = jnp.maximum(valid - 1, 0)
-    xlast = jax.vmap(
-        lambda xb, i: jax.lax.dynamic_slice_in_dim(xb, i, 1, axis=0))(
-            x, idx)[:, 0]                                       # [B, H]
-    if "head_w_s" in params:
-        xn = _final_ln(params, config, xlast)
-        logits = quant_gemm(xn, params["head_w"], params["head_w_s"],
-                            use_kernel=wq_kernel)
-        return logits, kc, vc
-    return _final_logits(params, config, xlast), kc, vc
+    # a None (no quantized pool, no adapters) is an empty pytree to scan.
+    # What the scan itself does on the device (each layer's weights sliced
+    # out of their stacks and copied into the layout their product wants,
+    # the loop's carry) is pt_layers'; a stage keeps its own, innermost
+    with jax.named_scope("pt_layers"):
+        (x, kc, vc), _ = jax.lax.scan(
+            layer_fn, (x, kc, vc),
+            (params["blocks"], layer_ids(params), ksc, vsc, slabs))
+    with jax.named_scope("pt_head"):
+        idx = jnp.maximum(valid - 1, 0)
+        xlast = jax.vmap(
+            lambda xb, i: jax.lax.dynamic_slice_in_dim(xb, i, 1, axis=0))(
+                x, idx)[:, 0]                                   # [B, H]
+        return _head_logits(params, config, xlast, wq_kernel), kc, vc
 
 
 # ---------------------------------------------------------------------------
@@ -700,29 +714,29 @@ def _layer_verify(p, h, kc, vc, l, table, pos, valid, nh, eps, page_size,
     are bit-for-bit what the plain engine would have produced. T is the
     static k+1, so the unrolled loop stays a small fixed cost."""
     B, T, H = h.shape
-    d = H // nh
 
-    h1 = ln_fp32(h, p["ln1_g"], p["ln1_b"], eps)
-    qkv = _proj(h1, p, "qkv_w", wq_kernel) + p["qkv_b"].astype(h.dtype)
-    q, k, v = jnp.split(qkv.reshape(B, T, 3, nh, d), 3, axis=2)
-    q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]
+    q, k, v = _qkv(p, h, nh, eps, wq_kernel)
+    with jax.named_scope("pt_kv_write"):
+        kc, vc = paged_kv_scatter(kc, vc, l, k, v, table, pos, valid,
+                                  page_size, ksc_l, vsc_l)
+    with jax.named_scope("pt_attn_read"):
+        ctx = jnp.concatenate(
+            [paged_attention_read(q[:, t:t + 1], kc, vc, l, table,
+                                  pos[:, t:t + 1], page_size, use_kernel,
+                                  h.dtype, ksc_l, vsc_l)
+             for t in range(T)], axis=1)
 
-    kc, vc = paged_kv_scatter(kc, vc, l, k, v, table, pos, valid,
-                              page_size, ksc_l, vsc_l)
-    ctx = jnp.concatenate(
-        [paged_attention_read(q[:, t:t + 1], kc, vc, l, table,
-                              pos[:, t:t + 1], page_size, use_kernel,
-                              h.dtype, ksc_l, vsc_l)
-         for t in range(T)], axis=1)
-
-    attn = _proj(ctx.reshape(B, T, H), p, "out_w", wq_kernel) + \
-        p["out_b"].astype(h.dtype)
-    h = h + attn
-    h2 = ln_fp32(h, p["ln2_g"], p["ln2_b"], eps)
-    up = _proj(h2, p, "up_w", wq_kernel) + p["up_b"].astype(h.dtype)
-    up = jax.nn.gelu(up, approximate=True)
-    return h + _proj(up, p, "down_w", wq_kernel) + \
-        p["down_b"].astype(h.dtype), kc, vc
+    with jax.named_scope("pt_attn_out"):
+        attn = _proj(ctx.reshape(B, T, H), p, "out_w", wq_kernel) + \
+            p["out_b"].astype(h.dtype)
+        h = h + attn
+    with jax.named_scope("pt_ffn"):
+        h2 = ln_fp32(h, p["ln2_g"], p["ln2_b"], eps)
+        up = _proj(h2, p, "up_w", wq_kernel) + p["up_b"].astype(h.dtype)
+        up = jax.nn.gelu(up, approximate=True)
+        h = h + _proj(up, p, "down_w", wq_kernel) + \
+            p["down_b"].astype(h.dtype)
+    return h, kc, vc
 
 
 def _head_logits(params, config, x, wq_kernel=False):
@@ -772,10 +786,12 @@ def paged_verify_forward(params, config, ids, kc, vc, start, valid, table,
                               use_kernel, ksc_l, vsc_l, wq_kernel)
         return carry, (saved_k, saved_v)
 
-    (x, kc, vc), (saved_k, saved_v) = jax.lax.scan(
-        layer_fn, (x, kc, vc),
-        (params["blocks"], layer_ids(params), ksc, vsc))
-    logits = _head_logits(params, config, x, wq_kernel)         # [B, T, V]
+    with jax.named_scope("pt_layers"):
+        (x, kc, vc), (saved_k, saved_v) = jax.lax.scan(
+            layer_fn, (x, kc, vc),
+            (params["blocks"], layer_ids(params), ksc, vsc))
+    with jax.named_scope("pt_head"):
+        logits = _head_logits(params, config, x, wq_kernel)     # [B, T, V]
     return logits, kc, vc, saved_k, saved_v
 
 

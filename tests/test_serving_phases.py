@@ -5,7 +5,9 @@ Gates:
   * ``step_s`` and the four phase counters are in ``serving_counters()`` and
     in the Prometheus page; the phases are disjoint and, with a small
     remainder, sum to the step; ``decode_time_s + prefill_time_s`` is the
-    feed + wait of the dispatches;
+    feed + wait of the dispatches; ``launch_s``, the jitted calls alone, is
+    a part of ``feed_s`` (a speculative engine's draft and verify too) and
+    counts nothing outside a step (``warm_up``);
   * ``prefill_time_s`` and the ``prefill_chunk`` span end after the fetch of
     the chunk step's outputs (they timed the enqueue before);
   * ``admit_queue_waits`` follows ``admitted`` and ``first_tokens`` the fresh
@@ -88,6 +90,8 @@ def test_phases_are_disjoint_and_sum_to_the_step(prefill_chunk):
     phases = sum(c[k] for k in PHASES)
     assert phases <= c["step_s"] * (1 + 1e-9)
     assert phases >= 0.9 * c["step_s"]
+    # the launch is no phase of its own: it lies inside the feed
+    assert 0 < c["launch_s"] <= c["feed_s"]
     # feed + wait of every dispatch go to exactly one of the two
     # executable-time counters
     assert c["decode_time_s"] + c["prefill_time_s"] == pytest.approx(
@@ -101,10 +105,30 @@ def test_phase_counters_reach_the_prometheus_page():
     _engine().run(_requests(2))
     page = prometheus.parse(prometheus.render(obs.snapshot()))
     c = profiler.serving_counters()
-    for k in PHASES + ("step_s", "admit_queue_wait_s", "admit_queue_waits",
-                       "prefill_span_s", "first_tokens"):
+    for k in PHASES + ("step_s", "launch_s", "admit_queue_wait_s",
+                       "admit_queue_waits", "prefill_span_s", "first_tokens"):
         assert page[f"paddle_tpu_serving_{k}"] == pytest.approx(c[k])
     assert "token_latency_p50" not in c
+
+
+def test_launch_is_part_of_the_feed_in_a_speculative_engine_too():
+    kw = {"num_slots": 6, "speculate_k": 2}
+    _warm(**kw)
+    _engine(**kw).run(_requests())
+    c = profiler.serving_counters()
+    assert c["draft_dispatches"] > 0 and c["verify_dispatches"] > 0
+    assert 0 < c["launch_s"] <= c["feed_s"]
+    assert sum(c[k] for k in PHASES) <= c["step_s"] * (1 + 1e-9)
+
+
+def test_warm_up_counts_no_launch():
+    """``warm_up`` dispatches outside any step: its compiles must not land
+    in ``launch_s`` at the next boundary's flush."""
+    eng = _engine(num_slots=4).warm_up()
+    assert eng._clock.sums == {}
+    eng.run(_requests(2))
+    c = profiler.serving_counters()
+    assert 0 < c["launch_s"] <= c["feed_s"]
 
 
 class _SlowFetch:
@@ -228,6 +252,10 @@ def test_exported_trace_has_the_boundaries_track(tmp_path):
     kinds = {e["args"].get("kind") for e in phases
              if e["name"] in ("pt.serve.feed", "pt.serve.wait")}
     assert kinds == {"chunk", "decode"}
+    exes = {e["args"].get("exe") for e in phases
+            if e["name"] in ("pt.serve.feed", "pt.serve.wait")}
+    assert "pt_paged_b9_t1" in exes
+    assert exes <= {"pt_paged_b9_t1", "pt_paged_b1_t8", "pt_paged_b1_t16"}
     # every phase lies inside a step, and the same floats reach the ledger
     for e in phases:
         assert any(s["ts"] - 1e-3 <= e["ts"] and
