@@ -16,8 +16,12 @@ leaves: ``ffn_norm_g``, ``router_w``, ``router_bias``,
 A model whose published keys differ states them as properties."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from ..ops.pallas_kernels.grouped_matmul import grouped_ffn
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -79,6 +83,34 @@ def moe_route(xn32, router_w, router_bias, config):
     return idx, w * c.routed_scaling_factor
 
 
+EXPERTS = ("experts_gate_w", "experts_up_w", "experts_down_w")
+
+
+def layer_leaves(stack, i):
+    """Layer ``i``'s leaves out of its kind's stack, but the expert stacks:
+    those stay whole, with ``expert_layer`` i beside them, for the grouped
+    product reads a layer's experts off the stack as stored (a slice here
+    would be a copy of the layer's experts on a TPU)."""
+    leaves = {k: v if k in EXPERTS else v[i] for k, v in stack.items()}
+    if EXPERTS[0] in stack:
+        leaves["expert_layer"] = i
+    return leaves
+
+
+def _ragged_ffn(x, gate, up, down, group_sizes, layer, lo):
+    """What ``grouped_matmul.grouped_ffn`` computes, off a TPU: the layer's
+    held experts sliced out and ``jax.lax.ragged_dot`` over them."""
+    def held(stack):
+        return jax.lax.dynamic_index_in_dim(stack, layer, 0, False)[
+            lo:lo + group_sizes.shape[0]].astype(x.dtype)
+
+    a = jax.lax.ragged_dot(x, held(gate), group_sizes).astype(F32)
+    b = jax.lax.ragged_dot(x, held(up), group_sizes).astype(F32)
+    act = (a * jax.nn.sigmoid(a) * b).astype(x.dtype)
+    return jax.lax.ragged_dot(act, held(down), group_sizes,
+                              preferred_element_type=F32)
+
+
 def moe_ffn(p, x, config, token_mask=None, held=None, shared=True):
     """The expert layer's FFN on the sublayer input x [B, T, H] (float32,
     not yet normed). Routes over every expert, computes the part of the result that
@@ -86,33 +118,55 @@ def moe_ffn(p, x, config, token_mask=None, held=None, shared=True):
     configuration's), plus the shared expert where ``shared``. Returns the
     result and int32 ``[assignments to held experts, held experts that got
     a token, the fullest held expert's tokens]`` over the tokens that
-    ``token_mask`` [B, T] keeps."""
+    ``token_mask`` [B, T] keeps.
+
+    The routed part multiplies each token by the experts it chose: its
+    (token, expert) pairs of held experts and kept tokens, sorted by expert
+    (a counting sort: every ``sort`` of a step lies under the sampling
+    tail's cond), one grouped product a matrix over the experts that hold
+    rows, each pair's row weighed in float32 and summed back into its token.
+    The expert stacks are one layer's ``[E, ...]``, or ``[L, E, ...]`` read at
+    ``p["expert_layer"]`` (``layer_leaves``)."""
     c = config
     B, T, H = x.shape
+    N, k = B * T, c.num_experts_per_tok
     lo, hi = held or c.held
     compute = compute_of(c)
     xn32 = rms_norm(x.astype(F32), p["ffn_norm_g"], c.rms_norm_eps)
-    xn = xn32.astype(compute).reshape(B * T, H)
+    xn = xn32.astype(compute).reshape(N, H)
     with jax.named_scope("pt_moe_route"):
-        idx, w = moe_route(xn32.reshape(B * T, H), p["router_w"],
+        idx, w = moe_route(xn32.reshape(N, H), p["router_w"],
                            p["router_bias"], c)
         hot = jax.nn.one_hot(idx, c.n_routed_experts, dtype=F32)  # [N, k, E]
-        combine = jnp.einsum("nk,nke->ne", w, hot)[:, lo:hi]
         load = jnp.sum(hot, axis=1)[:, lo:hi]                     # [N, E']
         if token_mask is not None:
-            load = load * token_mask.reshape(B * T, 1)
+            load = load * token_mask.reshape(N, 1)
         per_expert = jnp.sum(load, axis=0)
         stats = jnp.stack([jnp.sum(per_expert), jnp.sum(per_expert > 0),
                            jnp.max(per_expert)]).astype(jnp.int32)
     with jax.named_scope("pt_moe_experts"):
-        gate = jnp.einsum("nh,ehf->enf", xn,
-                          p["experts_gate_w"][lo:hi].astype(compute))
-        up = jnp.einsum("nh,ehf->enf", xn,
-                        p["experts_up_w"][lo:hi].astype(compute))
-        act = (jax.nn.silu(gate) * up).astype(F32) * combine.T[:, :, None]
-        y = jnp.einsum("enf,efh->nh", act.astype(compute),
-                       p["experts_down_w"][lo:hi].astype(compute),
-                       preferred_element_type=F32)
+        e = idx.reshape(N * k) - lo
+        keep = (e >= 0) & (e < hi - lo)
+        if token_mask is not None:
+            keep = keep & jnp.repeat(token_mask.reshape(N), k)
+        key = jnp.where(keep, e, hi - lo)              # dropped pairs last
+        count = jnp.cumsum(key[:, None] == jnp.arange(hi - lo + 1), axis=0,
+                           dtype=jnp.int32)            # [N k, E' + 1]
+        start = jnp.cumsum(count[-1], dtype=jnp.int32) - count[-1]
+        dest = start[key] + jnp.take_along_axis(count, key[:, None], 1)[:, 0]
+        dest = dest - 1                                # a pair's sorted row
+        src = jnp.zeros_like(dest).at[dest].set(
+            jnp.arange(N * k, dtype=dest.dtype))       # a sorted row's pair
+        stacks = [p[name] for name in EXPERTS]
+        layer = p.get("expert_layer", 0)
+        if stacks[0].ndim == 3:
+            stacks = [s[None] for s in stacks]
+        rows = jax.lax.platform_dependent(
+            xn[src // k], *stacks, count[-1, :-1], layer,
+            tpu=functools.partial(grouped_ffn, lo=lo),
+            default=functools.partial(_ragged_ffn, lo=lo))
+        y = jnp.where(keep[:, None], rows[dest] * w.reshape(N * k, 1), 0.0)
+        y = jnp.sum(y.reshape(N, k, H), axis=1)
         if shared:
             y = y + ffn(xn, p["shared_gate_w"], p["shared_up_w"],
                         p["shared_down_w"])
@@ -164,9 +218,8 @@ def run_layers(params, config, carry, layer_fn):
                     idx.append(base.get(key, 0) + rep * per[key]
                                + at.get(key, 0))
                     at[key] = at.get(key, 0) + 1
-                stack = params["moe" if moe else "dense"]
-                leaves = jax.tree_util.tree_map(lambda a, i=idx[0]: a[i],
-                                                stack)
+                leaves = layer_leaves(params["moe" if moe else "dense"],
+                                      idx[0])
                 carry = layer_fn(carry, leaves, kind, idx[0], idx[1])
             return carry, None
 

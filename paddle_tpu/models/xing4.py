@@ -21,9 +21,9 @@ reference.py``, states the same independently; tests hold the two together):
   directly and ``Wkvb_V`` follows the weighted sum;
 * experts: ``s = sigmoid(x Wg)`` in float32, top-k of ``s + bias``, weights
   ``s`` of the chosen over their sum times ``routed_scaling_factor``,
-  ``sum w_i E_i(x) + shared(x)``. Dropless: every held expert meets every
-  token with a weight that is nought where it was not chosen, so no token is
-  dropped at any shape and routing is traced data;
+  ``sum w_i E_i(x) + shared(x)``. Dropless: each token meets every held
+  expert it chose, in grouped products sized by the traced routing
+  (``moe.moe_ffn``), so no token is dropped at any shape;
 * mHC around each sublayer ``F`` on the stream ``X`` [n, H]: ``x~ =
   rms(vec X)``, ``Hpre = sigmoid(a_pre x~P_pre + b_pre)``, ``Hpost = 2
   sigmoid(a_post x~P_post + b_post)``, ``Hres = SK(clamp(a_res
@@ -41,8 +41,8 @@ import numpy as np
 from ..serving import metrics
 from ..serving.paged_attention import latent_scatter, latent_window
 from ..serving.served_model import CacheGeometry, ServedModel
-from .moe import F32, HIGHEST, compute_of, ffn, final_logits, mm, moe_ffn, \
-    rms_norm
+from .moe import F32, HIGHEST, compute_of, ffn, final_logits, layer_leaves, \
+    mm, moe_ffn, rms_norm
 
 ROPE_SCALING = (("beta_fast", 32), ("beta_slow", 1), ("factor", 64),
                 ("mscale", 1), ("mscale_all_dim", 1),
@@ -403,7 +403,8 @@ def _embed(params, config, ids):
 
 def _stack_scan(params, X, layer_fn):
     """The leading dense layers, then the stacked expert layers: one scan a
-    kind, the absolute layer index beside each layer's leaves."""
+    kind, the absolute layer index beside each layer's leaves (an expert
+    layer's expert stacks whole: ``moe.layer_leaves``)."""
     n_dense = params["dense"]["wq_a"].shape[0]
     n_moe = params["moe"]["wq_a"].shape[0]
     with jax.named_scope("pt_layers"):      # the walk's own: moe.run_layers
@@ -411,8 +412,9 @@ def _stack_scan(params, X, layer_fn):
             lambda cr, xs: layer_fn(cr, xs, False), X,
             (params["dense"], jnp.arange(n_dense, dtype=jnp.int32)))
         X, _ = jax.lax.scan(
-            lambda cr, xs: layer_fn(cr, xs, True), X,
-            (params["moe"], n_dense + jnp.arange(n_moe, dtype=jnp.int32)))
+            lambda cr, i: layer_fn(cr, (layer_leaves(params["moe"], i),
+                                        n_dense + i), True), X,
+            jnp.arange(n_moe, dtype=jnp.int32))
     return X
 
 

@@ -502,17 +502,21 @@ def test_engine_step_holds_no_branch_on_a_models_name():
 
 
 # the step of each model the engine served before this one, traced at a toy
-# size from the commit before the state group came (0a38e61) and hashed: the
-# seam, the expert layer's epsilon and the lifted layer walk left every one
-# of them as it was, equation for equation. A change that means to alter one
-# of these steps replaces its digest.
+# size and hashed. GPT's is the step of the commit before the state group
+# came (0a38e61): the seam, the lifted layer walk and the grouped expert
+# layer (PR 37) left it as it was, equation for equation. xing4's and
+# afmoe's were renewed by PR 37, whose expert layer multiplies each token by
+# the experts it chose (pairs sorted by expert, grouped products over the
+# stacks read whole at the layer's index) where the dense form multiplied
+# every token by every held expert. A change that means to alter one of
+# these steps replaces its digest.
 PARENT_STEPS = {
     "gpt":
         "40d2ed99b99ca818d9d75e932aeae344bcfcdf97bb1ea8bdab8c08536187516f",
     "xing4":
-        "2763789d60742babc1633a1bfab6fd557b3cf207d0e7885ebc14692084421be3",
+        "3dfd2bd2f00f323cb60ebf6624081665c50c7348c36b8655b1474642c81518ec",
     "afmoe":
-        "c894318cd1c8bf4c9a2072eedfd80d8648985378ecd1877ef5016c04bd88feb7",
+        "ecdb2ef3c792f7659522323b30cda479caa11a05d77f1a0f42a0ddff074fac4b",
 }
 
 

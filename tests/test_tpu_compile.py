@@ -127,3 +127,76 @@ def test_decode_step_keeps_the_sampling_tail_in_a_conditional_on_the_chip(
     assert re.search(r" conditional\(", entry)
     assert len(re.findall(r" sort\(", text)) == 2
     assert not re.search(r" sort\(", entry)
+
+
+def _compile_expert_step(chip, name, slots, chunk, max_seq):
+    """A benchmark configuration's [1, chunk] step for the described chip:
+    every expert width as published, one period, the vocabulary cut to 1024
+    (the head is not what this looks at); the engine's own builder and its
+    pools as the engine allocates them."""
+    import json
+    import os
+    from paddle_tpu.serving.paged_kv import ring_pages
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", f"{name}.json")
+    with open(path) as f:
+        d = dict(json.load(f), vocab_size=1024)
+    if d["family"] == "afmoe":
+        from paddle_tpu.models import afmoe as M
+        cfg = M.AfmoeConfig.from_dict(d, compute_dtype="bfloat16")
+        init = M.init_afmoe_params
+    else:
+        from paddle_tpu.models import lfm2 as M
+        cfg = M.Lfm2Config.from_dict(d, compute_dtype="bfloat16")
+        init = M.init_lfm2_params
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, "bfloat16"),
+        jax.eval_shape(lambda: init(cfg, jax.random.key(0))))
+    model = cfg.served_model
+    pools, widths = [], []
+    for g in model.geometry(cfg).groups:
+        if not g.paged:
+            shape, width = g.state_shape(slots), 0
+        else:
+            tokens = max_seq if g.window is None else min(
+                max_seq, PAGE * ring_pages(g.window, chunk, PAGE))
+            width = -(-tokens // PAGE)
+            shape = g.pool_shape(slots * width + 1, PAGE)
+        widths.append(width)
+        pools += [sds(shape, "bfloat16")] * len(g.names)
+    step = E._make_paged_step(model.key(cfg), None, PAGE, False,
+                              tuple(range(1, 1 + len(pools))), model=model)
+    layout = StepLayout(1, chunk, tuple(widths))
+    compiled = step.lower(params, *pools, sds((layout.size,), "int32"),
+                          layout=layout).compile()
+    return params["moe"], compiled
+
+
+@pytest.mark.parametrize("name,slots,chunk,max_seq", [
+    ("trinity-mini", 16, 512, 6400),
+    ("lfm2-24B-A2B", 64, 256, 1536),
+])
+def test_expert_chunk_step_reads_the_stacks_as_stored_on_the_chip(
+        chip, name, slots, chunk, max_seq):
+    """The expert cells' widest chunk step (ROADMAP S15): no copy or
+    transpose of an expert stack or of a layer's slice of one (the dense
+    form's hoisted layout copies were 4.3 and 3.2 GB of temporaries), and
+    the grouped products take each stack whole, with the layer's index."""
+    moe, compiled = _compile_expert_step(chip, name, slots, chunk, max_seq)
+    text = compiled.as_text()
+    stacks = {",".join(map(str, moe[k].shape))
+              for k in ("experts_gate_w", "experts_up_w", "experts_down_w")}
+    layer = {s.split(",", 1)[1] for s in stacks}
+    moved = re.findall(r"= bf16\[([\d,]+)\]\S* (copy|copy-start|transpose|"
+                       r"dynamic-slice)\(", text)
+    assert not [m for m in moved if m[0] in stacks | layer], moved
+    # a kernel's line names its operands' shapes in its layout constraints
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for dims in stacks:
+        assert [c for c in calls if f"bf16[{dims}]" in c], dims
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
