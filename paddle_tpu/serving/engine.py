@@ -708,7 +708,8 @@ class Engine:
                         for g in geo.groups]
         shapes = [g.pool_shape(pool.num_pages, self.page_size) if g.paged
                   else g.state_shape(B) for g, pool in self._groups]
-        self._pools = tuple(jnp.zeros(shapes[i], compute)
+        self._pools = tuple(jnp.zeros(shapes[i],
+                                      geo.groups[i].dtype or compute)
                             for i in self._pool_group)
         self._slot_ids = np.arange(B, dtype=np.int32)
         self._operand_bufs = {}           # (b, t) -> _operands' triple
@@ -1428,6 +1429,7 @@ class Engine:
         metrics.bump("decode_pages_swept",
                      int((self._pos // self.page_size + 1).sum())
                      if self._paged_kernel else table_pages)
+        self._model.observe("decode", valid)
         for b in decoding:
             req = self._slots[b]
             if ok is not None and not ok[b]:
@@ -1635,6 +1637,7 @@ class Engine:
         self._count_paged_step(emit and self._do_sample[b])
         metrics.bump("chunk_steps")
         metrics.bump("prefill_chunks")
+        self._model.observe("chunk", np.array([v]))
         if req.trace is not None:
             req.trace.span("prefill_chunk", t0, t1, offset=off, tokens=v,
                            chunk=C)
@@ -2183,9 +2186,13 @@ class Engine:
                              self.page_size)
         metrics.bump("state_slots_bound")
         metrics.bump("cache_bytes_bound", mapped + sum(
-            g.layers * g.row_bytes(size) for g in states))
-        metrics.bump("cache_bytes_all_paged", mapped + sum(
-            g.layers for g in states) * lifetime * page_bytes[0])
+            g.layers * g.row_bytes(jnp.dtype(g.dtype).itemsize if g.dtype
+                                   else size) for g in states))
+        # the layers that keep no pages (a layer may keep several states)
+        state_layers = self.config.num_layers - sum(
+            g.layers for g in self._geo.paged)
+        metrics.bump("cache_bytes_all_paged", mapped
+                     + state_layers * lifetime * page_bytes[0])
 
     def _quarantine(self, req, b):
         """Anomaly-guard resolution (``FLAGS_serving_anomaly_policy=
@@ -2561,11 +2568,11 @@ class Engine:
         ``load_state_dict`` for bitwise mid-decode resume."""
         pools_np = [self._logical(jax.device_get(a), g)
                     for a, g in zip(self._pools, self._pool_group)]
-        if pools_np[0].dtype not in (np.int8, np.float32, np.float64,
-                                     np.float16):
-            # fp8/bf16 pools: numpy IO paths don't all speak ml_dtypes —
-            # snapshot the raw bytes; meta's kv dtype restores the view
-            pools_np = [a.view(np.uint8) for a in pools_np]
+        # fp8/bf16 pools: numpy IO paths don't all speak ml_dtypes —
+        # snapshot the raw bytes; the restoring pool's dtype takes the view
+        pools_np = [a if a.dtype in (np.int8, np.float32, np.float64,
+                                     np.float16) else a.view(np.uint8)
+                    for a in pools_np]
         state = {
             "meta": self._snapshot_meta(),
             # each pool array under its geometry's name (GPT: kc, vc)
@@ -2664,14 +2671,13 @@ class Engine:
             raise ValueError(
                 f"engine snapshot meta {meta} does not match this engine "
                 f"{mine}; build the restoring Engine with the same config")
-        compute = self._pools[0].dtype
         restored = []
         for name, like in zip(self._geo.names, self._pools):
             a_np = np.asarray(state[name])
-            if a_np.dtype == np.uint8 and compute != jnp.uint8:
+            if a_np.dtype == np.uint8 and like.dtype != jnp.uint8:
                 # raw-byte snapshot of an fp8 pool: restore the dtype view
-                a_np = a_np.view(compute)
-            restored.append(pad_lanes(jnp.asarray(a_np, compute), like))
+                a_np = a_np.view(like.dtype)
+            restored.append(pad_lanes(jnp.asarray(a_np, like.dtype), like))
         self._pools = tuple(restored)
         if self._kv_sharding is not None:
             # snapshots hold the GLOBAL pool (mp-independent geometry, and

@@ -168,6 +168,11 @@ def _zero():
         # state layer kept rows a token as the first paged group's do
         "state_slots_bound": 0, "cache_bytes_bound": 0,
         "cache_bytes_all_paged": 0,
+        # a model of selective-scan layers (models/jamba.py), by the host's
+        # copy of each dispatch's operands: the real positions of every
+        # chunk dispatch, the live slots of every [B, 1] decode dispatch
+        # (each once a dispatch, not once a layer)
+        "ssm_scan_positions": 0, "ssm_step_slots": 0,
         # occupancy: sum of active slots over decode steps / (steps * slots)
         "active_slot_steps": 0, "slot_steps": 0,
         # queue depth observed at step boundaries

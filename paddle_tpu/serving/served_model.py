@@ -62,12 +62,16 @@ class CacheGroup:
     layer of the group attends to (its slot is then a ring). ``paged``
     False is a state group: ``row`` is then what a SLOT keeps a layer
     whatever its context (``(rows, hidden)``), the arrays are ``[layers,
-    slots, *row]`` and no table maps them."""
+    slots, *row]`` and no table maps them. ``dtype`` None stores the group
+    in the geometry's type; a group that must keep another (a recurrent
+    state summed over thousands of steps, float32 beside bfloat16 pages)
+    names it."""
     names: tuple
     layers: int
     row: tuple
     window: int = None
     paged: bool = True
+    dtype: str = None
 
     def pool_shape(self, num_pages, page_size):
         """On the device a row's last axis is whole lanes (``pool_head_dim``:
@@ -95,7 +99,7 @@ class CacheGroup:
 @dataclasses.dataclass(frozen=True)
 class CacheGeometry:
     """A model's paged cache: its groups of layers (most models have one)
-    and the storage type of every pool array."""
+    and the storage type of every pool array whose group names none."""
     groups: tuple
     dtype: str
 
@@ -144,6 +148,11 @@ class ServedModel:
 
     def record(self, stats, kind, config):
         """The step's ``stats`` on the host, ``kind`` chunk | decode."""
+
+    def observe(self, kind, valid):
+        """A chunk or decode dispatch's ``valid`` [B] as the host sent it
+        (its own copy, no sync): where a model counts the work of a layer
+        of its own."""
 
 
 class _GPTServed(ServedModel):

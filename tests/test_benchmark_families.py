@@ -5,12 +5,14 @@ cell resolves, an unknown or half family is refused in a sentence) and the
 rehearsal of the xing4 family (``benchmark/tests/test_rehearsal_xing4.py``: a
 whole serving run at a toy width to ``correct`` on the CPU) and of the afmoe
 family (``test_rehearsal_afmoe.py``, with its planted faults) and of the lfm2
-family (``test_rehearsal_lfm2.py``, likewise) run here too, imported and not
+family (``test_rehearsal_lfm2.py``, likewise) and of the jamba family
+(``test_rehearsal_jamba.py``, likewise) run here too, imported and not
 copied, so that a cell or a family that no longer loads
 fails the tests the driver runs. So do the readers of the xing4 cell's own
 per-layer metrics on their hand-made context
 (``benchmark/tests/test_xing4_readers.py``; ``test_afmoe_readers.py`` and
-``test_lfm2_readers.py`` for those cells'), and those of the program's spans
+``test_lfm2_readers.py`` and ``test_jamba_readers.py`` for those cells'),
+and those of the program's spans
 and counters (``benchmark/tests/test_program_span_readers.py``: the engine's
 phase clock; ``benchmark/tests/test_greedy_tail_share.py``: ``sampled_steps``;
 ``benchmark/tests/test_uploads_per_dispatch.py``: ``paged_uploads``),
@@ -58,6 +60,10 @@ globals().update({k + "_lfm2" if k in globals() else k: v for k, v in
                   _cases("test_rehearsal_lfm2").items()})
 globals().update({k + "_lfm2" if k in globals() else k: v for k, v in
                   _cases("test_lfm2_readers").items()})
+globals().update({k + "_jamba" if k in globals() else k: v for k, v in
+                  _cases("test_rehearsal_jamba").items()})
+globals().update({k + "_jamba" if k in globals() else k: v for k, v in
+                  _cases("test_jamba_readers").items()})
 globals().update(_cases("test_program_span_readers"))
 globals().update(_cases("test_greedy_tail_share"))
 globals().update(_cases("test_uploads_per_dispatch"))

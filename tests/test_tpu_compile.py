@@ -5,7 +5,9 @@ scan's carry; this shows the chip's compiler then updates it in place — no
 copy, zero fill or relayout of a whole pool, the outputs in the donated
 buffers — and that the decode kernel keeps the name the benchmark
 finds it by. And what it makes of the sampling tail: a conditional whose
-branches hold the sorts, so a greedy dispatch skips them.
+branches hold the sorts, so a greedy dispatch skips them. And the jamba
+cell's steps at published widths: pools and states in place at their
+logical bytes, the selective-scan kernels over the whole state.
 
 The topology is described inside a fixture (never at import: one process
 holds the TPU library, and every xdist worker imports this file)."""
@@ -200,3 +202,76 @@ def test_expert_chunk_step_reads_the_stacks_as_stored_on_the_chip(
     for dims in stacks:
         assert [c for c in calls if f"bf16[{dims}]" in c], dims
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def _compile_jamba_step(chip, batch, window, slots=128, max_seq=8192):
+    """The jamba cell's step for the described chip at the published widths
+    and depth (the vocabulary cut to 1024: the head is not what this looks
+    at), the pools and states as the engine allocates them: K and V of
+    twice what the slots hold, the convolution rows in bfloat16, the
+    recurrent state in float32."""
+    import json
+    import os
+    from paddle_tpu.models import jamba as M
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", "jamba2-3B.json")
+    with open(path) as f:
+        cfg = M.JambaConfig.from_dict(dict(json.load(f), vocab_size=1024),
+                                      compute_dtype="bfloat16")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, "bfloat16"),
+        jax.eval_shape(lambda: M.init_jamba_params(cfg, jax.random.key(0))))
+    model = cfg.served_model
+    geo = model.geometry(cfg)
+    pools, widths = [], []
+    for g in geo.groups:
+        width = max_seq // PAGE if g.paged else 0
+        shape = g.pool_shape(2 * slots * width + 1, PAGE) if g.paged \
+            else g.state_shape(slots)
+        widths.append(width)
+        pools += [sds(shape, g.dtype or geo.dtype)] * len(g.names)
+    # the engine's choice on a TPU (``kernel_ok``: one KV head)
+    step = E._make_paged_step(model.key(cfg), None, PAGE, True,
+                              tuple(range(1, 1 + len(pools))), model=model)
+    layout = StepLayout(batch, window, tuple(widths))
+    compiled = step.lower(params, *pools, sds((layout.size,), "int32"),
+                          layout=layout).compile()
+    return pools, compiled
+
+
+@pytest.mark.parametrize("batch,window,kernel", [(1, 512, "ssm_scan"),
+                                                 (128, 1, "ssm_step")],
+                         ids=["chunk_1x512", "decode_128x1"])
+def test_jamba_step_updates_pages_and_states_in_place_on_the_chip(
+        chip, batch, window, kernel):
+    """The jamba cell's widest chunk step and its decode step: the pools
+    and both states come back in the buffers they came in at their logical
+    bytes (a row of three bfloat16 rows of 5,120 took 4/3 of its bytes and
+    two relayouts a dispatch before it became one row of 15,360); no copy
+    of the recurrent state or of a layer's slice of it; the selective-scan
+    kernel takes the state whole, under its name, and the decode step's
+    attention read is a kernel over the pool as stored (the gather it
+    replaced moved the table's whole width, 1.07 GB a step)."""
+    pools, compiled = _compile_jamba_step(chip, batch, window)
+    text = compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        p.size * p.dtype.itemsize for p in pools)
+    kv, _, conv, ssm = (",".join(map(str, p.shape)) for p in pools)
+    slice_ = ssm.split(",", 1)[1]
+    moved = re.findall(r"= (?:bf16|f32)\[([\d,]+)\]\S* (copy|copy-start|"
+                       r"transpose|broadcast|dynamic-slice)\(", text)
+    assert not [m for m in moved if m[0] in (kv, conv, ssm, slice_)], moved
+    calls = [line.lstrip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    scans = [c for c in calls if c.startswith(f"%{kernel}")]
+    assert scans and all(f"f32[{ssm}]" in c for c in scans)
+    # the decode step's attention read is the multi-query kernel's, over
+    # the pool as it is stored; the chunk step gathers its window
+    reads = [c for c in calls if c.startswith("%paged_mqa_decode")]
+    assert len(scans) + len(reads) == len(calls)
+    assert bool(reads) == (window == 1)
+    assert all(f"bf16[{kv}]" in c for c in reads)
