@@ -426,7 +426,11 @@ def _logical_qkv(params, config):
     the wrong heads. Pure relabeling, bitwise identical. Runs once per
     generate_from_params CALL (amortized over the whole decode); for
     repeated-generation loops pre-permute once — or use the serving
-    Engine, which does this at construction."""
+    Engine, which does this at construction. The engine's own tree
+    (``_stored_qkv``) is logical already and only comes back to
+    ``qkv_w``."""
+    if "qkv_wt" in params["blocks"]:
+        return _trained_qkv(params)
     if not getattr(config, "qkv_head_major", False):
         return params
     from ..distributed.tp_overlap import qkv_head_major_perm
@@ -436,6 +440,33 @@ def _logical_qkv(params, config):
     blocks = dict(params["blocks"])
     blocks["qkv_w"] = jnp.asarray(blocks["qkv_w"])[..., inv]
     blocks["qkv_b"] = jnp.asarray(blocks["qkv_b"])[..., inv]
+    return {**params, "blocks": blocks}
+
+
+def _stored_qkv(params):
+    """A logical tree as the serving engine keeps it: a full-precision qkv
+    stack ``qkv_w`` [L, H, 3H] stored transposed as ``qkv_wt`` [L, 3H, H].
+    Row-major, that is XLA:TPU's default layout for what the fused product
+    reads (the contracted H minor-most), so the paged step reads a layer's
+    slice as stored; read as ``qkv_w``, the slice is copied into that order
+    in every layer of every dispatch. A quantized stack (its ``qkv_w_s``
+    scale beside it) keeps its form."""
+    blocks = params["blocks"]
+    if "qkv_w" not in blocks or "qkv_w_s" in blocks:
+        return params
+    blocks = dict(blocks)
+    blocks["qkv_wt"] = jnp.swapaxes(jnp.asarray(blocks.pop("qkv_w")), -1, -2)
+    return {**params, "blocks": blocks}
+
+
+def _trained_qkv(params):
+    """``_stored_qkv`` undone: ``qkv_wt`` back to ``qkv_w``, exactly; a
+    tree in any other form as it is."""
+    blocks = params["blocks"]
+    if "qkv_wt" not in blocks:
+        return params
+    blocks = dict(blocks)
+    blocks["qkv_w"] = jnp.swapaxes(blocks.pop("qkv_wt"), -1, -2)
     return {**params, "blocks": blocks}
 
 

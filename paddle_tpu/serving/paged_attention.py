@@ -577,7 +577,7 @@ def latent_window(pool, l, table, row):
 def layer_ids(params):
     """The layer scan's index operand, [L] int32 over the tree's blocks (a
     layer-truncated draft tree walks the pool's leading layers)."""
-    return jnp.arange(params["blocks"]["qkv_w"].shape[0], dtype=jnp.int32)
+    return jnp.arange(params["blocks"]["qkv_b"].shape[0], dtype=jnp.int32)
 
 
 def _adapted_proj(h, p, name, wq_kernel, aid, ad_l):
@@ -596,6 +596,20 @@ def _adapted_proj(h, p, name, wq_kernel, aid, ad_l):
     return compose_delta(base, lora_delta(h, A_l, B_l, aid), aid)
 
 
+def _qkv_proj(h, p, wq_kernel=False):
+    """The fused q, k, v product of one block plus its bias, h [B, T, H] to
+    [B, T, 3H]. A full-precision stack comes as the engine stores it,
+    ``qkv_wt`` [3H, H] a layer (``generation._stored_qkv``), and is read
+    as stored; a quantized one, or a tree the engine did not prepare,
+    takes ``_proj``."""
+    wt = p.get("qkv_wt")
+    if wt is None:
+        qkv = _proj(h, p, "qkv_w", wq_kernel)
+    else:
+        qkv = jnp.einsum("bth,nh->btn", h, wt.astype(h.dtype))
+    return qkv + p["qkv_b"].astype(h.dtype)
+
+
 def _qkv(p, h, nh, eps, wq_kernel):
     """The first norm and the fused q, k, v projection of one block over
     h [B, T, H]: three [B, T, nh, d]. Under ``pt_attn_qkv`` on a device
@@ -604,7 +618,7 @@ def _qkv(p, h, nh, eps, wq_kernel):
     B, T, H = h.shape
     with jax.named_scope("pt_attn_qkv"):
         h1 = ln_fp32(h, p["ln1_g"], p["ln1_b"], eps)
-        qkv = _proj(h1, p, "qkv_w", wq_kernel) + p["qkv_b"].astype(h.dtype)
+        qkv = _qkv_proj(h1, p, wq_kernel)
         q, k, v = jnp.split(qkv.reshape(B, T, 3, nh, H // nh), 3, axis=2)
         return q[:, :, 0], k[:, :, 0], v[:, :, 0]
 
@@ -830,7 +844,7 @@ def _draft_layer(p_l, h, kc, vc, l, sk_l, sv_l, table, base_pos, i, nh,
     kmax = sk_l.shape[1]
 
     h1 = ln_fp32(h, p_l["ln1_g"], p_l["ln1_b"], eps)
-    qkv = _proj(h1, p_l, "qkv_w") + p_l["qkv_b"].astype(h.dtype)
+    qkv = _qkv_proj(h1, p_l)
     q, kx, vx = jnp.split(qkv.reshape(B, 1, 3, nh, d), 3, axis=2)
     q, kx, vx = q[:, :, 0], kx[:, :, 0], vx[:, :, 0]
     sk_l = sk_l.at[:, i].set(kx[:, 0])

@@ -231,9 +231,13 @@ def quantize_params(params, config, spec, qkv_perm=None):
     quantized weight. Pinned ``spec.weight_scales`` are honored
     (``qkv_perm`` relabels the pinned qkv columns when the caller already
     permuted the tree head-major); otherwise scales are fresh absmax of
-    the live weights — which is exactly what ``swap_params`` wants."""
+    the live weights — which is exactly what ``swap_params`` wants. A
+    tree whose qkv stack is stored transposed (the engine's,
+    ``generation._stored_qkv``) is quantized in the logical form."""
+    from ..models.generation import _trained_qkv
     if not spec.quantizes_weights:
         return params
+    params = _trained_qkv(params)
     pinned = spec.weight_scales or {}
     pinned_blocks = dict(pinned.get("blocks", {}))
     if qkv_perm is not None and "qkv_w" in pinned_blocks:

@@ -48,7 +48,8 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generation import _cfg_key, _cfg_view, _logical_qkv
+from ..models.generation import _cfg_key, _cfg_view, _logical_qkv, \
+    _stored_qkv
 from .paged_attention import paged_forward, paged_kernel_supported, \
     pool_head_dim
 
@@ -166,8 +167,9 @@ class _GPTServed(ServedModel):
 
     def prepare(self, params, config):
         # undo head-major qkv storage (sequence-parallel HybridTrainStep)
-        # once at construction: single-chip decode splits qkv logically
-        return _logical_qkv(params, config)
+        # once at construction: single-chip decode splits qkv logically;
+        # then keep a full-precision qkv stack as the step reads it
+        return _stored_qkv(_logical_qkv(params, config))
 
     def geometry(self, config):
         nh = config.num_heads

@@ -502,19 +502,20 @@ def test_engine_step_holds_no_branch_on_a_models_name():
 
 
 # the step of each model the engine served before this one, traced at a toy
-# size and hashed. GPT's is the step of the commit before the state group
-# came (0a38e61): the seam, the lifted layer walk and the grouped expert
-# layer (PR 37) left it as it was, equation for equation. xing4's and
-# afmoe's were renewed by PR 37, whose expert layer multiplies each token by
-# the experts it chose (pairs sorted by expert, grouped products over the
-# stacks read whole at the layer's index) where the dense form multiplied
-# every token by every held expert. lfm2's own step was recorded on the
-# commit before the seam's groups took a type of their own (de6c695): the
-# second model of state groups, the Jamba family, left it as it was. A
-# change that means to alter one of these steps replaces its digest.
+# size and hashed. GPT's was renewed when its engine came to keep the qkv
+# stack transposed: the same step equation for equation but the qkv
+# product's, which reads the layer's weight [3H, H] and contracts its
+# second axis. xing4's and afmoe's were renewed when the expert layer came
+# to multiply each token by the experts it chose (pairs sorted by expert,
+# grouped products over the stacks read whole at the layer's index) where
+# the dense form multiplied every token by every held expert. lfm2's
+# own step was recorded on the commit before the seam's groups took a type
+# of their own (de6c695): the second model of state groups, the Jamba
+# family, left it as it was. A change that means to alter one of these
+# steps replaces its digest.
 PARENT_STEPS = {
     "gpt":
-        "40d2ed99b99ca818d9d75e932aeae344bcfcdf97bb1ea8bdab8c08536187516f",
+        "772e4481636a560663ac7898d82b14ebf86f334ada5402aacef270ac8bd6d3c8",
     "xing4":
         "3dfd2bd2f00f323cb60ebf6624081665c50c7348c36b8655b1474642c81518ec",
     "afmoe":

@@ -387,6 +387,35 @@ def test_inference_serve_accepts_spec_and_rejects_bad():
 # hot weight swap: re-quantize on device, zero retraces
 
 
+@pytest.mark.parametrize("quant", [None, "int8"])
+def test_engine_stores_a_full_precision_qkv_stack_transposed(quant):
+    """The single-chip engine keeps a full-precision qkv stack as the paged
+    step reads it, ``qkv_wt`` [L, 3H, H], the trained stack transposed bit
+    for bit; a quantized stack stays [L, H, 3H] with its per-column scale.
+    A swap lands in the same form, and the full-precision engine's tree
+    decodes through generate_from_params as the trained tree does."""
+    eng = _engine(quant=quant, page_size=4, prefill_chunk=4)
+    L, H = CFG.num_layers, CFG.hidden_size
+    trained = np.asarray(_params()["blocks"]["qkv_w"])
+    for version in (1, 2):
+        b = eng.params["blocks"]
+        if quant is None:
+            assert "qkv_w" not in b and b["qkv_wt"].shape == (L, 3 * H, H)
+            np.testing.assert_array_equal(np.asarray(b["qkv_wt"]),
+                                          np.swapaxes(trained, 1, 2))
+        else:
+            assert "qkv_wt" not in b and b["qkv_w"].dtype == jnp.int8
+            assert b["qkv_w"].shape == (L, H, 3 * H)
+            assert b["qkv_w_s"].shape == (L, 3 * H)
+        eng.swap_params(_params(), version=version + 1)
+    if quant is None:
+        prompt = np.asarray([[1, 2, 3, 4, 5]], np.int32)
+        served, want = (generate_from_params(p, prompt, CFG, max_new_tokens=6)
+                        for p in (eng.params, _params()))
+        np.testing.assert_array_equal(np.asarray(served._data),
+                                      np.asarray(want._data))
+
+
 def test_swap_params_requantizes_zero_retraces():
     eng = _engine(quant="int8", page_size=4, prefill_chunk=4)
     eng.run([serving.Request([1, 2, 3, 4, 5], max_new_tokens=4)])

@@ -3,10 +3,11 @@ serving cells' attention geometry and two layers: what XLA:TPU makes of the
 KV pool. The CPU jaxpr gate (test_paged_serving) shows the pool is the layer
 scan's carry; this shows the chip's compiler then updates it in place — no
 copy, zero fill or relayout of a whole pool, the outputs in the donated
-buffers — and that the decode kernel keeps the name the benchmark
-finds it by. And what it makes of the sampling tail: a conditional whose
-branches hold the sorts, so a greedy dispatch skips them. And the jamba
-cell's steps at published widths: pools and states in place at their
+buffers — and that the decode kernel keeps the name the benchmark finds it
+by. That the qkv product reads the engine's stack as stored, with no copy
+of a layer's slice. And what it makes of the sampling tail: a conditional
+whose branches hold the sorts, so a greedy dispatch skips them. And the
+jamba cell's steps at published widths: pools and states in place at their
 logical bytes, the selective-scan kernels over the whole state.
 
 The topology is described inside a fixture (never at import: one process
@@ -23,6 +24,7 @@ from paddle_tpu.models.gpt_hybrid import init_gpt_params
 from paddle_tpu.serving import engine as E
 from paddle_tpu.serving.operands import StepLayout
 from paddle_tpu.serving.paged_attention import pool_head_dim
+from paddle_tpu.serving.served_model import GPT
 
 PAGE, SLOTS, MAX_SEQ = 16, 16, 2048
 
@@ -54,13 +56,15 @@ def _gpt(hidden, heads, vocab=1024):
 
 def _compile_step(chip, cfg, num_pages, batch, window, use_kernel):
     """The engine's own builder, lowered on shapes placed on the described
-    chip; the pool as the engine allocates it."""
+    chip; the tree as the engine prepares it and the pool as the engine
+    allocates it."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=chip)
 
     params = jax.tree_util.tree_map(
         lambda a: sds(a.shape, "bfloat16"),
-        jax.eval_shape(lambda: init_gpt_params(cfg, jax.random.key(0))))
+        jax.eval_shape(lambda: GPT.prepare(
+            init_gpt_params(cfg, jax.random.key(0)), cfg)))
     pool = sds((cfg.num_layers, num_pages, PAGE, cfg.num_heads,
                 pool_head_dim(cfg.hidden_size // cfg.num_heads)), "bfloat16")
     step = E._make_paged_step(E._cfg_key(cfg), None, PAGE, use_kernel,
@@ -70,13 +74,17 @@ def _compile_step(chip, cfg, num_pages, batch, window, use_kernel):
                             layout=layout).compile()
 
 
-@pytest.mark.parametrize("name,hidden,heads,num_pages,batch,window", [
-    ("1.3B-decode", 2048, 16, 2049, SLOTS, 1),
-    ("1.3B-chunk", 2048, 16, 2049, 1, 16),
-    # head_dim 80 in a pool of 128 lanes: the kernel takes it too (PR 33)
-    ("2.7B-decode", 2560, 32, 769, SLOTS, 1),
-    ("2.7B-chunk", 2560, 32, 769, 1, 16),
-])
+GPT_STEPS = pytest.mark.parametrize(
+    "name,hidden,heads,num_pages,batch,window", [
+        ("1.3B-decode", 2048, 16, 2049, SLOTS, 1),
+        ("1.3B-chunk", 2048, 16, 2049, 1, 16),
+        # head_dim 80 in a pool of 128 lanes: the kernel takes it too
+        ("2.7B-decode", 2560, 32, 769, SLOTS, 1),
+        ("2.7B-chunk", 2560, 32, 769, 1, 16),
+    ])
+
+
+@GPT_STEPS
 def test_paged_step_updates_the_pool_in_place_on_the_chip(
         chip, name, hidden, heads, num_pages, batch, window):
     """The step as the engine builds it on a TPU, with the kernel: a Mosaic
@@ -100,6 +108,23 @@ def test_paged_step_updates_the_pool_in_place_on_the_chip(
         assert [c for c in calls if c.startswith("%paged_decode_attention")]
     else:
         assert not calls
+
+
+@GPT_STEPS
+def test_gpt_step_reads_the_qkv_stack_as_stored_on_the_chip(
+        chip, name, hidden, heads, num_pages, batch, window):
+    """The GPT cells' steps (ROADMAP S16): no copy or transpose of the qkv
+    stack, of a layer's slice of it in either order, or of that slice
+    split by heads. The layer scan's read of the slice stays: the product
+    needs it."""
+    _, compiled = _compile_step(chip, _gpt(hidden, heads), num_pages, batch,
+                                window, True)
+    L, H, d = 2, hidden, hidden // heads
+    qkv = {f"{n},{H},{3 * H}" for n in (1, L)} | \
+        {f"{n},{3 * H},{H}" for n in (1, L)} | {f"3,{heads},{d},{H}"}
+    moved = re.findall(r"= bf16\[([\d,]+)\]\S* (copy|copy-start|transpose)\(",
+                       compiled.as_text())
+    assert not [m for m in moved if m[0] in qkv], moved
 
 
 def test_decode_kernel_takes_the_gather_reads_windows_off_the_2_7b_step(
